@@ -61,4 +61,7 @@ def main() -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     print("\n".join(main()))

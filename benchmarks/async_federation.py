@@ -186,6 +186,9 @@ def append_trajectory(record: dict, out: str) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--sites", type=int, default=8)
     ap.add_argument("--rounds", type=int, default=5)

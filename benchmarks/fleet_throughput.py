@@ -186,6 +186,9 @@ def main(k: int = 64, m0: int = 16, n: int = 256, repeats: int = 3) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--tenants", type=int, default=64)
     ap.add_argument("--features", type=int, default=16)

@@ -9,12 +9,10 @@ committed per-platform cache (``src/repro/kernels/autotune_cache.json``).
 
 Each record carries attained GFLOP/s from the analytic contraction count
 (2*o*m^2*n for the Gram fold, + the fused-chunk kernel's recomputed
-stage-1 matmul) and the attained-vs-peak fraction against
-``launch/roofline.PEAK_FLOPS``.  The peak is the TPU v5e bf16 reference the
-rest of the launch tooling uses (`scripts/profile_dots.py` cross-checks the
-per-dot counts on compiled HLO), so on CPU the fraction reads as "how far
-from the accelerator roof this host is" — expect tiny numbers in interpret
-mode; the sweep's *ordering* is what the cache consumes.
+stage-1 matmul).  On a device with published peaks
+(``launch/roofline.PEAKS``, keyed by ``device_kind``) it also carries the
+attained-vs-peak fraction; on any other device (the CPU among them) no
+fraction is written — the sweep's *ordering* is what the cache consumes.
 
 The sweep results are appended under the ``"autotune"`` key of
 ``BENCH_stats.json`` (the rest of the record is `benchmarks/stats_backends.py`'s).
@@ -39,7 +37,7 @@ import numpy as np
 from repro.core import stats_backend
 from repro.kernels import autotune
 from repro.kernels.rolann_stats import ops
-from repro.launch.roofline import PEAK_FLOPS
+from repro.launch import roofline
 
 REPO_ROOT = Path(__file__).resolve().parent.parent
 
@@ -108,8 +106,15 @@ def _kind_flops(kind: str, m: int, n: int, o: int) -> float:
     return gram
 
 
+def _peak_flops() -> float | None:
+    """Published peak FLOP/s of the running device, or None off-table."""
+    kind = jax.devices()[0].device_kind
+    return roofline.PEAKS[kind].flops if kind in roofline.PEAKS else None
+
+
 def sweep(repeats: int) -> list[dict]:
     records = []
+    peak = _peak_flops()
     for m, n, o in SHAPES:
         p = _problem(m, n, o)
         for kind in ("stats", "stats_acc", "fused_chunk"):
@@ -138,14 +143,14 @@ def sweep(repeats: int) -> list[dict]:
                 "best_ms": best_s * 1e3,
                 "static_block_n": autotune.static_block_n(n),
                 "attained_gflops": flops / best_s / 1e9,
-                "peak_gflops_ref": PEAK_FLOPS / 1e9,
-                "attained_vs_peak": flops / best_s / PEAK_FLOPS,
             }
+            if peak is not None:
+                rec["peak_gflops"] = peak / 1e9
+                rec["attained_vs_peak"] = flops / best_s / peak
             records.append(rec)
             print(f"{kind} m={m} n={n} o={o}: best block {best_block} "
                   f"({rec['best_ms']:.2f} ms, "
-                  f"{rec['attained_gflops']:.2f} GFLOP/s, "
-                  f"{rec['attained_vs_peak']:.2e} of peak)")
+                  f"{rec['attained_gflops']:.2f} GFLOP/s)")
     return records
 
 
@@ -201,6 +206,9 @@ def main(repeats: int = 2, write_cache: bool = False) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--repeats", type=int, default=2)
     ap.add_argument("--write-cache", action="store_true",

@@ -341,6 +341,9 @@ def main(archs=None, gen: int = 24) -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--tenants", type=int, default=32)
     ap.add_argument("--pad", type=int, default=64)

@@ -113,6 +113,9 @@ def main(repeats: int = 3) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--repeats", type=int, default=3)
     ap.add_argument("--out", default=str(REPO_ROOT / "BENCH_stats.json"),

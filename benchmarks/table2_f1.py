@@ -153,4 +153,7 @@ def main(datasets=None, folds: int = 3) -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     print("\n".join(main()))

@@ -39,9 +39,8 @@ Rules (each finding carries file:line:col, a rule id and a fix hint):
   (``src/repro`` outside ``kernels/*/ref.py``): pins every caller to the
   Pallas interpreter, silently discarding accelerator compilation.
   Backend selection belongs to the resolver chain
-  (``rolann_stats.ops._resolve_interpret``: explicit arg >
-  ``set_interpret_override`` > ``$REPRO_KERNEL_INTERPRET`` > backend
-  probe); reference oracles under ``kernels/*/ref.py`` are exempt.
+  (``rolann_stats.ops._resolve_interpret``: explicit arg, else
+  interpret iff the backend is the CPU); reference oracles under ``kernels/*/ref.py`` are exempt.
 
 Escapes: append ``# repro-lint: disable=RPR001`` (comma-separate several
 ids) to a line to suppress findings on it, or grandfather existing
@@ -477,10 +476,9 @@ class _Checker(ast.NodeVisitor):
                         "call to the Pallas interpreter — accelerator "
                         "compilation is silently discarded for every caller",
                         "pass interpret through (None resolves via "
-                        "rolann_stats.ops._resolve_interpret: explicit arg > "
-                        "set_interpret_override > $REPRO_KERNEL_INTERPRET > "
-                        "backend probe); only kernels/*/ref.py oracles may "
-                        "pin it",
+                        "rolann_stats.ops._resolve_interpret: explicit arg, "
+                        "else interpret iff the backend is the CPU); only "
+                        "kernels/*/ref.py oracles may pin it",
                     )
 
         if self.privacy:

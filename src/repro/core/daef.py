@@ -124,6 +124,17 @@ class DAEFModel(NamedTuple):
     train_errors: Array                 # per-sample reconstruction MSE on train
 
 
+@jax.jit
+def sample_mse(recon: Array, x: Array) -> Array:
+    """Per-sample reconstruction MSE [n] of ``recon`` against ``x`` [m0, n].
+
+    Compiled even when called eagerly: inside a compiled reduction XLA
+    fuses the square into the sum (one rounding, as a fused multiply-add),
+    which op-by-op dispatch does not, so an eager fit and a compiled one
+    would otherwise disagree in the last bit of every train error."""
+    return jnp.mean((recon - x) ** 2, axis=0)
+
+
 def _acts(config: DAEFConfig):
     f_hl = activations.get(config.act_hidden, invertible_required=True)
     f_ll = activations.get(config.act_last, invertible_required=True)
@@ -137,14 +148,38 @@ def fit(config: DAEFConfig, x: Array, *, n_partitions: int = 1) -> DAEFModel:
     ROLANN merge paths exactly as the paper describes (the result is
     identical to n_partitions=1 up to numerics).
     """
+    fn, args, kw = _fit_call(config, x, n_partitions=n_partitions)
+    return fn(*args, **kw)
+
+
+def lower_fit(config: DAEFConfig, x: Array, *, chunk_samples: int | None = None):
+    """Lower the program that ``fit`` (or ``fit_chunked``, given
+    ``chunk_samples``) runs for ``x``: ``.compile().as_text()`` is what the
+    device executes, e.g. which Pallas kernels the fit launches."""
+    fn, args, kw = _fit_call(config, x, chunk_samples=chunk_samples)
+    return fn.lower(*args, **kw)
+
+
+def _fit_call(config: DAEFConfig, x: Array, *, n_partitions: int = 1,
+              chunk_samples: int | None = None):
+    """(compiled core, args, kwargs) that ``fit`` / ``fit_chunked`` run.
+
+    The whole fit is one program per (config, input shape): dispatched op
+    by op, a TPU would compile every small op of the pipeline on its own."""
     m0 = x.shape[0]
     if m0 != config.layer_sizes[0]:
         raise ValueError(f"input dim {m0} != layer_sizes[0] {config.layer_sizes[0]}")
     config = config.resolved()
-    return _fit_core(
-        config, x, config.layer_keys(), config.lam_hidden, config.lam_last,
-        n_partitions=n_partitions,
-    )
+    # The program reads the seed and the lambdas only through its arguments;
+    # keying it on the rest lets per-tenant configs share one compile.
+    shared = dataclasses.replace(config, seed=0, lam_hidden=0.0, lam_last=0.0)
+    args = (shared, x, config.layer_keys(), config.lam_hidden, config.lam_last)
+    if chunk_samples is None:
+        return _fit_program, args, {"n_partitions": n_partitions}
+    if not isinstance(chunk_samples, int) or chunk_samples < 1:
+        raise ValueError(f"chunk_samples must be a positive int, got {chunk_samples!r}")
+    _require_gram(config, "fit_chunked")
+    return _fit_chunked_program, args, {"chunk": chunk_samples}
 
 
 def _fit_core(
@@ -202,7 +237,7 @@ def _fit_core(
     biases.append(b_ll)
     knowledge.append(k_ll)
     recon = f_ll.fn(w_ll.T @ h + b_ll[:, None])
-    train_errors = jnp.mean((recon - x) ** 2, axis=0)
+    train_errors = sample_mse(recon, x)
 
     return DAEFModel(
         weights=tuple(weights),
@@ -212,6 +247,8 @@ def _fit_core(
         train_errors=train_errors,
     )
 
+
+_fit_program = jax.jit(_fit_core, static_argnames=("config", "n_partitions"))
 
 # ---------------------------------------------------------------------------
 # Streaming / chunked training (bounded-memory Alg. 1)
@@ -340,7 +377,7 @@ def _fit_chunked_core(
         xcg, _ = inp
         h = _stream_forward(config, xcg, tuple(weights[:-1]), tuple(biases[:-1]))
         recon = f_ll.fn(w_ll.T @ h + b_ll[:, None])
-        return carry, jnp.mean((recon - xcg) ** 2, axis=0)
+        return carry, sample_mse(recon, xcg)
 
     _, errs = jax.lax.scan(err_step, jnp.zeros((), x.dtype), (xc, mask))
     train_errors = errs.reshape(-1)[:n]
@@ -354,6 +391,9 @@ def _fit_chunked_core(
     )
 
 
+_fit_chunked_program = jax.jit(_fit_chunked_core,
+                               static_argnames=("config", "chunk"))
+
 def fit_chunked(config: DAEFConfig, x: Array, *, chunk_samples: int) -> DAEFModel:
     """Alg. 1 with bounded activation memory: `fit`, as a fold over
     ``chunk_samples``-wide sample chunks (see the section comment above).
@@ -362,17 +402,8 @@ def fit_chunked(config: DAEFConfig, x: Array, *, chunk_samples: int) -> DAEFMode
     error for every chunk size, including chunk widths that do not divide n
     (the ragged tail is padded and masked exactly).
     """
-    m0 = x.shape[0]
-    if m0 != config.layer_sizes[0]:
-        raise ValueError(f"input dim {m0} != layer_sizes[0] {config.layer_sizes[0]}")
-    if not isinstance(chunk_samples, int) or chunk_samples < 1:
-        raise ValueError(f"chunk_samples must be a positive int, got {chunk_samples!r}")
-    config = config.resolved()
-    _require_gram(config, "fit_chunked")
-    return _fit_chunked_core(
-        config, x, config.layer_keys(), config.lam_hidden, config.lam_last,
-        chunk=chunk_samples,
-    )
+    fn, args, kw = _fit_call(config, x, chunk_samples=chunk_samples)
+    return fn(*args, **kw)
 
 
 # ---- host-streaming driver (data never fully on device) ----
@@ -464,7 +495,7 @@ def _errors_chunk(config, params, x):
     _, f_ll = _acts(config)
     h = _stream_forward(config, x, weights[:-1], biases[:-1])
     recon = f_ll.fn(weights[-1].T @ h + biases[-1][:, None])
-    return jnp.mean((recon - x) ** 2, axis=0)
+    return sample_mse(recon, x)
 
 
 _stream_errors_chunk = partial(jax.jit, static_argnames=("config",))(_errors_chunk)
@@ -569,8 +600,7 @@ def predict(config: DAEFConfig, model: DAEFModel, x: Array) -> Array:
 
 def reconstruction_error(config: DAEFConfig, model: DAEFModel, x: Array) -> Array:
     """Per-sample MSE reconstruction error (the anomaly score)."""
-    recon = predict(config, model, x)
-    return jnp.mean((recon - x) ** 2, axis=0)
+    return sample_mse(predict(config, model, x), x)
 
 
 # ---------------------------------------------------------------------------

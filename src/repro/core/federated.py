@@ -161,7 +161,7 @@ def _federated_fit(
     knowledge.append(k_ll)
 
     errors = [
-        jnp.mean((f_ll.fn(w_ll.T @ h + b_ll[:, None]) - p) ** 2, axis=0)
+        daef.sample_mse(f_ll.fn(w_ll.T @ h + b_ll[:, None]), p)
         for h, p in zip(hs, partitions, strict=True)
     ]
     return daef.DAEFModel(

@@ -44,7 +44,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import daef, dsvd, fleet, rolann
 
 Array = jnp.ndarray
@@ -62,7 +61,8 @@ def tenant_mesh(n_devices: int | None = None) -> Mesh:
     n = avail if n_devices is None else n_devices
     if not 1 <= n <= avail:
         raise ValueError(f"need 1 <= n_devices <= {avail}, got {n}")
-    return compat.make_mesh((n,), (TENANT_AXIS,))
+    return jax.make_mesh((n,), (TENANT_AXIS,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def tenant_sharding(mesh: Mesh) -> NamedSharding:
@@ -140,16 +140,37 @@ def _fit_sharded(
     lam_hidden = jax.device_put(lam_hidden, spec)
     lam_last = jax.device_put(lam_last, spec)
     if chunk_samples is not None:
-        model = fleet._fleet_fit_chunked_kernel(
-            config, xs, seeds, lam_hidden, lam_last,
-            chunk_samples=chunk_samples,
-        )
+        fit = _per_shard(fleet._fleet_fit_chunked_kernel, config, mesh, 4,
+                         chunk_samples=chunk_samples)
     else:
-        model = fleet._fleet_fit(
-            config, xs, seeds, lam_hidden, lam_last, n_partitions=n_partitions
-        )
+        fit = _per_shard(fleet._fleet_fit, config, mesh, 4,
+                         n_partitions=n_partitions)
+    model = fit(xs, seeds, lam_hidden, lam_last)
     return fleet.DAEFFleet(model=model, seeds=seeds, lam_hidden=lam_hidden,
                            lam_last=lam_last)
+
+
+@functools.lru_cache(maxsize=None)
+def _per_shard(kernel, config: daef.DAEFConfig, mesh: Mesh, n_args: int,
+               donate: tuple[int, ...] = (), **static):
+    """``kernel(config, *args, **static)`` run on each device's own tenant
+    block, jitted once per (kernel, config, mesh, static).
+
+    The tenant-vmapped kernels have no cross-tenant data flow, but the
+    compiler cannot partition a Mosaic kernel inside them by itself: the
+    split over the tenant axis is spelled out as a shard_map.  Every
+    argument and output leaf leads with the tenant axis, so each stays
+    K/D per device (including leaves that are equal across tenants)."""
+    spec = P(TENANT_AXIS)
+    fn = jax.shard_map(
+        partial(kernel, config, **static),
+        mesh=mesh,
+        in_specs=(spec,) * n_args,
+        out_specs=spec,
+        axis_names={TENANT_AXIS},
+        check_vma=False,
+    )
+    return jax.jit(fn, donate_argnums=donate)
 
 
 def _fit_sharded_stream(
@@ -273,10 +294,10 @@ def sharded_fleet_partial_fit(
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable"
         )
-        model = _partial_fit_kernel(
-            config, fl.model, shard_batch(xs_new, mesh), fl.seeds,
-            fl.lam_hidden, fl.lam_last, chunk_samples=chunk_samples,
-        )
+        update = _per_shard(_partial_fit_kernel, config, mesh, 5,
+                            donate=(0,), chunk_samples=chunk_samples)
+        model = update(fl.model, shard_batch(xs_new, mesh), fl.seeds,
+                       fl.lam_hidden, fl.lam_last)
     return fleet.DAEFFleet(model=model, seeds=fl.seeds,
                            lam_hidden=fl.lam_hidden, lam_last=fl.lam_last)
 
@@ -361,7 +382,7 @@ def _merge_tree_fn(config: daef.DAEFConfig, mesh: Mesh, local_rounds: int,
         return merged, seeds, lam_hidden, lam_last
 
     spec = P(TENANT_AXIS)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec, spec),
@@ -537,7 +558,7 @@ def _state_tree_fn(config: daef.DAEFConfig, mesh: Mesh, local_rounds: int,
         return state
 
     spec = P(TENANT_AXIS)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec),

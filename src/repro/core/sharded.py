@@ -15,7 +15,7 @@ non-iterative and shallow (<= ~8 layers), so unrolling is the right call.
 """
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import Sequence
 
 import jax
@@ -24,7 +24,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import activations, daef, dsvd, elm_ae, rolann
 
 Array = jnp.ndarray
@@ -37,7 +36,7 @@ def _replicated(x: Array, axes) -> Array:
     reduce is noise next to the gather itself)."""
     denom = 1.0
     for ax in axes:
-        denom = denom * compat.axis_size(ax)
+        denom = denom * lax.axis_size(ax)
     return lax.psum(x, axes) / denom
 
 
@@ -115,18 +114,35 @@ def _fit_on_mesh(
     Returns a DAEFModel whose weights are replicated and whose train_errors
     remain sharded over the data axes.
     """
-    config = config.resolved()
+    axes = tuple(data_axes)
+    fit = _mesh_fit_program(config.resolved(), mesh, axes, local_factorization)
+    x = jax.device_put(x, NamedSharding(mesh, P(None, axes)))
+    weights, biases, (enc_u, enc_s), knowledge, errors = fit(x)
+    return daef.DAEFModel(
+        weights=weights,
+        biases=biases,
+        encoder_factors=dsvd.SvdFactors(u=enc_u, s=enc_s),
+        layer_knowledge=knowledge,
+        train_errors=errors,
+    )
+
+
+@lru_cache(maxsize=None)
+def _mesh_fit_program(config: daef.DAEFConfig, mesh: Mesh, axes: tuple,
+                      local_factorization: str):
+    """The data-sharded fit as one program, compiled once per (config,
+    mesh, axes, factorization).  Dispatched op by op, a TPU would compile
+    every small op of the shard_map body on its own."""
     f_hl = activations.get(config.act_hidden, invertible_required=True)
     f_ll = activations.get(config.act_last, invertible_required=True)
     keys = config.layer_keys()
     sizes = config.layer_sizes
     use_gram = config.method == "gram"
-    axes = tuple(data_axes)
 
     def node(xp: Array):
         # ---------------- encoder ----------------
         if use_gram:
-            g = _psum(xp @ xp.T, axes)
+            g = _psum(dsvd.gram(xp), axes)
             enc_u, enc_s = dsvd.gram_to_factors(g)
         else:
             # Local factors: eigh of the local Gram (default) carries the
@@ -191,7 +207,7 @@ def _fit_on_mesh(
         knowledge.append(merged)
 
         recon = f_ll.fn(w_ll.T @ h + b_ll[:, None])
-        errors = jnp.mean((recon - xp) ** 2, axis=0)
+        errors = daef.sample_mse(recon, xp)
         return (
             tuple(weights),
             tuple(biases),
@@ -206,22 +222,14 @@ def _fit_on_mesh(
     # Manual collectives over the data axes only; the model axis stays
     # "auto" so XLA shards the per-output ROLANN solves across it (the
     # paper's per-core output parallelism, TPU-native — DESIGN.md §2).
-    fn = compat.shard_map(
+    return jax.jit(jax.shard_map(
         node,
         mesh=mesh,
         in_specs=(data_spec,),
         out_specs=out_specs,
         axis_names=set(axes),
         check_vma=True,
-    )
-    weights, biases, (enc_u, enc_s), knowledge, errors = fn(x)
-    return daef.DAEFModel(
-        weights=weights,
-        biases=biases,
-        encoder_factors=dsvd.SvdFactors(u=enc_u, s=enc_s),
-        layer_knowledge=knowledge,
-        train_errors=errors,
-    )
+    ))
 
 
 def predict_on_mesh(

@@ -55,6 +55,13 @@ DEFAULT = AUTO
 Array = jnp.ndarray
 
 
+def _einsum_stats(xa: Array, fsq: Array, fd: Array) -> tuple[Array, Array]:
+    """Unfused (G, M); ``...`` is an optional leading tenant axis."""
+    g = jnp.einsum("...in,...on,...jn->...oij", xa, fsq, xa)
+    m = jnp.einsum("...in,...on->...oi", xa, fd)
+    return g, m
+
+
 def _resolve_auto() -> str:
     """Measured winner for this platform from the committed autotune cache
     (einsum where unmeasured/unknown — see ``autotune.preferred_backend``)."""
@@ -87,9 +94,7 @@ def _gram_stats_unbatched(xa: Array, fsq: Array, fd: Array, backend: str):
         from repro.kernels.rolann_stats import rolann_stats
 
         return rolann_stats(xa, fsq, fd)
-    g = jnp.einsum("in,on,jn->oij", xa, fsq, xa)
-    m = jnp.einsum("in,on->oi", xa, fd)
-    return g, m
+    return _einsum_stats(xa, fsq, fd)
 
 
 @functools.lru_cache(maxsize=None)
@@ -152,9 +157,7 @@ def gram_stats_batched(
         from repro.kernels.rolann_stats import rolann_stats_batched
 
         return rolann_stats_batched(xa, fsq, fd)
-    g = jnp.einsum("kin,kon,kjn->koij", xa, fsq, xa)
-    m = jnp.einsum("kin,kon->koi", xa, fd)
-    return g, m
+    return _einsum_stats(xa, fsq, fd)
 
 
 # ---------------------------------------------------------------------------
@@ -168,9 +171,8 @@ def _gram_stats_acc_unbatched(g, m, xa, fsq, fd, backend: str):
         from repro.kernels.rolann_stats import rolann_stats_acc
 
         return rolann_stats_acc(g, m, xa, fsq, fd)
-    g = g + jnp.einsum("in,on,jn->oij", xa, fsq, xa)
-    m = m + jnp.einsum("in,on->oi", xa, fd)
-    return g, m
+    dg, dm = _einsum_stats(xa, fsq, fd)
+    return g + dg, m + dm
 
 
 @functools.lru_cache(maxsize=None)
@@ -228,9 +230,8 @@ def gram_stats_acc_batched(
         from repro.kernels.rolann_stats import rolann_stats_acc_batched
 
         return rolann_stats_acc_batched(g, m, xa, fsq, fd)
-    g = g + jnp.einsum("kin,kon,kjn->koij", xa, fsq, xa)
-    m = m + jnp.einsum("kin,kon->koi", xa, fd)
-    return g, m
+    dg, dm = _einsum_stats(xa, fsq, fd)
+    return g + dg, m + dm
 
 
 # ---------------------------------------------------------------------------
@@ -271,9 +272,8 @@ def _fused_chunk_acc_unbatched(g, m, h, w, b, mask, act_name: str,
     fsq, fd = _fused_chunk_targets(h, act)
     fsq = fsq * mask[None, :]
     fd = fd * mask[None, :]
-    g = g + jnp.einsum("in,on,jn->oij", xa, fsq, xa)
-    m = m + jnp.einsum("in,on->oi", xa, fd)
-    return g, m
+    dg, dm = _einsum_stats(xa, fsq, fd)
+    return g + dg, m + dm
 
 
 @functools.lru_cache(maxsize=None)
@@ -366,9 +366,8 @@ def fused_chunk_acc_batched(
     fsq, fd = _fused_chunk_targets(h, act_obj)
     fsq = fsq * mask[:, None, :]
     fd = fd * mask[:, None, :]
-    g = g + jnp.einsum("kin,kon,kjn->koij", xa, fsq, xa)
-    m = m + jnp.einsum("kin,kon->koi", xa, fd)
-    return g, m
+    dg, dm = _einsum_stats(xa, fsq, fd)
+    return g + dg, m + dm
 
 
 __all__ = ["AUTO", "BACKENDS", "ENV_VAR", "DEFAULT", "resolve", "gram_stats",

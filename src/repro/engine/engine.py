@@ -222,15 +222,16 @@ class DAEFEngine:
                 f"cannot auto-build a mesh for axes {plan.mesh_axes}; pass "
                 "mesh= explicitly (e.g. launch.mesh.make_production_mesh())"
             )
-        from repro import compat
-
         n = plan.mesh_devices or avail
         if n > avail:
             raise PlanError(
                 f"bad mesh size: mesh_devices={n} exceeds the {avail} "
                 "available device(s)"
             )
-        return compat.make_mesh((n,), plan.mesh_axes)
+        return jax.make_mesh(
+            (n,), plan.mesh_axes,
+            axis_types=(jax.sharding.AxisType.Auto,) * len(plan.mesh_axes),
+        )
 
     # ------------------------------------------------------------------
     # Input handling
@@ -608,7 +609,7 @@ class DAEFEngine:
                 recon = sharded.predict_on_mesh(
                     cfg, state, x, self.mesh, data_axes=plan.mesh_axes
                 )
-                return jnp.mean((recon - x) ** 2, axis=0)
+                return daef.sample_mse(recon, x)
             return daef.reconstruction_error(cfg, state, x)
         self._check_x(x, what="scores")
         if plan.mode == "loop":

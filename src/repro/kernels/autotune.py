@@ -201,8 +201,8 @@ def preferred_backend(platform: str | None = None) -> str:
 
     Reads ``platforms.<platform>.preferred_backend`` from the cache;
     anything missing or unrecognized resolves to ``"einsum"`` — the safe
-    default on hardware nobody has measured (including CPU, where the fused
-    kernel only runs in interpret mode).
+    default on hardware nobody has measured — and says so once per process,
+    so an unmeasured accelerator never silently runs the unfused path.
     """
     plat = platform if platform is not None else _default_platform()
     entry = load_cache().get("platforms", {}).get(plat, {})
@@ -214,6 +214,14 @@ def preferred_backend(platform: str | None = None) -> str:
             f"pref:{plat}",
             f"autotune cache names unknown preferred_backend {pref!r} for "
             f"platform {plat!r}; resolving 'auto' to 'einsum'",
+        )
+    else:
+        _warn_once(
+            f"unmeasured:{plat}",
+            f"autotune cache {cache_path()} has no preferred_backend for "
+            f"platform {plat!r}; stats_backend 'auto' resolves to 'einsum' "
+            "unmeasured — measure with benchmarks/kernel_autotune.py "
+            "--write-cache, or pass stats_backend explicitly",
         )
     return "einsum"
 

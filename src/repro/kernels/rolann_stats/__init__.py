@@ -6,5 +6,4 @@ from repro.kernels.rolann_stats.ops import (  # noqa: F401
     rolann_stats_acc_batched,
     rolann_stats_batched,
     rolann_stats_ref,
-    set_interpret_override,
 )

@@ -3,7 +3,7 @@
 One pass over the sample axis computes, per output neuron o,
 
     G[o] += (X_tile * fsq[o]) @ X_tile^T        (MXU)
-    M[o] += X_tile @ fd[o]                      (MXU, rank-1 of the same tile)
+    M[o] += fd[o] @ X_tile^T                    (MXU, rank-1 of the same tile)
 
 instead of three separate HBM passes (scale, Gram matmul, M matvec).  The
 sample axis is streamed HBM->VMEM in ``block_n`` tiles; the [m, m]
@@ -13,6 +13,16 @@ accumulator lives in VMEM scratch across the sequential ``n`` grid dimension
 Grid: (outputs, n_tiles) — n iterates innermost (sequential on TPU), so the
 accumulator carries correctly; outputs are independent (parallelizable /
 shardable over the ``model`` mesh axis at the ops level).
+
+Block layout (what Mosaic accepts): the last two dimensions of every block
+must be multiples of (8, 128) or span the whole array axis.  A per-output
+row is therefore never a ``(1, block_n)`` block of an ``[o, n]`` array —
+the wrappers below give every per-output operand a unit axis
+(``[o, 1, n]`` in, ``[o, 1, m]`` out), so its block is ``(1, 1, block_n)``
+/ ``(1, 1, m)`` with the unit axis spanning the array.  Both contractions
+are ``A @ B^T`` (contract the lane axis of both operands), so no in-kernel
+transpose is needed.  The dots run at the default matmul precision, as the
+einsum backend does.
 """
 from __future__ import annotations
 
@@ -24,8 +34,26 @@ from jax.experimental import pallas as pl
 
 from repro.core import activations
 
+_NT = (((1,), (1,)), ((), ()))   # A @ B^T: contract the lane axes
 
-def _kernel(x_ref, fsq_ref, fd_ref, g_ref, m_ref, *, n_tiles: int):
+
+def _dot_nt(a, b):
+    return jax.lax.dot_general(
+        a, b, _NT, preferred_element_type=jnp.float32,
+    )
+
+
+def _tile_deltas(x, fsq, fd):
+    """This tile's (ΔG [m, m], ΔM [1, m]) for x [m, bn], fsq/fd [1, bn]."""
+    return _dot_nt(x * fsq, x), _dot_nt(fd, x)
+
+
+def _rows(a):
+    """[..., o, n] -> [..., o, 1, n]: one unit-axis row per output."""
+    return jnp.expand_dims(a, -2)
+
+
+def _kernel(x_ref, fsq_ref, fd_ref, g_ref, m_ref):
     ni = pl.program_id(1)
 
     @pl.when(ni == 0)
@@ -33,16 +61,9 @@ def _kernel(x_ref, fsq_ref, fd_ref, g_ref, m_ref, *, n_tiles: int):
         g_ref[...] = jnp.zeros_like(g_ref)
         m_ref[...] = jnp.zeros_like(m_ref)
 
-    x = x_ref[...]                       # [m, bn]
-    fsq = fsq_ref[...]                   # [1, bn]
-    fd = fd_ref[...]                     # [1, bn]
-    scaled = x * fsq                     # VPU
-    g_ref[0] += jax.lax.dot_general(
-        scaled, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] += jax.lax.dot_general(
-        x, fd, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ).T
+    dg, dm = _tile_deltas(x_ref[...], fsq_ref[0], fd_ref[0])
+    g_ref[0] += dg
+    m_ref[0] += dm
 
 
 def rolann_stats_kernel(
@@ -59,24 +80,25 @@ def rolann_stats_kernel(
     assert n % block_n == 0, (n, block_n)
     n_tiles = n // block_n
 
-    return pl.pallas_call(
-        functools.partial(_kernel, n_tiles=n_tiles),
+    g, mv = pl.pallas_call(
+        _kernel,
         grid=(o, n_tiles),
         in_specs=[
             pl.BlockSpec((m, block_n), lambda oi, ni: (0, ni)),
-            pl.BlockSpec((1, block_n), lambda oi, ni: (oi, ni)),
-            pl.BlockSpec((1, block_n), lambda oi, ni: (oi, ni)),
+            pl.BlockSpec((1, 1, block_n), lambda oi, ni: (oi, 0, ni)),
+            pl.BlockSpec((1, 1, block_n), lambda oi, ni: (oi, 0, ni)),
         ],
         out_specs=[
             pl.BlockSpec((1, m, m), lambda oi, ni: (oi, 0, 0)),
-            pl.BlockSpec((1, m), lambda oi, ni: (oi, 0)),
+            pl.BlockSpec((1, 1, m), lambda oi, ni: (oi, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((o, m, m), jnp.float32),
-            jax.ShapeDtypeStruct((o, m), jnp.float32),
+            jax.ShapeDtypeStruct((o, 1, m), jnp.float32),
         ],
         interpret=interpret,
-    )(xa, fsq, fd)
+    )(xa, _rows(fsq), _rows(fd))
+    return g, mv[:, 0]
 
 
 def _kernel_batched(x_ref, fsq_ref, fd_ref, g_ref, m_ref):
@@ -87,16 +109,9 @@ def _kernel_batched(x_ref, fsq_ref, fd_ref, g_ref, m_ref):
         g_ref[...] = jnp.zeros_like(g_ref)
         m_ref[...] = jnp.zeros_like(m_ref)
 
-    x = x_ref[0]                         # [m, bn]
-    fsq = fsq_ref[0]                     # [1, bn]
-    fd = fd_ref[0]                       # [1, bn]
-    scaled = x * fsq                     # VPU
-    g_ref[0, 0] += jax.lax.dot_general(
-        scaled, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[0] += jax.lax.dot_general(
-        x, fd, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ).T
+    dg, dm = _tile_deltas(x_ref[0], fsq_ref[0, 0], fd_ref[0, 0])
+    g_ref[0, 0] += dg
+    m_ref[0, 0] += dm
 
 
 def rolann_stats_kernel_batched(
@@ -119,24 +134,25 @@ def rolann_stats_kernel_batched(
     assert n % block_n == 0, (n, block_n)
     n_tiles = n // block_n
 
-    return pl.pallas_call(
+    g, mv = pl.pallas_call(
         _kernel_batched,
         grid=(k, o, n_tiles),
         in_specs=[
             pl.BlockSpec((1, m, block_n), lambda ki, oi, ni: (ki, 0, ni)),
-            pl.BlockSpec((1, 1, block_n), lambda ki, oi, ni: (ki, oi, ni)),
-            pl.BlockSpec((1, 1, block_n), lambda ki, oi, ni: (ki, oi, ni)),
+            pl.BlockSpec((1, 1, 1, block_n), lambda ki, oi, ni: (ki, oi, 0, ni)),
+            pl.BlockSpec((1, 1, 1, block_n), lambda ki, oi, ni: (ki, oi, 0, ni)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, m, m), lambda ki, oi, ni: (ki, oi, 0, 0)),
-            pl.BlockSpec((1, 1, m), lambda ki, oi, ni: (ki, oi, 0)),
+            pl.BlockSpec((1, 1, 1, m), lambda ki, oi, ni: (ki, oi, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((k, o, m, m), jnp.float32),
-            jax.ShapeDtypeStruct((k, o, m), jnp.float32),
+            jax.ShapeDtypeStruct((k, o, 1, m), jnp.float32),
         ],
         interpret=interpret,
-    )(xa, fsq, fd)
+    )(xa, _rows(fsq), _rows(fd))
+    return g, mv[:, :, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -156,16 +172,9 @@ def _kernel_acc(g_in_ref, m_in_ref, x_ref, fsq_ref, fd_ref, g_ref, m_ref):
         g_ref[...] = g_in_ref[...]
         m_ref[...] = m_in_ref[...]
 
-    x = x_ref[...]                       # [m, bn]
-    fsq = fsq_ref[...]                   # [1, bn]
-    fd = fd_ref[...]                     # [1, bn]
-    scaled = x * fsq                     # VPU
-    g_ref[0] += jax.lax.dot_general(
-        scaled, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] += jax.lax.dot_general(
-        x, fd, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ).T
+    dg, dm = _tile_deltas(x_ref[...], fsq_ref[0], fd_ref[0])
+    g_ref[0] += dg
+    m_ref[0] += dm
 
 
 def rolann_stats_kernel_acc(
@@ -185,27 +194,28 @@ def rolann_stats_kernel_acc(
     assert n % block_n == 0, (n, block_n)
     n_tiles = n // block_n
 
-    return pl.pallas_call(
+    g, mv = pl.pallas_call(
         _kernel_acc,
         grid=(o, n_tiles),
         in_specs=[
             pl.BlockSpec((1, m, m), lambda oi, ni: (oi, 0, 0)),
-            pl.BlockSpec((1, m), lambda oi, ni: (oi, 0)),
+            pl.BlockSpec((1, 1, m), lambda oi, ni: (oi, 0, 0)),
             pl.BlockSpec((m, block_n), lambda oi, ni: (0, ni)),
-            pl.BlockSpec((1, block_n), lambda oi, ni: (oi, ni)),
-            pl.BlockSpec((1, block_n), lambda oi, ni: (oi, ni)),
+            pl.BlockSpec((1, 1, block_n), lambda oi, ni: (oi, 0, ni)),
+            pl.BlockSpec((1, 1, block_n), lambda oi, ni: (oi, 0, ni)),
         ],
         out_specs=[
             pl.BlockSpec((1, m, m), lambda oi, ni: (oi, 0, 0)),
-            pl.BlockSpec((1, m), lambda oi, ni: (oi, 0)),
+            pl.BlockSpec((1, 1, m), lambda oi, ni: (oi, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((o, m, m), jnp.float32),
-            jax.ShapeDtypeStruct((o, m), jnp.float32),
+            jax.ShapeDtypeStruct((o, 1, m), jnp.float32),
         ],
         input_output_aliases={0: 0, 1: 1},
         interpret=interpret,
-    )(g, mv, xa, fsq, fd)
+    )(g, _rows(mv), xa, _rows(fsq), _rows(fd))
+    return g, mv[:, 0]
 
 
 def _kernel_acc_batched(g_in_ref, m_in_ref, x_ref, fsq_ref, fd_ref, g_ref, m_ref):
@@ -216,16 +226,9 @@ def _kernel_acc_batched(g_in_ref, m_in_ref, x_ref, fsq_ref, fd_ref, g_ref, m_ref
         g_ref[...] = g_in_ref[...]
         m_ref[...] = m_in_ref[...]
 
-    x = x_ref[0]                         # [m, bn]
-    fsq = fsq_ref[0]                     # [1, bn]
-    fd = fd_ref[0]                       # [1, bn]
-    scaled = x * fsq                     # VPU
-    g_ref[0, 0] += jax.lax.dot_general(
-        scaled, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[0] += jax.lax.dot_general(
-        x, fd, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ).T
+    dg, dm = _tile_deltas(x_ref[0], fsq_ref[0, 0], fd_ref[0, 0])
+    g_ref[0, 0] += dg
+    m_ref[0, 0] += dm
 
 
 def rolann_stats_kernel_acc_batched(
@@ -245,27 +248,28 @@ def rolann_stats_kernel_acc_batched(
     assert n % block_n == 0, (n, block_n)
     n_tiles = n // block_n
 
-    return pl.pallas_call(
+    g, mv = pl.pallas_call(
         _kernel_acc_batched,
         grid=(k, o, n_tiles),
         in_specs=[
             pl.BlockSpec((1, 1, m, m), lambda ki, oi, ni: (ki, oi, 0, 0)),
-            pl.BlockSpec((1, 1, m), lambda ki, oi, ni: (ki, oi, 0)),
+            pl.BlockSpec((1, 1, 1, m), lambda ki, oi, ni: (ki, oi, 0, 0)),
             pl.BlockSpec((1, m, block_n), lambda ki, oi, ni: (ki, 0, ni)),
-            pl.BlockSpec((1, 1, block_n), lambda ki, oi, ni: (ki, oi, ni)),
-            pl.BlockSpec((1, 1, block_n), lambda ki, oi, ni: (ki, oi, ni)),
+            pl.BlockSpec((1, 1, 1, block_n), lambda ki, oi, ni: (ki, oi, 0, ni)),
+            pl.BlockSpec((1, 1, 1, block_n), lambda ki, oi, ni: (ki, oi, 0, ni)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, m, m), lambda ki, oi, ni: (ki, oi, 0, 0)),
-            pl.BlockSpec((1, 1, m), lambda ki, oi, ni: (ki, oi, 0)),
+            pl.BlockSpec((1, 1, 1, m), lambda ki, oi, ni: (ki, oi, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((k, o, m, m), jnp.float32),
-            jax.ShapeDtypeStruct((k, o, m), jnp.float32),
+            jax.ShapeDtypeStruct((k, o, 1, m), jnp.float32),
         ],
         input_output_aliases={0: 0, 1: 1},
         interpret=interpret,
-    )(g, mv, xa, fsq, fd)
+    )(g, _rows(mv), xa, _rows(fsq), _rows(fd))
+    return g, mv[:, :, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -276,6 +280,11 @@ def rolann_stats_kernel_acc_batched(
 # the unfused path materializes it to HBM between the XLA matmul and the
 # stats kernel, paying a [m_c1, n] round-trip per chunk per layer.
 #
+# The stage-1 weights arrive pre-augmented: ``wt`` = [W_c1 | 0]^T [ma, m_l]
+# and ``bt`` = [b_c1; 0] [ma, 1], so ``wt @ h + bt`` is a plain NN matmul
+# whose last row is then overwritten with the bias row of ones (an iota
+# select — no sublane concatenate inside the kernel).
+#
 # Cost note: the stage-1 matmul is recomputed once per OUTPUT grid step (the
 # target row changes, the activation does not) — o * 2*m_l*m_c1*block_n
 # redundant FLOPs per tile.  DAEF layer widths are small (tens), so the fold
@@ -283,26 +292,22 @@ def rolann_stats_kernel_acc_batched(
 # is the right side of the roofline; see docs/kernels.md.
 # ---------------------------------------------------------------------------
 
-def _fused_chunk_deltas(act, xa, d, mask):
-    """Shared tail of the fused-chunk kernels: target transform + this tile's
-    (ΔG, ΔM) contribution (the callers fold these into the output refs)."""
+def _fused_chunk_deltas(act, h, wt, bt, d, mask):
+    """Shared body of the fused-chunk kernels: stage-1 activation with the
+    bias row, target transform, and this tile's (ΔG, ΔM) contribution."""
+    z = jax.lax.dot_general(                 # W_c1^T h  (MXU)
+        wt, h, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+    ) + bt
+    row = jax.lax.broadcasted_iota(jnp.int32, z.shape, 0)
+    xa = jnp.where(row == z.shape[0] - 1, 1.0, act.fn(z))   # [ma, bn]
     dbar = act.inv(act.clip_to_range(d))     # [1, bn]
     fp = act.deriv(dbar)
     fsq = fp * fp
     fd = fsq * dbar
-    fsq = fsq * mask                         # padded columns contribute 0
-    fd = fd * mask
-    scaled = xa * fsq                        # VPU
-    dg = jax.lax.dot_general(
-        scaled, xa, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    dm = jax.lax.dot_general(
-        xa, fd, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ).T                                      # [1, ma]
-    return dg, dm
+    return _tile_deltas(xa, fsq * mask, fd * mask)   # padded columns -> 0
 
 
-def _kernel_fused_chunk(g_in_ref, m_in_ref, h_ref, d_ref, w_ref, b_ref,
+def _kernel_fused_chunk(g_in_ref, m_in_ref, h_ref, d_ref, wt_ref, bt_ref,
                         mask_ref, g_ref, m_ref, *, act_name: str):
     ni = pl.program_id(1)
 
@@ -312,19 +317,19 @@ def _kernel_fused_chunk(g_in_ref, m_in_ref, h_ref, d_ref, w_ref, b_ref,
         m_ref[...] = m_in_ref[...]
 
     act = activations.get(act_name, invertible_required=True)
-    h = h_ref[...]                           # [m_l, bn]
-    w = w_ref[...]                           # [m_l, m_c1]
-    b = b_ref[...]                           # [m_c1, 1]
-    z = jax.lax.dot_general(                 # W_c1^T h  (MXU)
-        w, h, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    ) + b
-    a = act.fn(z)                            # [m_c1, bn], never leaves VMEM
-    xa = jnp.concatenate(                    # bias-row augmentation
-        [a, jnp.ones((1, a.shape[1]), a.dtype)], axis=0
-    )
-    dg, dm = _fused_chunk_deltas(act, xa, d_ref[...], mask_ref[...])
+    dg, dm = _fused_chunk_deltas(act, h_ref[...], wt_ref[...], bt_ref[...],
+                                 d_ref[0], mask_ref[...])
     g_ref[0] += dg
-    m_ref[...] += dm
+    m_ref[0] += dm
+
+
+def _augment_stage1(w, b):
+    """(W_c1 [.., m_l, m_c1], b_c1 [.., m_c1, 1]) -> the kernel's
+    (wt [.., ma, m_l], bt [.., ma, 1]) with a zero bias row appended."""
+    row = [(0, 0)] * (w.ndim - 2)
+    wt = jnp.pad(jnp.swapaxes(w, -1, -2), row + [(0, 1), (0, 0)])
+    bt = jnp.pad(b, row + [(0, 1), (0, 0)])
+    return wt, bt
 
 
 def rolann_fused_chunk_kernel(
@@ -342,44 +347,46 @@ def rolann_fused_chunk_kernel(
     """One launch: recompute the chunk activation and fold (g, mv) in place.
 
     ``h`` is read through TWO block specs — the full [m_l, block] tile feeds
-    the stage-1 matmul, and the [1, block] row of the current output feeds
-    the target transform (ELM-AE reconstructs its own input, so targets ARE
-    ``h``).  The accumulators alias onto the outputs exactly like
-    ``rolann_stats_kernel_acc``.
+    the stage-1 matmul, and the current output's row (of the unit-axis view
+    ``[m_l, 1, n]``) feeds the target transform (ELM-AE reconstructs its own
+    input, so targets ARE ``h``).  The accumulators alias onto the outputs
+    exactly like ``rolann_stats_kernel_acc``.
     """
     o, ma, _ = g.shape
     m_l, n = h.shape
     block_n = min(block_n, n)
     assert n % block_n == 0, (n, block_n)
     n_tiles = n // block_n
+    wt, bt = _augment_stage1(w, b)
 
-    return pl.pallas_call(
+    g, mv = pl.pallas_call(
         functools.partial(_kernel_fused_chunk, act_name=act_name),
         grid=(o, n_tiles),
         in_specs=[
             pl.BlockSpec((1, ma, ma), lambda oi, ni: (oi, 0, 0)),
-            pl.BlockSpec((1, ma), lambda oi, ni: (oi, 0)),
+            pl.BlockSpec((1, 1, ma), lambda oi, ni: (oi, 0, 0)),
             pl.BlockSpec((m_l, block_n), lambda oi, ni: (0, ni)),
-            pl.BlockSpec((1, block_n), lambda oi, ni: (oi, ni)),
-            pl.BlockSpec(w.shape, lambda oi, ni: (0, 0)),
-            pl.BlockSpec(b.shape, lambda oi, ni: (0, 0)),
+            pl.BlockSpec((1, 1, block_n), lambda oi, ni: (oi, 0, ni)),
+            pl.BlockSpec(wt.shape, lambda oi, ni: (0, 0)),
+            pl.BlockSpec(bt.shape, lambda oi, ni: (0, 0)),
             pl.BlockSpec((1, block_n), lambda oi, ni: (0, ni)),
         ],
         out_specs=[
             pl.BlockSpec((1, ma, ma), lambda oi, ni: (oi, 0, 0)),
-            pl.BlockSpec((1, ma), lambda oi, ni: (oi, 0)),
+            pl.BlockSpec((1, 1, ma), lambda oi, ni: (oi, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((o, ma, ma), jnp.float32),
-            jax.ShapeDtypeStruct((o, ma), jnp.float32),
+            jax.ShapeDtypeStruct((o, 1, ma), jnp.float32),
         ],
         input_output_aliases={0: 0, 1: 1},
         interpret=interpret,
-    )(g, mv, h, h, w, b, mask)
+    )(g, _rows(mv), h, _rows(h), wt, bt, mask)
+    return g, mv[:, 0]
 
 
-def _kernel_fused_chunk_batched(g_in_ref, m_in_ref, h_ref, d_ref, w_ref,
-                                b_ref, mask_ref, g_ref, m_ref, *,
+def _kernel_fused_chunk_batched(g_in_ref, m_in_ref, h_ref, d_ref, wt_ref,
+                                bt_ref, mask_ref, g_ref, m_ref, *,
                                 act_name: str):
     ni = pl.program_id(2)
 
@@ -389,17 +396,10 @@ def _kernel_fused_chunk_batched(g_in_ref, m_in_ref, h_ref, d_ref, w_ref,
         m_ref[...] = m_in_ref[...]
 
     act = activations.get(act_name, invertible_required=True)
-    h = h_ref[0]                             # [m_l, bn]
-    w = w_ref[0]                             # [m_l, m_c1]
-    b = b_ref[0]                             # [m_c1, 1]
-    z = jax.lax.dot_general(
-        w, h, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    ) + b
-    a = act.fn(z)
-    xa = jnp.concatenate([a, jnp.ones((1, a.shape[1]), a.dtype)], axis=0)
-    dg, dm = _fused_chunk_deltas(act, xa, d_ref[0], mask_ref[0])
+    dg, dm = _fused_chunk_deltas(act, h_ref[0], wt_ref[0], bt_ref[0],
+                                 d_ref[0, 0], mask_ref[0])
     g_ref[0, 0] += dg
-    m_ref[0] += dm
+    m_ref[0, 0] += dm
 
 
 def rolann_fused_chunk_kernel_batched(
@@ -422,27 +422,29 @@ def rolann_fused_chunk_kernel_batched(
     block_n = min(block_n, n)
     assert n % block_n == 0, (n, block_n)
     n_tiles = n // block_n
+    wt, bt = _augment_stage1(w, b)
 
-    return pl.pallas_call(
+    g, mv = pl.pallas_call(
         functools.partial(_kernel_fused_chunk_batched, act_name=act_name),
         grid=(k, o, n_tiles),
         in_specs=[
             pl.BlockSpec((1, 1, ma, ma), lambda ki, oi, ni: (ki, oi, 0, 0)),
-            pl.BlockSpec((1, 1, ma), lambda ki, oi, ni: (ki, oi, 0)),
+            pl.BlockSpec((1, 1, 1, ma), lambda ki, oi, ni: (ki, oi, 0, 0)),
             pl.BlockSpec((1, m_l, block_n), lambda ki, oi, ni: (ki, 0, ni)),
-            pl.BlockSpec((1, 1, block_n), lambda ki, oi, ni: (ki, oi, ni)),
-            pl.BlockSpec((1, *w.shape[1:]), lambda ki, oi, ni: (ki, 0, 0)),
-            pl.BlockSpec((1, *b.shape[1:]), lambda ki, oi, ni: (ki, 0, 0)),
+            pl.BlockSpec((1, 1, 1, block_n), lambda ki, oi, ni: (ki, oi, 0, ni)),
+            pl.BlockSpec((1, *wt.shape[1:]), lambda ki, oi, ni: (ki, 0, 0)),
+            pl.BlockSpec((1, *bt.shape[1:]), lambda ki, oi, ni: (ki, 0, 0)),
             pl.BlockSpec((1, 1, block_n), lambda ki, oi, ni: (ki, 0, ni)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, ma, ma), lambda ki, oi, ni: (ki, oi, 0, 0)),
-            pl.BlockSpec((1, 1, ma), lambda ki, oi, ni: (ki, oi, 0)),
+            pl.BlockSpec((1, 1, 1, ma), lambda ki, oi, ni: (ki, oi, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((k, o, ma, ma), jnp.float32),
-            jax.ShapeDtypeStruct((k, o, ma), jnp.float32),
+            jax.ShapeDtypeStruct((k, o, 1, ma), jnp.float32),
         ],
         input_output_aliases={0: 0, 1: 1},
         interpret=interpret,
-    )(g, mv, h, h, w, b, mask)
+    )(g, _rows(mv), h, _rows(h), wt, bt, mask)
+    return g, mv[:, :, 0]

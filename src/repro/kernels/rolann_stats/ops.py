@@ -1,6 +1,6 @@
 """Jit'd wrappers for the fused ROLANN statistics kernel.
 
-On CPU (this container) the kernel body runs in interpret mode; on TPU it
+On the CPU backend the kernel body runs in interpret mode; on TPU it
 compiles to a Mosaic kernel.  ``rolann_stats`` pads the sample axis to the
 block size (zero samples contribute nothing to either G or M, so padding is
 exact) and short-circuits degenerate shapes (empty sample/feature/output
@@ -13,15 +13,12 @@ returned in the *promoted input dtype* — bf16 in, bf16 out; f64 in (under
 is that f64 inputs still accumulate in f32 inside the kernel, so the fused
 backend trades ~1e-7 relative error for the fusion win on x64 runs.
 
-``interpret`` resolution (None -> "am I on CPU?") happens *outside* the
-jitted body: the resolved value is part of the jit cache key, so a cached
-trace can never bake a stale backend decision in after the default backend
-changes.  The backend probe itself is cached module-wide (one
-``jax.default_backend()`` call per process instead of one per op call); if
-your process initializes an accelerator AFTER the first kernel call — rare,
-but possible with late ``jax.distributed`` setup — flip the decision
-explicitly via :func:`set_interpret_override`, the
-``$REPRO_KERNEL_INTERPRET`` env var, or ``_backend_is_cpu.cache_clear()``.
+``interpret`` resolution happens *outside* the jitted body: an explicit
+argument wins, and ``None`` means "interpret exactly when the default
+backend is the CPU".  On an accelerator nothing but an explicit
+``interpret=True`` selects the interpreter, so a kernel can never silently
+fall back to it there.  The resolved value is part of the jit cache key,
+and the backend probe is cached per process.
 
 ``block_n`` resolution: ``None`` (the default) asks the shape-keyed
 autotuner (`repro.kernels.autotune`) for the measured winner on this
@@ -32,7 +29,6 @@ An explicit ``block_n`` is honoured as requested — and warns if the legacy
 from __future__ import annotations
 
 import functools
-import os
 import warnings
 from functools import partial
 
@@ -87,19 +83,6 @@ def _pick_block_n(kind: str, n: int, m: int, o: int,
     return _resolve_block_n(n, block_n)
 
 
-_INTERPRET_ENV = "REPRO_KERNEL_INTERPRET"
-_INTERPRET_OVERRIDE: bool | None = None
-
-
-def set_interpret_override(value: bool | None) -> None:
-    """Force (True/False) or restore auto-detection (None) of interpret mode
-    for every kernel in this module — the test/debug hook, and the escape
-    hatch for processes whose default backend changes after the first call
-    (the cached probe would otherwise keep the stale decision)."""
-    global _INTERPRET_OVERRIDE
-    _INTERPRET_OVERRIDE = None if value is None else bool(value)
-
-
 @functools.lru_cache(maxsize=1)
 def _backend_is_cpu() -> bool:
     """One probe per process (``jax.default_backend()`` walks the backend
@@ -108,16 +91,9 @@ def _backend_is_cpu() -> bool:
 
 
 def _resolve_interpret(interpret: bool | None) -> bool:
-    """explicit arg > set_interpret_override > $REPRO_KERNEL_INTERPRET >
-    cached am-I-on-CPU probe.  Env/override are read at call time (never
-    baked into a trace — the resolved bool is the jit cache key)."""
+    """Explicit arg, else interpret iff the default backend is the CPU."""
     if interpret is not None:
         return bool(interpret)
-    if _INTERPRET_OVERRIDE is not None:
-        return _INTERPRET_OVERRIDE
-    env = os.environ.get(_INTERPRET_ENV)
-    if env is not None and env != "":
-        return env.lower() not in ("0", "false", "no")
     return _backend_is_cpu()
 
 
@@ -450,5 +426,4 @@ __all__ = [
     "rolann_stats_batched",
     "rolann_stats_ref",
     "next_pow2",
-    "set_interpret_override",
 ]

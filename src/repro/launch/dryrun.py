@@ -6,6 +6,9 @@ jax locks the device count at first init.
 """
 import os
 
+# Lowering only: 512 virtual CPU devices stand in for the pod, so pin the
+# CPU platform too — on a host with a TPU, JAX would otherwise take the chip.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")
@@ -21,11 +24,11 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro import compat, optim
+from repro import optim
 from repro.configs import registry
 from repro.launch import roofline as roofline_mod
 from repro.launch import shardings, steps
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
 from repro.models import api as model_api
 from repro.models import get_bundle
 
@@ -97,7 +100,7 @@ def build(arch: str, shape_name: str, *, multi_pod: bool, microbatches: int | No
         step = steps.make_train_step(
             bundle, opt, microbatches=mb, accum_dtype=accum_dtype
         )
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step,
                 in_shardings=(p_shard, o_shard, b_shard),
@@ -109,7 +112,7 @@ def build(arch: str, shape_name: str, *, multi_pod: bool, microbatches: int | No
 
     if shape.kind == "prefill":
         step = steps.make_prefill_step(bundle)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step, in_shardings=(p_shard, b_shard)
             ).lower(params_shape, batch_specs)
@@ -125,7 +128,7 @@ def build(arch: str, shape_name: str, *, multi_pod: bool, microbatches: int | No
     t_shard = shardings.batch_shardings({"t": token_spec}, mesh)["t"]
     pos_spec = jax.ShapeDtypeStruct((), jnp.int32)
     step = steps.make_decode_step(bundle)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             step,
             in_shardings=(p_shard, c_shard, t_shard, None),
@@ -176,7 +179,9 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             )
         }
         if want_roofline:
-            rf = roofline_mod.analyze(compiled, mesh)
+            rf = roofline_mod.analyze(
+                compiled, mesh, device_kind=PRODUCTION_DEVICE_KIND
+            )
             record["roofline"] = rf.as_dict()
             total, routed = count_params(params_shape)
             cfg2 = bundle.cfg

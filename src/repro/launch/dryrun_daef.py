@@ -15,6 +15,9 @@ The collective-bytes difference between the two IS the paper-vs-optimized
 """
 import os
 
+# Lowering only: 512 virtual CPU devices stand in for the pod, so pin the
+# CPU platform too — on a host with a TPU, JAX would otherwise take the chip.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")
@@ -29,11 +32,14 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core import daef
 from repro.engine import DAEFEngine, ExecutionPlan
 from repro.launch import roofline as roofline_mod
-from repro.launch.mesh import data_axes, make_production_mesh
+from repro.launch.mesh import (
+    PRODUCTION_DEVICE_KIND,
+    data_axes,
+    make_production_mesh,
+)
 
 
 def build(method: str, *, d: int, n: int, multi_pod: bool, latent: int,
@@ -63,7 +69,7 @@ def build(method: str, *, d: int, n: int, multi_pod: bool, latent: int,
     from jax.sharding import PartitionSpec as P
 
     x_sharding = NamedSharding(mesh, P(None, axes))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fit, in_shardings=(x_sharding,)).lower(x_spec)
     return lowered, mesh, cfg
 
@@ -92,7 +98,9 @@ def run_one(method: str, *, d: int = 2048, n: int = 1 << 22,
             for k in ("argument_size_in_bytes", "output_size_in_bytes",
                       "temp_size_in_bytes")
         }
-        rf = roofline_mod.analyze(compiled, mesh)
+        rf = roofline_mod.analyze(
+            compiled, mesh, device_kind=PRODUCTION_DEVICE_KIND
+        )
         record["roofline"] = rf.as_dict()
         # "Useful" flops for DAEF: the Gram/SVD accumulations, ~ sum over
         # layers of 2 * m_in^2 * n (+ per-output for hidden layers).

@@ -7,21 +7,26 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
+
+#: The chip the production meshes (and so the dry-runs) target, as
+#: ``jax.Device.device_kind`` names it.
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips with a leading 'pod' axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Mesh over whatever devices exist (CPU demos / tests)."""
     n = len(jax.devices())
     assert n % model_parallel == 0, (n, model_parallel)
-    return compat.make_mesh((n // model_parallel, model_parallel), ("data", "model"))
+    return jax.make_mesh((n // model_parallel, model_parallel), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def make_tenant_mesh(n_devices: int | None = None):

@@ -24,7 +24,6 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ArchConfig
 from repro.models import common
 from repro.models import hints
@@ -364,7 +363,7 @@ def attend_auto(
 
     b_ok = dp_spec is not None and b % hints.axis_extent(mesh, dp) == 0
     bspec = dp_spec if b_ok else None
-    return compat.shard_map(
+    return jax.shard_map(
         stripe,
         mesh=mesh,
         in_specs=(

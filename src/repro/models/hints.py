@@ -19,14 +19,12 @@ import math
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 Axis = str | tuple[str, ...]
 
 
 def active_mesh():
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or not tuple(getattr(mesh, "axis_names", ())):
+    mesh = jax.sharding.get_abstract_mesh()
+    if not tuple(getattr(mesh, "axis_names", ())):
         return None
     return mesh
 

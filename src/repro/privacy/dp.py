@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import activations, dsvd, elm_ae, rolann
+from repro.core import activations, daef, dsvd, elm_ae, rolann
 from repro.privacy.spec import PrivacyError, PrivacySpec
 
 Array = jnp.ndarray
@@ -378,7 +378,7 @@ def fit_dp(config, x: Array, key: jax.Array, spec: PrivacySpec,
     for a, b in _chunks(n, chunk_samples):
         h = _forward(config, x[:, a:b], weights[:-1], biases[:-1])
         recon = f_ll.fn(w_ll.T @ h + b_ll[:, None])
-        errs.append(jnp.mean((recon - x[:, a:b]) ** 2, axis=0))
+        errs.append(daef.sample_mse(recon, x[:, a:b]))
     train_errors = dp_train_errors(block_keys[-1], jnp.concatenate(errs),
                                    sigmas["errors"])
 

@@ -18,7 +18,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"
 import sys
 sys.path.insert(0, {src!r})
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
 """
 
 

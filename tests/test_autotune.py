@@ -8,8 +8,8 @@ Covers the ISSUE-10 tentpole surface:
 * backend auto-selection — ``stats_backend.resolve("auto")`` follows the
   cache's measured ``preferred_backend`` verdict per platform;
 * wrapper resolution — an explicitly requested ``block_n`` is never
-  silently clipped (RPR-adjacent satellite), and interpret-mode resolution
-  honours the override hook and ``$REPRO_KERNEL_INTERPRET``;
+  silently clipped (RPR-adjacent satellite), and interpret mode is chosen
+  by an explicit argument or, failing one, by the CPU backend alone;
 * fused-chunk parity — ``rolann_fused_chunk`` == the einsum chunked path
   at ``test_parity`` tolerances across modes x dtypes, including c=1 and
   ragged-tail chunks;
@@ -129,7 +129,8 @@ def test_wrong_version_warns_and_falls_back():
 
 def test_resolve_auto_follows_cache_verdict():
     plat = jax.default_backend()
-    assert stats_backend.resolve("auto") == "einsum"  # unmeasured platform
+    with pytest.warns(RuntimeWarning, match="no preferred_backend"):
+        assert stats_backend.resolve("auto") == "einsum"  # unmeasured platform
     autotune.update_cache(platform=plat, preferred="fused")
     assert stats_backend.resolve("auto") == "fused"
     autotune.update_cache(platform=plat, preferred="einsum")
@@ -182,22 +183,16 @@ def test_explicit_block_n_warns_through_public_wrapper():
 
 
 def test_interpret_override_and_env(monkeypatch):
-    monkeypatch.delenv(ops._INTERPRET_ENV, raising=False)
+    """An explicit argument always wins; ``None`` interprets exactly on the
+    CPU backend, so an accelerator never falls back to the interpreter."""
     assert ops._resolve_interpret(True) is True
     assert ops._resolve_interpret(False) is False
-    try:
-        ops.set_interpret_override(True)
-        assert ops._resolve_interpret(None) is True
-        ops.set_interpret_override(False)
-        assert ops._resolve_interpret(None) is False
-    finally:
-        ops.set_interpret_override(None)
-    monkeypatch.setenv(ops._INTERPRET_ENV, "1")
-    assert ops._resolve_interpret(None) is True
-    monkeypatch.setenv(ops._INTERPRET_ENV, "false")
-    assert ops._resolve_interpret(None) is False
-    monkeypatch.delenv(ops._INTERPRET_ENV)
     assert ops._resolve_interpret(None) == (jax.default_backend() == "cpu")
+    monkeypatch.setattr(ops, "_backend_is_cpu", lambda: False)
+    assert ops._resolve_interpret(None) is False
+    assert ops._resolve_interpret(True) is True
+    monkeypatch.setattr(ops, "_backend_is_cpu", lambda: True)
+    assert ops._resolve_interpret(None) is True
 
 
 # ---------------------------------------------------------------------------

@@ -128,6 +128,7 @@ def test_roofline_dominant_term():
     from repro.launch.roofline import Roofline
 
     r = Roofline(
+        device_kind="TPU v5 lite",
         chips=256, flops_per_device=197e12, bytes_per_device=819e9 * 2,
         collective_per_device=0, peak_memory_per_device=0,
         collective_breakdown={},
@@ -135,3 +136,41 @@ def test_roofline_dominant_term():
     assert r.compute_s == pytest.approx(1.0)
     assert r.memory_s == pytest.approx(2.0)
     assert r.dominant == "memory"
+
+
+def test_roofline_unknown_device_is_an_error():
+    """Peaks are keyed by device_kind; a device without published peaks is
+    refused, never measured against another chip's roof."""
+    from repro.launch import roofline
+
+    assert roofline.peaks("TPU v5 lite").flops == 197e12
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.peaks("cpu")
+    r = roofline.Roofline(
+        device_kind="cpu", chips=1, flops_per_device=1.0,
+        bytes_per_device=1.0, collective_per_device=0.0,
+        peak_memory_per_device=0.0, collective_breakdown={},
+    )
+    with pytest.raises(ValueError, match="no published peaks"):
+        r.compute_s
+
+
+def test_compile_cache_honours_env_else_fixed_repo_path(monkeypatch):
+    """Entry points keep JAX's persistent cache where the environment says,
+    else at one fixed path inside the checkout — never a temporary name."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    calls = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.__setitem__(name, value))
+    monkeypatch.setenv(compile_cache.ENV_VAR, "/somewhere/else")
+    assert compile_cache.enable() == "/somewhere/else"
+    assert "jax_compilation_cache_dir" not in calls
+    monkeypatch.delenv(compile_cache.ENV_VAR)
+    path = compile_cache.enable()
+    repo = Path(__file__).resolve().parents[1]
+    assert path == str(repo / ".jax_cache")
+    assert calls["jax_compilation_cache_dir"] == path
+    assert calls["jax_persistent_cache_min_compile_time_secs"] == 0.0

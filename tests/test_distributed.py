@@ -89,7 +89,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
     b_shard = batch_shardings(batch, mesh)
     params_d = jax.device_put(params, p_shard)
     batch_d = jax.device_put(batch, b_shard)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         p2, s2, l2 = jax.jit(step)(params_d, opt.init(params_d), batch_d)
     print("LOSS", float(l1), float(l2))
     assert abs(float(l1) - float(l2)) < 1e-3
@@ -111,7 +111,7 @@ def test_attend_auto_on_mesh_both_strategies():
     k = jax.random.normal(ks[1], (4, 256, 3, 32))
     v = jax.random.normal(ks[2], (4, 256, 3, 32))
     ref = A.attend_full(q, k, v)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda *a: A.attend_auto(*a, q_block=64, kv_block=64))(q, k, v)
     err1 = float(jnp.abs(out - ref).max())
     # divisible heads -> hint path
@@ -119,7 +119,7 @@ def test_attend_auto_on_mesh_both_strategies():
     k2 = jax.random.normal(ks[4], (4, 256, 4, 32))
     v2 = jax.random.normal(ks[5], (4, 256, 4, 32))
     ref2 = A.attend_full(q2, k2, v2)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out2 = jax.jit(lambda *a: A.attend_auto(*a, q_block=64, kv_block=64))(q2, k2, v2)
     err2 = float(jnp.abs(out2 - ref2).max())
     print("ERRS", err1, err2)

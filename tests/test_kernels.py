@@ -101,10 +101,8 @@ def test_rolann_stats_dtypes(dtype):
 def test_rolann_stats_float64_roundtrip():
     """Under jax_enable_x64, f64 inputs come back f64 (accumulation is f32,
     so values carry f32-level error — dtype parity is the contract)."""
-    from jax.experimental import enable_x64
-
     rng = np.random.default_rng(1)
-    with enable_x64():
+    with jax.enable_x64(True):
         xa = jnp.asarray(rng.normal(size=(8, 256)), jnp.float64)
         fsq = jnp.asarray(rng.uniform(0.1, 1, (3, 256)), jnp.float64)
         fd = jnp.asarray(rng.normal(size=(3, 256)), jnp.float64)

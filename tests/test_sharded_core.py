@@ -80,13 +80,36 @@ def test_fit_on_mesh_local_svd_factorization():
     assert "FACTORIZATION OK" in out
 
 
+def test_fits_are_one_compiled_program():
+    """The one-shot, chunked and data-sharded fits each compile as one
+    program per shape, and a second fit of that shape compiles nothing.
+    Dispatched op by op, a TPU compiles every small op on its own: the
+    data-sharded fit then recompiled ~200 ops on every call."""
+    out = run_on_devices(_DATA, """
+    from repro.analysis.retrace import trace_guard
+    cfg = daef.DAEFConfig(layer_sizes=(9, 3, 5, 9), lam_hidden=0.5, lam_last=0.9)
+    mesh = make_host_mesh()
+    fits = {
+        "fit": lambda: daef.fit(cfg, x),
+        "chunked": lambda: daef.fit_chunked(cfg, x, chunk_samples=256),
+        "mesh": lambda: sharded._fit_on_mesh(cfg, x, mesh),
+    }
+    for name, fit in fits.items():
+        for budget in (5, 0):
+            with trace_guard(max_compiles=budget, what=name):
+                jax.block_until_ready(fit())
+    print("COMPILED OK")
+    """)
+    assert "COMPILED OK" in out
+
+
 @pytest.mark.slow
 def test_fit_on_mesh_multi_axis_data_mesh():
     """Collectives that loop over several data axes (('pod', 'data'))."""
     out = run_on_devices(_DATA, """
-    from repro import compat
     cfg = daef.DAEFConfig(layer_sizes=(9, 3, 5, 9), lam_hidden=0.5, lam_last=0.9)
-    mesh = compat.make_mesh((2, 4), ("pod", "data"))
+    mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     model_mesh = sharded.fit_on_mesh(cfg, x, mesh, data_axes=("pod", "data"))
     model_host = daef.fit(cfg, x)
     diffs = [float(jnp.abs(a - b).max())
