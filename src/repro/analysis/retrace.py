@@ -34,15 +34,21 @@ from dataclasses import dataclass, field
 import jax
 
 JAXPR_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _COUNTS = {JAXPR_TRACE_EVENT: 0, BACKEND_COMPILE_EVENT: 0}
+_TIMED = (JAXPR_TRACE_EVENT, LOWER_EVENT, BACKEND_COMPILE_EVENT)
+_SECONDS = 0.0
 _LISTENING = False
 
 
 def _listener(event: str, duration: float, **kwargs) -> None:  # noqa: ARG001
-    if event in _COUNTS:
-        _COUNTS[event] += 1
+    global _SECONDS
+    if event in _TIMED:
+        _SECONDS += duration
+        if event in _COUNTS:
+            _COUNTS[event] += 1
 
 
 def _ensure_listening() -> None:
@@ -58,6 +64,15 @@ def trace_counts() -> tuple[int, int]:
     first guard/urge to count — the listener installs lazily)."""
     _ensure_listening()
     return _COUNTS[JAXPR_TRACE_EVENT], _COUNTS[BACKEND_COMPILE_EVENT]
+
+
+def compile_seconds() -> float:
+    """Process-lifetime seconds JAX spent tracing, lowering and compiling
+    (backend compiles served from the persistent cache included), counted
+    like :func:`trace_counts`; a jit traced inside another's trace counts
+    in both."""
+    _ensure_listening()
+    return _SECONDS
 
 
 class TraceBudgetExceeded(AssertionError):
@@ -162,6 +177,6 @@ def trace_guard(max_traces: int | None = None, *,
         )
 
 
-__all__ = ["trace_guard", "trace_counts", "TraceReport",
-           "TraceBudgetExceeded", "JAXPR_TRACE_EVENT",
+__all__ = ["trace_guard", "trace_counts", "compile_seconds", "TraceReport",
+           "TraceBudgetExceeded", "JAXPR_TRACE_EVENT", "LOWER_EVENT",
            "BACKEND_COMPILE_EVENT"]

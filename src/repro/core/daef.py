@@ -21,12 +21,13 @@ from __future__ import annotations
 import dataclasses
 import functools
 from functools import partial
-from typing import NamedTuple, Sequence
+from typing import Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import activations, dsvd, elm_ae, rolann, stats_backend
 
 Array = jnp.ndarray
@@ -141,6 +142,54 @@ def _acts(config: DAEFConfig):
     return f_hl, f_ll
 
 
+class FitCall(NamedTuple):
+    """One compiled fit program and what it is called with: what a fit path
+    dispatches and what its ``lower_fit`` lowers.
+
+    ``place`` maps ``args`` to the arguments as the program reads them (the
+    input on its device or shards, see `put`); ``finish`` (optional) maps
+    the program's output and the placed arguments to what the fit returns.
+    """
+
+    fn: Callable
+    args: tuple
+    kw: dict
+    place: Callable[[tuple], tuple]
+    finish: Callable | None = None
+
+    def run(self):
+        """Place the arguments, then dispatch the program: the host spans
+        ``fit.place`` and ``fit.dispatch``."""
+        with obs.span("fit.place"):
+            args = self.place(self.args)
+        with obs.span("fit.dispatch"):
+            out = self.fn(*args, **self.kw)
+        return out if self.finish is None else self.finish(out, args)
+
+    def lower(self):
+        """The lowered program ``run`` would dispatch."""
+        return self.fn.lower(*self.place(self.args), **self.kw)
+
+
+def put(a, sharding=None):
+    """An input where its program reads it: ``jax.device_put`` of an array,
+    by default onto the device the jit would have used (the default device,
+    uncommitted; an array already on a device stays as it is).  A
+    ``jax.ShapeDtypeStruct`` takes the sharding instead, so a lowering sees
+    what a call would; a value traced by a caller's jit is left to it."""
+    if isinstance(a, jax.ShapeDtypeStruct):
+        return a if sharding is None else jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                               sharding=sharding)
+    if sharding is None and isinstance(a, jax.core.Tracer):
+        return a
+    return jax.device_put(a, sharding)
+
+
+def _place_input(args: tuple) -> tuple:
+    """Put the input (the argument after the static config) on its device."""
+    return (args[0], put(args[1]), *args[2:])
+
+
 def fit(config: DAEFConfig, x: Array, *, n_partitions: int = 1) -> DAEFModel:
     """Alg. 1 — non-iterative DAEF training on a single host.
 
@@ -148,21 +197,22 @@ def fit(config: DAEFConfig, x: Array, *, n_partitions: int = 1) -> DAEFModel:
     ROLANN merge paths exactly as the paper describes (the result is
     identical to n_partitions=1 up to numerics).
     """
-    fn, args, kw = _fit_call(config, x, n_partitions=n_partitions)
-    return fn(*args, **kw)
+    with obs.span("fit.prepare"):
+        call = _fit_call(config, x, n_partitions=n_partitions)
+    return call.run()
 
 
-def lower_fit(config: DAEFConfig, x: Array, *, chunk_samples: int | None = None):
+def lower_fit(config: DAEFConfig, x, *, chunk_samples: int | None = None):
     """Lower the program that ``fit`` (or ``fit_chunked``, given
-    ``chunk_samples``) runs for ``x``: ``.compile().as_text()`` is what the
-    device executes, e.g. which Pallas kernels the fit launches."""
-    fn, args, kw = _fit_call(config, x, chunk_samples=chunk_samples)
-    return fn.lower(*args, **kw)
+    ``chunk_samples``) runs for ``x`` (an array or a
+    ``jax.ShapeDtypeStruct``): ``.compile().as_text()`` is what the device
+    executes, e.g. which Pallas kernels the fit launches."""
+    return _fit_call(config, x, chunk_samples=chunk_samples).lower()
 
 
 def _fit_call(config: DAEFConfig, x: Array, *, n_partitions: int = 1,
-              chunk_samples: int | None = None):
-    """(compiled core, args, kwargs) that ``fit`` / ``fit_chunked`` run.
+              chunk_samples: int | None = None) -> FitCall:
+    """The program ``fit`` / ``fit_chunked`` run, and its arguments.
 
     The whole fit is one program per (config, input shape): dispatched op
     by op, a TPU would compile every small op of the pipeline on its own."""
@@ -175,11 +225,11 @@ def _fit_call(config: DAEFConfig, x: Array, *, n_partitions: int = 1,
     shared = dataclasses.replace(config, seed=0, lam_hidden=0.0, lam_last=0.0)
     args = (shared, x, config.layer_keys(), config.lam_hidden, config.lam_last)
     if chunk_samples is None:
-        return _fit_program, args, {"n_partitions": n_partitions}
+        return FitCall(_fit_program, args, {"n_partitions": n_partitions}, _place_input)
     if not isinstance(chunk_samples, int) or chunk_samples < 1:
         raise ValueError(f"chunk_samples must be a positive int, got {chunk_samples!r}")
     _require_gram(config, "fit_chunked")
-    return _fit_chunked_program, args, {"chunk": chunk_samples}
+    return FitCall(_fit_chunked_program, args, {"chunk": chunk_samples}, _place_input)
 
 
 def _fit_core(
@@ -199,10 +249,11 @@ def _fit_core(
     f_hl, f_ll = _acts(config)
 
     # ---- encoder: distributed truncated SVD (lines 5-12) ----
-    parts = _split(x, n_partitions)
-    enc = dsvd.dsvd(parts, rank=min(m0, x.shape[1]), method=_dsvd_method(config))
-    w_enc = enc.u[:, : config.latent_dim]
-    h = f_hl.fn(w_enc.T @ x)  # [m1, n]
+    with jax.named_scope("encoder"):
+        parts = _split(x, n_partitions)
+        enc = dsvd.dsvd(parts, rank=min(m0, x.shape[1]), method=_dsvd_method(config))
+        w_enc = enc.u[:, : config.latent_dim]
+        h = f_hl.fn(w_enc.T @ x)  # [m1, n]
 
     weights = [w_enc]
     biases: list[Array] = []
@@ -211,33 +262,38 @@ def _fit_core(
     # ---- decoder hidden layers (lines 13-19) ----
     sizes = config.layer_sizes
     for li in range(2, len(sizes) - 1):
-        res = elm_ae.train_layer(
-            keys[li],
-            h,
-            sizes[li],
-            lam_hidden,
-            f_hl,
-            init=config.init,
-            aux_bias=config.aux_bias,
-            method=config.method,
-            backend=config.stats_backend,
-            gram_solver=config.gram_solver,
-        )
+        with jax.named_scope(f"layer{li}"):
+            with jax.named_scope("forward"):  # the layer's stage-1 key
+                key = keys[li]
+            res = elm_ae.train_layer(
+                key,
+                h,
+                sizes[li],
+                lam_hidden,
+                f_hl,
+                init=config.init,
+                aux_bias=config.aux_bias,
+                method=config.method,
+                backend=config.stats_backend,
+                gram_solver=config.gram_solver,
+            )
         weights.append(res.w)
         biases.append(res.b)
         knowledge.append(res.knowledge)
         h = res.h
 
     # ---- last layer: supervised ROLANN to reconstruct X (lines 20-25) ----
-    w_ll, b_ll, k_ll = rolann.fit(
-        h, x, f_ll, lam_last, method=config.method,
-        backend=config.stats_backend, gram_solver=config.gram_solver,
-    )
+    with jax.named_scope(f"layer{len(sizes) - 1}"):
+        w_ll, b_ll, k_ll = rolann.fit(
+            h, x, f_ll, lam_last, method=config.method,
+            backend=config.stats_backend, gram_solver=config.gram_solver,
+        )
     weights.append(w_ll)
     biases.append(b_ll)
     knowledge.append(k_ll)
-    recon = f_ll.fn(w_ll.T @ h + b_ll[:, None])
-    train_errors = sample_mse(recon, x)
+    with jax.named_scope("errors"):
+        recon = f_ll.fn(w_ll.T @ h + b_ll[:, None])
+        train_errors = sample_mse(recon, x)
 
     return DAEFModel(
         weights=tuple(weights),
@@ -283,9 +339,10 @@ def _stream_forward(config: DAEFConfig, x: Array, weights, biases) -> Array:
     """Forward one chunk through the encoder + the solved decoder layers so
     far (all hidden activations) — the recompute-on-the-fly of each pass."""
     f_hl, _ = _acts(config)
-    h = f_hl.fn(weights[0].T @ x)
-    for w, b in zip(weights[1:], biases, strict=True):
-        h = f_hl.fn(w.T @ h + b[:, None])
+    with jax.named_scope("forward"):
+        h = f_hl.fn(weights[0].T @ x)
+        for w, b in zip(weights[1:], biases, strict=True):
+            h = f_hl.fn(w.T @ h + b[:, None])
     return h
 
 
@@ -309,19 +366,20 @@ def _fit_chunked_core(
     chunk = min(chunk, max(n, 1))
     n_chunks = -(-n // chunk)
     pad = n_chunks * chunk - n
-    xp = jnp.pad(x, ((0, 0), (0, pad)))
-    mask = (jnp.arange(n_chunks * chunk) < n).astype(x.dtype)
-    mask = mask.reshape(n_chunks, chunk)
-    xc = jnp.moveaxis(xp.reshape(m0, n_chunks, chunk), 1, 0)  # [c#, m0, chunk]
+    with jax.named_scope("encoder"):
+        xp = jnp.pad(x, ((0, 0), (0, pad)))
+        mask = (jnp.arange(n_chunks * chunk) < n).astype(x.dtype)
+        mask = mask.reshape(n_chunks, chunk)
+        xc = jnp.moveaxis(xp.reshape(m0, n_chunks, chunk), 1, 0)  # [c#, m0, chunk]
 
-    # ---- pass 1: encoder Gram, chunk by chunk ----
-    def enc_step(g, inp):
-        xcg, mk = inp
-        return g + dsvd.masked_gram(xcg, mk), None
+        # ---- pass 1: encoder Gram, chunk by chunk ----
+        def enc_step(g, inp):
+            xcg, mk = inp
+            return g + dsvd.masked_gram(xcg, mk), None
 
-    g_enc, _ = jax.lax.scan(enc_step, jnp.zeros((m0, m0), x.dtype), (xc, mask))
-    enc = dsvd.truncate(dsvd.gram_to_factors(g_enc), min(m0, n))
-    w_enc = enc.u[:, : config.latent_dim]
+        g_enc, _ = jax.lax.scan(enc_step, jnp.zeros((m0, m0), x.dtype), (xc, mask))
+        enc = dsvd.truncate(dsvd.gram_to_factors(g_enc), min(m0, n))
+        w_enc = enc.u[:, : config.latent_dim]
 
     weights = [w_enc]
     biases: list[Array] = []
@@ -329,27 +387,29 @@ def _fit_chunked_core(
 
     # ---- passes 2..L-1: decoder layers, stats folded per chunk ----
     for li in range(2, len(sizes) - 1):
-        w_c1, b_c1 = elm_ae.stage1(
-            keys[li], sizes[li - 1], sizes[li], config.init, x.dtype
-        )
-        solved = (tuple(weights), tuple(biases))
+        with jax.named_scope(f"layer{li}"):
+            with jax.named_scope("forward"):
+                w_c1, b_c1 = elm_ae.stage1(
+                    keys[li], sizes[li - 1], sizes[li], config.init, x.dtype
+                )
+            solved = (tuple(weights), tuple(biases))
 
-        def layer_step(stats, inp, _solved=solved, _wc1=w_c1, _bc1=b_c1):
-            xcg, mk = inp
-            h = _stream_forward(config, xcg, *_solved)
-            stats = elm_ae.accumulate_layer_stats(
-                stats, _wc1, _bc1, h, f_hl, weights=mk,
-                backend=config.stats_backend,
+            def layer_step(stats, inp, _solved=solved, _wc1=w_c1, _bc1=b_c1):
+                xcg, mk = inp
+                h = _stream_forward(config, xcg, *_solved)
+                stats = elm_ae.accumulate_layer_stats(
+                    stats, _wc1, _bc1, h, f_hl, weights=mk,
+                    backend=config.stats_backend,
+                )
+                return stats, None
+
+            stats0 = rolann.init_stats(sizes[li], sizes[li - 1], f_hl, x.dtype)
+            stats, _ = jax.lax.scan(layer_step, stats0, (xc, mask))
+            w_next, b_next = elm_ae.layer_from_knowledge(
+                stats, keys[li], sizes[li - 1], sizes[li], lam_hidden, f_hl,
+                init=config.init, aux_bias=config.aux_bias, dtype=x.dtype,
+                gram_solver=config.gram_solver,
             )
-            return stats, None
-
-        stats0 = rolann.init_stats(sizes[li], sizes[li - 1], f_hl, x.dtype)
-        stats, _ = jax.lax.scan(layer_step, stats0, (xc, mask))
-        w_next, b_next = elm_ae.layer_from_knowledge(
-            stats, keys[li], sizes[li - 1], sizes[li], lam_hidden, f_hl,
-            init=config.init, aux_bias=config.aux_bias, dtype=x.dtype,
-            gram_solver=config.gram_solver,
-        )
         weights.append(w_next)
         biases.append(b_next)
         knowledge.append(stats)
@@ -365,9 +425,10 @@ def _fit_chunked_core(
         )
         return stats, None
 
-    stats0 = rolann.init_stats(sizes[-2], m0, f_ll, x.dtype)
-    k_ll, _ = jax.lax.scan(last_step, stats0, (xc, mask))
-    w_ll, b_ll = rolann.solve(k_ll, lam_last, gram_solver=config.gram_solver)
+    with jax.named_scope(f"layer{len(sizes) - 1}"):
+        stats0 = rolann.init_stats(sizes[-2], m0, f_ll, x.dtype)
+        k_ll, _ = jax.lax.scan(last_step, stats0, (xc, mask))
+        w_ll, b_ll = rolann.solve(k_ll, lam_last, gram_solver=config.gram_solver)
     weights.append(w_ll)
     biases.append(b_ll)
     knowledge.append(k_ll)
@@ -379,8 +440,9 @@ def _fit_chunked_core(
         recon = f_ll.fn(w_ll.T @ h + b_ll[:, None])
         return carry, sample_mse(recon, xcg)
 
-    _, errs = jax.lax.scan(err_step, jnp.zeros((), x.dtype), (xc, mask))
-    train_errors = errs.reshape(-1)[:n]
+    with jax.named_scope("errors"):
+        _, errs = jax.lax.scan(err_step, jnp.zeros((), x.dtype), (xc, mask))
+        train_errors = errs.reshape(-1)[:n]
 
     return DAEFModel(
         weights=tuple(weights),
@@ -402,8 +464,9 @@ def fit_chunked(config: DAEFConfig, x: Array, *, chunk_samples: int) -> DAEFMode
     error for every chunk size, including chunk widths that do not divide n
     (the ragged tail is padded and masked exactly).
     """
-    fn, args, kw = _fit_call(config, x, chunk_samples=chunk_samples)
-    return fn(*args, **kw)
+    with obs.span("fit.prepare"):
+        call = _fit_call(config, x, chunk_samples=chunk_samples)
+    return call.run()
 
 
 # ---- host-streaming driver (data never fully on device) ----
@@ -466,7 +529,8 @@ def _iter_padded_chunks(factory, ndim: int, m0: int, what: str):
 
 @partial(jax.jit, donate_argnums=(0,))
 def _stream_enc_step(g, x, mask):
-    return g + dsvd.masked_gram(x, mask)
+    with jax.named_scope("encoder"):
+        return g + dsvd.masked_gram(x, mask)
 
 
 @partial(jax.jit, static_argnames=("config",), donate_argnums=(1,))
@@ -493,9 +557,10 @@ def _errors_chunk(config, params, x):
     """Per-sample reconstruction MSE of one chunk under solved weights."""
     weights, biases = params
     _, f_ll = _acts(config)
-    h = _stream_forward(config, x, weights[:-1], biases[:-1])
-    recon = f_ll.fn(weights[-1].T @ h + biases[-1][:, None])
-    return sample_mse(recon, x)
+    with jax.named_scope("errors"):
+        h = _stream_forward(config, x, weights[:-1], biases[:-1])
+        recon = f_ll.fn(weights[-1].T @ h + biases[-1][:, None])
+        return sample_mse(recon, x)
 
 
 _stream_errors_chunk = partial(jax.jit, static_argnames=("config",))(_errors_chunk)
@@ -531,8 +596,9 @@ def fit_stream(config: DAEFConfig, batches) -> DAEFModel:
             g = jnp.zeros((m0, m0), jnp.asarray(x).dtype)
         g = _stream_enc_step(g, x, mask)
         n_total += n_valid
-    enc = dsvd.truncate(dsvd.gram_to_factors(g), min(m0, n_total))
-    w_enc = enc.u[:, : config.latent_dim]
+    with jax.named_scope("encoder"):
+        enc = dsvd.truncate(dsvd.gram_to_factors(g), min(m0, n_total))
+        w_enc = enc.u[:, : config.latent_dim]
     dtype = w_enc.dtype
 
     weights = [w_enc]

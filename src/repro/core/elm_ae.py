@@ -62,8 +62,9 @@ def train_layer(
 ) -> LayerResult:
     """Alg. 2: train the decoder layer mapping H_l [m_l, n] -> H_{l+1}."""
     m_l = h_l.shape[0]
-    w_c1, b_c1 = stage1(key, m_l, m_next, init, h_l.dtype)
-    h_c1 = act.fn(w_c1.T @ h_l + b_c1[:, None])  # [m_next, n]
+    with jax.named_scope("forward"):
+        w_c1, b_c1 = stage1(key, m_l, m_next, init, h_l.dtype)
+        h_c1 = act.fn(w_c1.T @ h_l + b_c1[:, None])  # [m_next, n]
 
     # ROLANN solves the reconstruction h_c1 -> h_l; rolann.fit returns W with
     # shape [inputs=m_next, outputs=m_l].  The decoder layer needs
@@ -73,15 +74,17 @@ def train_layer(
         h_c1, h_l, act, lam, method=method, backend=backend,
         gram_solver=gram_solver,
     )
-    w_next = w_c2.T  # [m_l, m_next]
-    if aux_bias == "zero":
-        b_next = jnp.zeros((m_next,), h_l.dtype)
-    elif aux_bias == "c1":
-        b_next = b_c1
-    else:
-        raise ValueError(f"unknown aux_bias {aux_bias!r}")
+    with jax.named_scope("solve"):
+        w_next = w_c2.T  # [m_l, m_next]
+        if aux_bias == "zero":
+            b_next = jnp.zeros((m_next,), h_l.dtype)
+        elif aux_bias == "c1":
+            b_next = b_c1
+        else:
+            raise ValueError(f"unknown aux_bias {aux_bias!r}")
 
-    h_next = act.fn(w_next.T @ h_l + b_next[:, None])
+    with jax.named_scope("forward"):
+        h_next = act.fn(w_next.T @ h_l + b_next[:, None])
     return LayerResult(w=w_next, b=b_next, h=h_next, knowledge=knowledge)
 
 
@@ -100,8 +103,9 @@ def layer_knowledge_from_partition(
     of this partition for the given decoder layer (stage-1 randomness is
     derived from the shared key, so all nodes agree)."""
     m_l = h_l.shape[0]
-    w_c1, b_c1 = stage1(key, m_l, m_next, init, h_l.dtype)
-    h_c1 = act.fn(w_c1.T @ h_l + b_c1[:, None])
+    with jax.named_scope("forward"):
+        w_c1, b_c1 = stage1(key, m_l, m_next, init, h_l.dtype)
+        h_c1 = act.fn(w_c1.T @ h_l + b_c1[:, None])
     if method == "gram":
         return rolann.compute_stats(h_c1, h_l, act, backend=backend)
     if factorization == "gram_eigh":
@@ -137,12 +141,14 @@ def accumulate_layer_stats(
     """
     resolved = stats_backend.resolve(backend)
     if resolved == "fused" and act.name != "linear":
-        g, m = stats_backend.fused_chunk_acc(
-            stats.g, stats.m, h_l, w_c1, b_c1, weights,
-            act=act, backend=resolved,
-        )
+        with jax.named_scope("stats"):
+            g, m = stats_backend.fused_chunk_acc(
+                stats.g, stats.m, h_l, w_c1, b_c1, weights,
+                act=act, backend=resolved,
+            )
         return rolann.RolannStats(g=g, m=m)
-    h_c1 = act.fn(w_c1.T @ h_l + b_c1[:, None])
+    with jax.named_scope("forward"):
+        h_c1 = act.fn(w_c1.T @ h_l + b_c1[:, None])
     return rolann.accumulate_stats(
         stats, h_c1, h_l, act, weights=weights, backend=resolved
     )
@@ -163,12 +169,13 @@ def layer_from_knowledge(
 ) -> tuple[Array, Array]:
     """Solve the decoder layer weights from (merged) federated knowledge."""
     w_c2, _ = rolann.solve(knowledge, lam, gram_solver=gram_solver)
-    w_next = w_c2.T
-    if aux_bias == "zero":
-        b_next = jnp.zeros((m_next,), dtype)
-    elif aux_bias == "c1":
-        _, b_c1 = stage1(key, m_l, m_next, init, dtype)
-        b_next = b_c1
-    else:
-        raise ValueError(f"unknown aux_bias {aux_bias!r}")
+    with jax.named_scope("solve"):
+        w_next = w_c2.T
+        if aux_bias == "zero":
+            b_next = jnp.zeros((m_next,), dtype)
+        elif aux_bias == "c1":
+            _, b_c1 = stage1(key, m_l, m_next, init, dtype)
+            b_next = b_c1
+        else:
+            raise ValueError(f"unknown aux_bias {aux_bias!r}")
     return w_next, b_next

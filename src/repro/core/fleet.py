@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import anomaly, daef, dsvd, elm_ae, rolann
 
 Array = jnp.ndarray
@@ -90,7 +91,8 @@ def _prepare_fit(
 @partial(jax.jit, static_argnames=("config", "n_partitions"))
 def _fleet_fit(config, xs, seeds, lam_hidden, lam_last, *, n_partitions=1):
     def one(x, seed, lh, ll):
-        keys = _tenant_keys(config, seed)
+        with jax.named_scope("forward"):  # the stage-1 randomness
+            keys = _tenant_keys(config, seed)
         return daef._fit_core(config, x, keys, lh, ll, n_partitions=n_partitions)
 
     return jax.vmap(one)(xs, seeds, lam_hidden, lam_last)
@@ -105,7 +107,8 @@ def _fleet_fit_chunked_kernel(config, xs, seeds, lam_hidden, lam_last, *,
     rule lowers to `rolann_stats_acc_batched` on the fused backend)."""
 
     def one(x, seed, lh, ll):
-        keys = _tenant_keys(config, seed)
+        with jax.named_scope("forward"):  # the stage-1 randomness
+            keys = _tenant_keys(config, seed)
         return daef._fit_chunked_core(config, x, keys, lh, ll,
                                       chunk=chunk_samples)
 
@@ -151,15 +154,10 @@ def _fit_fleet(
     seeds / lam_hidden / lam_last: scalar (shared) or [K] (per tenant);
     defaults come from ``config``.
     """
-    config = config.resolved()  # env-resolved backend keys the jit cache
-    seeds, lam_hidden, lam_last = _prepare_fit(
-        config, xs, seeds, lam_hidden, lam_last
-    )
-    model = _fleet_fit(
-        config, xs, seeds, lam_hidden, lam_last, n_partitions=n_partitions
-    )
-    return DAEFFleet(model=model, seeds=seeds, lam_hidden=lam_hidden,
-                     lam_last=lam_last)
+    with obs.span("fit.prepare"):
+        call = _fit_fleet_call(config, xs, seeds, lam_hidden, lam_last,
+                               n_partitions=n_partitions)
+    return call.run()
 
 
 def _fit_fleet_chunked(
@@ -175,14 +173,35 @@ def _fit_fleet_chunked(
     path): K tenants trained by the chunked `lax.scan` core in one jitted
     vmap dispatch — peak activation memory O(K * (m^2 + chunk)) instead of
     O(K * m * n)."""
-    config = config.resolved()
-    daef._require_gram(config, "chunked fleet fit")
+    with obs.span("fit.prepare"):
+        call = _fit_fleet_call(config, xs, seeds, lam_hidden, lam_last,
+                               chunk_samples=chunk_samples)
+    return call.run()
+
+
+def _fit_fleet_call(config: daef.DAEFConfig, xs, seeds, lam_hidden, lam_last, *,
+                    n_partitions: int = 1,
+                    chunk_samples: int | None = None) -> daef.FitCall:
+    """The program `_fit_fleet` / `_fit_fleet_chunked` run, and its
+    arguments: the one-shot or the chunked core vmapped over tenants."""
+    config = config.resolved()  # env-resolved backend keys the jit cache
+    if chunk_samples is not None:
+        daef._require_gram(config, "chunked fleet fit")
     seeds, lam_hidden, lam_last = _prepare_fit(
         config, xs, seeds, lam_hidden, lam_last
     )
-    model = _fleet_fit_chunked_kernel(
-        config, xs, seeds, lam_hidden, lam_last, chunk_samples=chunk_samples
-    )
+    args = (config, xs, seeds, lam_hidden, lam_last)
+    if chunk_samples is None:
+        fn, kw = _fleet_fit, {"n_partitions": n_partitions}
+    else:
+        fn, kw = _fleet_fit_chunked_kernel, {"chunk_samples": chunk_samples}
+    return daef.FitCall(fn, args, kw, daef._place_input, _as_fleet)
+
+
+def _as_fleet(model: daef.DAEFModel, args: tuple) -> DAEFFleet:
+    """The fleet a fit program's output makes, with the per-tenant seeds
+    and lambdas it was called with (``args[-3:]``)."""
+    seeds, lam_hidden, lam_last = args[-3:]
     return DAEFFleet(model=model, seeds=seeds, lam_hidden=lam_hidden,
                      lam_last=lam_last)
 
@@ -196,7 +215,8 @@ def _fit_fleet_chunked(
 
 @partial(jax.jit, donate_argnums=(0,))
 def _fleet_stream_enc_step(g, xs, mask):
-    return g + jax.vmap(dsvd.masked_gram, in_axes=(0, None))(xs, mask)
+    with jax.named_scope("encoder"):
+        return g + jax.vmap(dsvd.masked_gram, in_axes=(0, None))(xs, mask)
 
 
 @partial(jax.jit, static_argnames=("config",), donate_argnums=(1,))
@@ -293,8 +313,9 @@ def _fit_fleet_stream(
     lam_last = place(_per_tenant(lam_last, config.lam_last, k, g.dtype))
     keys = jax.vmap(lambda s: daef.layer_keys_from_seed(s, len(sizes)))(seeds)
     rank = min(m0, n_total)
-    enc = jax.vmap(lambda gi: dsvd.truncate(dsvd.gram_to_factors(gi), rank))(g)
-    w_enc = enc.u[:, :, : config.latent_dim]
+    with jax.named_scope("encoder"):
+        enc = jax.vmap(lambda gi: dsvd.truncate(dsvd.gram_to_factors(gi), rank))(g)
+        w_enc = enc.u[:, :, : config.latent_dim]
     dtype = w_enc.dtype
 
     weights = [w_enc]

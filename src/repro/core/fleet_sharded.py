@@ -44,6 +44,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core import daef, dsvd, fleet, rolann
 
 Array = jnp.ndarray
@@ -130,24 +131,33 @@ def _fit_sharded(
     program is the chunked-scan streaming core (bounded activation memory
     per device) instead of the one-shot fit.
     """
+    with obs.span("fit.prepare"):
+        call = _fit_sharded_call(config, xs, mesh, seeds, lam_hidden, lam_last,
+                                 n_partitions=n_partitions,
+                                 chunk_samples=chunk_samples)
+    return call.run()
+
+
+def _fit_sharded_call(config: daef.DAEFConfig, xs, mesh: Mesh, seeds, lam_hidden,
+                      lam_last, *, n_partitions: int = 1,
+                      chunk_samples: int | None = None) -> daef.FitCall:
+    """The per-shard program `_fit_sharded` runs, and its arguments; every
+    argument is placed sharded over tenants."""
     config = config.resolved()
     seeds, lam_hidden, lam_last = fleet._prepare_fit(
         config, xs, seeds, lam_hidden, lam_last
     )
+    _check_divisible(xs.shape[0], mesh, "shard_batch")
     spec = tenant_sharding(mesh)
-    xs = shard_batch(xs, mesh)
-    seeds = jax.device_put(seeds, spec)
-    lam_hidden = jax.device_put(lam_hidden, spec)
-    lam_last = jax.device_put(lam_last, spec)
     if chunk_samples is not None:
         fit = _per_shard(fleet._fleet_fit_chunked_kernel, config, mesh, 4,
                          chunk_samples=chunk_samples)
     else:
         fit = _per_shard(fleet._fleet_fit, config, mesh, 4,
                          n_partitions=n_partitions)
-    model = fit(xs, seeds, lam_hidden, lam_last)
-    return fleet.DAEFFleet(model=model, seeds=seeds, lam_hidden=lam_hidden,
-                           lam_last=lam_last)
+    return daef.FitCall(fit, (xs, seeds, lam_hidden, lam_last), {},
+                        lambda args: tuple(daef.put(a, spec) for a in args),
+                        fleet._as_fleet)
 
 
 @functools.lru_cache(maxsize=None)

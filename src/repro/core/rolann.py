@@ -98,22 +98,23 @@ def compute_stats(
     kernel); None resolves from $REPRO_STATS_BACKEND.
     """
     act = activations.get(act.name, invertible_required=True)
-    xa = _augment(x)  # [m+1, n]
-    dbar, fp = _targets(d, act)
-    fsq = fp * fp
-    if act.name == "linear":
-        # Shared F: one [m, m] Gram for all outputs — a single matmul XLA
-        # already fuses; the per-output kernel has nothing to win here.
-        m_vec = jnp.einsum("in,on->oi", xa, fsq * dbar)
-        g = xa @ xa.T
-    else:
-        # Per-output Gram: G_j = Xa diag(fp_j^2) Xa^T.  The output axis is
-        # embarrassingly parallel — shard it over the model mesh axis when
-        # one is active (the paper's pool.map over cores, TPU-native).
-        from repro.models import hints
+    with jax.named_scope("stats"):
+        xa = _augment(x)  # [m+1, n]
+        dbar, fp = _targets(d, act)
+        fsq = fp * fp
+        if act.name == "linear":
+            # Shared F: one [m, m] Gram for all outputs — a single matmul XLA
+            # already fuses; the per-output kernel has nothing to win here.
+            m_vec = jnp.einsum("in,on->oi", xa, fsq * dbar)
+            g = xa @ xa.T
+        else:
+            # Per-output Gram: G_j = Xa diag(fp_j^2) Xa^T.  The output axis is
+            # embarrassingly parallel — shard it over the model mesh axis when
+            # one is active (the paper's pool.map over cores, TPU-native).
+            from repro.models import hints
 
-        g, m_vec = stats_backend.gram_stats(xa, fsq, fsq * dbar, backend=backend)
-        g = hints.hint(g, {0: "model"})
+            g, m_vec = stats_backend.gram_stats(xa, fsq, fsq * dbar, backend=backend)
+            g = hints.hint(g, {0: "model"})
     return RolannStats(g=g, m=m_vec)
 
 
@@ -124,11 +125,12 @@ def init_stats(
     and targets [n_outputs, ·] — the identity of ``merge_stats``.  Linear
     activations share one Gram across outputs (see ``compute_stats``)."""
     m_aug = n_inputs + 1  # bias row
-    if act.name == "linear":
-        g = jnp.zeros((m_aug, m_aug), dtype)
-    else:
-        g = jnp.zeros((n_outputs, m_aug, m_aug), dtype)
-    return RolannStats(g=g, m=jnp.zeros((n_outputs, m_aug), dtype))
+    with jax.named_scope("stats"):
+        if act.name == "linear":
+            g = jnp.zeros((m_aug, m_aug), dtype)
+        else:
+            g = jnp.zeros((n_outputs, m_aug, m_aug), dtype)
+        return RolannStats(g=g, m=jnp.zeros((n_outputs, m_aug), dtype))
 
 
 def accumulate_stats(
@@ -153,42 +155,44 @@ def accumulate_stats(
     chunks can be padded to a fixed shape without biasing the statistics.
     """
     act = activations.get(act.name, invertible_required=True)
-    xa = _augment(x)  # [m+1, n]
-    dbar, fp = _targets(d, act)
-    fsq = fp * fp
-    fd = fsq * dbar
-    if weights is not None:
-        w = weights.astype(xa.dtype)
-        fsq = fsq * w[None, :]
-        fd = fd * w[None, :]
-    if act.name == "linear":
-        # Shared F: fp == 1, so masking must hit the Gram's columns directly.
-        xw = xa if weights is None else xa * w[None, :]
-        g = stats.g + xw @ xa.T
-        m_vec = stats.m + jnp.einsum("in,on->oi", xa, fd)
-        return RolannStats(g=g, m=m_vec)
-    g, m_vec = stats_backend.gram_stats_acc(
-        stats.g, stats.m, xa, fsq, fd, backend=backend
-    )
+    with jax.named_scope("stats"):
+        xa = _augment(x)  # [m+1, n]
+        dbar, fp = _targets(d, act)
+        fsq = fp * fp
+        fd = fsq * dbar
+        if weights is not None:
+            w = weights.astype(xa.dtype)
+            fsq = fsq * w[None, :]
+            fd = fd * w[None, :]
+        if act.name == "linear":
+            # Shared F: fp == 1, so masking must hit the Gram's columns directly.
+            xw = xa if weights is None else xa * w[None, :]
+            g = stats.g + xw @ xa.T
+            m_vec = stats.m + jnp.einsum("in,on->oi", xa, fd)
+            return RolannStats(g=g, m=m_vec)
+        g, m_vec = stats_backend.gram_stats_acc(
+            stats.g, stats.m, xa, fsq, fd, backend=backend
+        )
     return RolannStats(g=g, m=m_vec)
 
 
 def compute_factors(x: Array, d: Array, act: activations.Activation) -> RolannFactors:
     """Paper-faithful statistics via SVD of Xa F (Eq. 6-7)."""
     act = activations.get(act.name, invertible_required=True)
-    xa = _augment(x)
-    dbar, fp = _targets(d, act)
-    m_vec = jnp.einsum("in,on->oi", xa, fp * fp * dbar)
-    if act.name == "linear":
-        u, s, _ = jnp.linalg.svd(xa, full_matrices=False)
-        r = min(xa.shape)
-        return RolannFactors(u=u[:, :r], s=s[:r], m=m_vec)
+    with jax.named_scope("stats"):
+        xa = _augment(x)
+        dbar, fp = _targets(d, act)
+        m_vec = jnp.einsum("in,on->oi", xa, fp * fp * dbar)
+        if act.name == "linear":
+            u, s, _ = jnp.linalg.svd(xa, full_matrices=False)
+            r = min(xa.shape)
+            return RolannFactors(u=u[:, :r], s=s[:r], m=m_vec)
 
-    def one(fp_j: Array) -> tuple[Array, Array]:
-        u, s, _ = jnp.linalg.svd(xa * fp_j[None, :], full_matrices=False)
-        return u, s
+        def one(fp_j: Array) -> tuple[Array, Array]:
+            u, s, _ = jnp.linalg.svd(xa * fp_j[None, :], full_matrices=False)
+            return u, s
 
-    u, s = jax.vmap(one)(fp)
+        u, s = jax.vmap(one)(fp)
     return RolannFactors(u=u, s=s, m=m_vec)
 
 
@@ -202,7 +206,9 @@ def compute_factors_via_gram(
     [m, n_local] matrix — at pod scale (n_local ~ 256k) the direct SVD's
     workspace is hundreds of GiB while this stays O(m^2) (EXPERIMENTS §Perf).
     """
-    return stats_to_factors(compute_stats(x, d, act, backend=backend))
+    stats = compute_stats(x, d, act, backend=backend)
+    with jax.named_scope("stats"):
+        return stats_to_factors(stats)
 
 
 def stats_to_factors(stats: RolannStats) -> RolannFactors:
@@ -377,20 +383,21 @@ def solve(
         raise ValueError(
             f"unknown gram_solver {gram_solver!r}: choose from {GRAM_SOLVERS}"
         )
-    if isinstance(knowledge, RolannStats) and gram_solver != "eigh":
-        w_aug = _solve_stats_chol(knowledge, lam)
-        if gram_solver == "auto":
-            w_aug = jax.lax.cond(
-                jnp.all(jnp.isfinite(w_aug)),
-                lambda w: w,
-                lambda w: _solve_factors(stats_to_factors(knowledge), lam),
-                w_aug,
-            )
+    with jax.named_scope("solve"):
+        if isinstance(knowledge, RolannStats) and gram_solver != "eigh":
+            w_aug = _solve_stats_chol(knowledge, lam)
+            if gram_solver == "auto":
+                w_aug = jax.lax.cond(
+                    jnp.all(jnp.isfinite(w_aug)),
+                    lambda w: w,
+                    lambda w: _solve_factors(stats_to_factors(knowledge), lam),
+                    w_aug,
+                )
+            return w_aug[:-1, :], w_aug[-1, :]
+        if isinstance(knowledge, RolannStats):
+            knowledge = stats_to_factors(knowledge)
+        w_aug = _solve_factors(knowledge, lam)
         return w_aug[:-1, :], w_aug[-1, :]
-    if isinstance(knowledge, RolannStats):
-        knowledge = stats_to_factors(knowledge)
-    w_aug = _solve_factors(knowledge, lam)
-    return w_aug[:-1, :], w_aug[-1, :]
 
 
 def fit(

@@ -24,6 +24,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core import activations, daef, dsvd, elm_ae, rolann
 
 Array = jnp.ndarray
@@ -114,10 +115,26 @@ def _fit_on_mesh(
     Returns a DAEFModel whose weights are replicated and whose train_errors
     remain sharded over the data axes.
     """
+    with obs.span("fit.prepare"):
+        call = _fit_on_mesh_call(config, x, mesh, data_axes=data_axes,
+                                 local_factorization=local_factorization)
+    return call.run()
+
+
+def _fit_on_mesh_call(config: daef.DAEFConfig, x, mesh: Mesh, *,
+                      data_axes: Sequence[str] = ("data",),
+                      local_factorization: str = "gram_eigh") -> daef.FitCall:
+    """The program `_fit_on_mesh` runs, and its input, placed with the
+    sample axis sharded over ``data_axes``."""
     axes = tuple(data_axes)
     fit = _mesh_fit_program(config.resolved(), mesh, axes, local_factorization)
-    x = jax.device_put(x, NamedSharding(mesh, P(None, axes)))
-    weights, biases, (enc_u, enc_s), knowledge, errors = fit(x)
+    spec = NamedSharding(mesh, P(None, axes))
+    return daef.FitCall(fit, (x,), {}, lambda args: (daef.put(args[0], spec),),
+                        _as_model)
+
+
+def _as_model(out, args) -> daef.DAEFModel:  # noqa: ARG001
+    weights, biases, (enc_u, enc_s), knowledge, errors = out
     return daef.DAEFModel(
         weights=weights,
         biases=biases,
@@ -141,21 +158,22 @@ def _mesh_fit_program(config: daef.DAEFConfig, mesh: Mesh, axes: tuple,
 
     def node(xp: Array):
         # ---------------- encoder ----------------
-        if use_gram:
-            g = _psum(dsvd.gram(xp), axes)
-            enc_u, enc_s = dsvd.gram_to_factors(g)
-        else:
-            # Local factors: eigh of the local Gram (default) carries the
-            # same U·S message as the paper's direct SVD but avoids its
-            # O(m * n_local) right-factor workspace.
-            f = (
-                dsvd.gram_to_factors(dsvd.gram(xp))
-                if local_factorization == "gram_eigh"
-                else dsvd.local_svd(xp)
-            )
-            enc_u, enc_s = _gather_merge_svd(f.u * f.s[None, :], axes)
-        w_enc = enc_u[:, : config.latent_dim]
-        h = f_hl.fn(w_enc.T @ xp)
+        with jax.named_scope("encoder"):
+            if use_gram:
+                g = _psum(dsvd.gram(xp), axes)
+                enc_u, enc_s = dsvd.gram_to_factors(g)
+            else:
+                # Local factors: eigh of the local Gram (default) carries the
+                # same U·S message as the paper's direct SVD but avoids its
+                # O(m * n_local) right-factor workspace.
+                f = (
+                    dsvd.gram_to_factors(dsvd.gram(xp))
+                    if local_factorization == "gram_eigh"
+                    else dsvd.local_svd(xp)
+                )
+                enc_u, enc_s = _gather_merge_svd(f.u * f.s[None, :], axes)
+            w_enc = enc_u[:, : config.latent_dim]
+            h = f_hl.fn(w_enc.T @ xp)
 
         weights = [w_enc]
         biases = []
@@ -163,51 +181,57 @@ def _mesh_fit_program(config: daef.DAEFConfig, mesh: Mesh, axes: tuple,
 
         # ---------------- decoder hidden layers ----------------
         for li in range(2, len(sizes) - 1):
-            local = elm_ae.layer_knowledge_from_partition(
-                keys[li], h, sizes[li], f_hl,
-                init=config.init, method=config.method,
-                factorization=local_factorization,
-                backend=config.stats_backend,
-            )
-            if use_gram:
-                merged = _psum(local, axes)
-            else:
-                u, s = _gather_merge_svd(local.u * local.s[..., None, :], axes)
-                m_vec = _psum(local.m, axes)
-                merged = rolann.RolannFactors(u=u, s=s, m=m_vec)
-            w, b = elm_ae.layer_from_knowledge(
-                merged, keys[li], sizes[li - 1], sizes[li],
-                config.lam_hidden, f_hl,
-                init=config.init, aux_bias=config.aux_bias, dtype=xp.dtype,
-                gram_solver=config.gram_solver,
-            )
+            with jax.named_scope(f"layer{li}"):
+                local = elm_ae.layer_knowledge_from_partition(
+                    keys[li], h, sizes[li], f_hl,
+                    init=config.init, method=config.method,
+                    factorization=local_factorization,
+                    backend=config.stats_backend,
+                )
+                with jax.named_scope("stats"):
+                    if use_gram:
+                        merged = _psum(local, axes)
+                    else:
+                        u, s = _gather_merge_svd(local.u * local.s[..., None, :], axes)
+                        m_vec = _psum(local.m, axes)
+                        merged = rolann.RolannFactors(u=u, s=s, m=m_vec)
+                w, b = elm_ae.layer_from_knowledge(
+                    merged, keys[li], sizes[li - 1], sizes[li],
+                    config.lam_hidden, f_hl,
+                    init=config.init, aux_bias=config.aux_bias, dtype=xp.dtype,
+                    gram_solver=config.gram_solver,
+                )
+                with jax.named_scope("forward"):
+                    h = f_hl.fn(w.T @ h + b[:, None])
             weights.append(w)
             biases.append(b)
             knowledge.append(merged)
-            h = f_hl.fn(w.T @ h + b[:, None])
 
         # ---------------- last layer ----------------
-        if use_gram:
-            local = rolann.compute_stats(h, xp, f_ll, backend=config.stats_backend)
-        elif local_factorization == "gram_eigh":
-            local = rolann.compute_factors_via_gram(
-                h, xp, f_ll, backend=config.stats_backend
-            )
-        else:
-            local = rolann.compute_factors(h, xp, f_ll)
-        if use_gram:
-            merged = _psum(local, axes)
-        else:
-            u, s = _gather_merge_svd(local.u * local.s[..., None, :], axes)
-            merged = rolann.RolannFactors(u=u, s=s, m=_psum(local.m, axes))
-        w_ll, b_ll = rolann.solve(merged, config.lam_last,
-                                  gram_solver=config.gram_solver)
+        with jax.named_scope(f"layer{len(sizes) - 1}"):
+            if use_gram:
+                local = rolann.compute_stats(h, xp, f_ll, backend=config.stats_backend)
+            elif local_factorization == "gram_eigh":
+                local = rolann.compute_factors_via_gram(
+                    h, xp, f_ll, backend=config.stats_backend
+                )
+            else:
+                local = rolann.compute_factors(h, xp, f_ll)
+            with jax.named_scope("stats"):
+                if use_gram:
+                    merged = _psum(local, axes)
+                else:
+                    u, s = _gather_merge_svd(local.u * local.s[..., None, :], axes)
+                    merged = rolann.RolannFactors(u=u, s=s, m=_psum(local.m, axes))
+            w_ll, b_ll = rolann.solve(merged, config.lam_last,
+                                      gram_solver=config.gram_solver)
         weights.append(w_ll)
         biases.append(b_ll)
         knowledge.append(merged)
 
-        recon = f_ll.fn(w_ll.T @ h + b_ll[:, None])
-        errors = daef.sample_mse(recon, xp)
+        with jax.named_scope("errors"):
+            recon = f_ll.fn(w_ll.T @ h + b_ll[:, None])
+            errors = daef.sample_mse(recon, xp)
         return (
             tuple(weights),
             tuple(biases),

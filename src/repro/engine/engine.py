@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import anomaly, daef, dsvd, fleet, fleet_sharded, rolann, sharded
 from repro.engine.plan import ExecutionPlan, PlanError
 
@@ -60,6 +61,18 @@ def _bumps_model_version(method):
         self._model_version += 1
         return out
     return wrapper
+
+
+def _only(outs: list):
+    """The state of a fit that makes one program call: its output."""
+    return outs[0]
+
+
+def _tenant(x, i: int):
+    """Tenant ``i``'s data of a fleet batch (for lowering, its shape)."""
+    if isinstance(x, jax.ShapeDtypeStruct):
+        return jax.ShapeDtypeStruct(x.shape[1:], x.dtype)
+    return x[i]
 
 
 class DAEFEngine:
@@ -336,7 +349,34 @@ class DAEFEngine:
             PlanError: batch shape disagrees with the plan (tenant count,
                 feature dim), per-tenant overrides on a single model, or
                 ``n_partitions`` combined with ``plan.chunk_samples``.
+
+        Host spans (`repro.obs`): ``engine.fit`` (attributes ``tenants`` and
+        ``samples`` per tenant) over ``fit.prepare``, then ``fit.place`` and
+        ``fit.dispatch`` for each program call.
         """
+        shape = getattr(x, "shape", None) or (0,)
+        with obs.span("engine.fit", tenants=self.plan.tenants, samples=int(shape[-1])):
+            with obs.span("fit.prepare"):
+                calls, finish = self._fit_calls(x, seeds, lam_hidden, lam_last,
+                                                n_partitions)
+            return finish([call.run() for call in calls])
+
+    def lower_fit(self, x, *, seeds=None, lam_hidden=None, lam_last=None):
+        """The lowered program ``fit`` would run for ``x`` under the plan
+        (for ``mode="loop"``, the per-tenant program, which every tenant
+        shares): ``.compile().as_text()`` is what the device executes.
+
+        ``x`` may be a ``jax.ShapeDtypeStruct``: lowering needs only its
+        shape and dtype, and takes the placement from the plan.  Raises what
+        ``fit`` raises for the same arguments.
+        """
+        calls, _ = self._fit_calls(x, seeds, lam_hidden, lam_last, 1)
+        return calls[0].lower()
+
+    def _fit_calls(self, x, seeds, lam_hidden, lam_last, n_partitions):
+        """The program calls ``fit`` makes for ``x`` under the plan
+        (`daef.FitCall`; one per tenant in loop mode, else one) and the
+        function that makes the fitted state from their outputs."""
         cfg, plan = self.config, self.plan
         chunk = plan.chunk_samples
         if chunk is not None and n_partitions != 1:
@@ -352,48 +392,45 @@ class DAEFEngine:
                     "for a single model set them on the DAEFConfig"
                 )
             if plan.data_sharded:
-                return sharded._fit_on_mesh(
+                call = sharded._fit_on_mesh_call(
                     cfg, x, self.mesh, data_axes=plan.mesh_axes,
                     local_factorization=plan.local_factorization,
                 )
-            if chunk is not None:
-                return daef.fit_chunked(cfg, x, chunk_samples=chunk)
-            return daef.fit(cfg, x, n_partitions=n_partitions)
+            else:
+                call = daef._fit_call(cfg, x, n_partitions=n_partitions,
+                                      chunk_samples=chunk)
+            return [call], _only
 
         if plan.mode == "loop":
             seeds, lam_hidden, lam_last = fleet._prepare_fit(
                 cfg, x, seeds, lam_hidden, lam_last
             )
-            models = [
-                daef.fit_chunked(
+            calls = [
+                daef._fit_call(
                     self._tenant_cfg(seeds, lam_hidden, lam_last, i),
-                    x[i], chunk_samples=chunk,
-                )
-                if chunk is not None
-                else daef.fit(
-                    self._tenant_cfg(seeds, lam_hidden, lam_last, i),
-                    x[i], n_partitions=n_partitions,
+                    _tenant(x, i), n_partitions=n_partitions, chunk_samples=chunk,
                 )
                 for i in range(plan.tenants)
             ]
-            return fleet.fleet_from_models(
-                cfg, models, seeds=seeds, lam_hidden=lam_hidden,
-                lam_last=lam_last,
-            )
-        if plan.mode == "vmap":
-            if chunk is not None:
-                return fleet._fit_fleet_chunked(
-                    cfg, x, chunk_samples=chunk, seeds=seeds,
-                    lam_hidden=lam_hidden, lam_last=lam_last,
+
+            def finish(models):
+                return fleet.fleet_from_models(
+                    cfg, models, seeds=seeds, lam_hidden=lam_hidden,
+                    lam_last=lam_last,
                 )
-            return fleet._fit_fleet(
-                cfg, x, seeds=seeds, lam_hidden=lam_hidden, lam_last=lam_last,
-                n_partitions=n_partitions,
+
+            return calls, finish
+        if plan.mode == "vmap":
+            call = fleet._fit_fleet_call(
+                cfg, x, seeds, lam_hidden, lam_last, n_partitions=n_partitions,
+                chunk_samples=chunk,
             )
-        return fleet_sharded._fit_sharded(
-            cfg, x, self.mesh, seeds=seeds, lam_hidden=lam_hidden,
-            lam_last=lam_last, n_partitions=n_partitions, chunk_samples=chunk,
-        )
+        else:
+            call = fleet_sharded._fit_sharded_call(
+                cfg, x, self.mesh, seeds, lam_hidden, lam_last,
+                n_partitions=n_partitions, chunk_samples=chunk,
+            )
+        return [call], _only
 
     @_bumps_model_version
     def fit_stream(
