@@ -4,6 +4,8 @@ Public API:
   activations  — f / f' / f^-1 bundles used by ROLANN
   rolann       — closed-form one-layer solver + incremental merge
   dsvd         — distributed truncated SVD (encoder)
+  eigh         — the encoder's eigh: lane-batched Jacobi for stacks of
+                 small Grams, jnp.linalg.eigh otherwise
   elm_ae       — auxiliary-network decoder-layer trainer (TLD, Alg. 2)
   daef         — DAEFConfig / fit / predict / merge_models / partial_fit
   anomaly      — reconstruction-error thresholds + metrics
@@ -36,6 +38,7 @@ from repro.core import (  # noqa: F401
     anomaly,
     daef,
     dsvd,
+    eigh,
     elm_ae,
     federated,
     fleet,

@@ -17,7 +17,10 @@ from __future__ import annotations
 
 from typing import NamedTuple, Sequence
 
+import jax
 import jax.numpy as jnp
+
+from repro.core import eigh
 
 Array = jnp.ndarray
 
@@ -84,14 +87,23 @@ def masked_gram(x: Array, mask: Array | None = None) -> Array:
 
 
 def gram_to_factors(g: Array) -> SvdFactors:
-    """eigh of the summed Gram == the merged SVD factors (fast path)."""
-    evals, evecs = jnp.linalg.eigh(g)
+    """eigh of the summed Gram == the merged SVD factors (fast path).
+
+    ``g`` is one Gram [m, m] or a stack [..., m, m].  A stack, given whole
+    or under ``vmap``, reaches ``eigh.eigh_stack``, which picks the solver
+    from the stack's shape (``core/eigh.py``)."""
+    if g.ndim > 2:
+        lead = g.shape[:-2]
+        f = jax.vmap(gram_to_factors)(g.reshape(-1, *g.shape[-2:]))
+        return SvdFactors(u=f.u.reshape(*lead, *f.u.shape[1:]),
+                          s=f.s.reshape(*lead, *f.s.shape[1:]))
+    evals, evecs = eigh.eigh(g)
     evals = jnp.maximum(evals, 0.0)
     return SvdFactors(u=canonicalize_signs(evecs[:, ::-1]), s=jnp.sqrt(evals[::-1]))
 
 
 def truncate(f: SvdFactors, rank: int) -> SvdFactors:
-    return SvdFactors(u=f.u[:, :rank], s=f.s[:rank])
+    return SvdFactors(u=f.u[..., :rank], s=f.s[..., :rank])
 
 
 def pad_rank(f: SvdFactors, rank: int) -> SvdFactors:

@@ -314,7 +314,7 @@ def _fit_fleet_stream(
     keys = jax.vmap(lambda s: daef.layer_keys_from_seed(s, len(sizes)))(seeds)
     rank = min(m0, n_total)
     with jax.named_scope("encoder"):
-        enc = jax.vmap(lambda gi: dsvd.truncate(dsvd.gram_to_factors(gi), rank))(g)
+        enc = dsvd.truncate(dsvd.gram_to_factors(g), rank)
         w_enc = enc.u[:, :, : config.latent_dim]
     dtype = w_enc.dtype
 

@@ -9,7 +9,10 @@ compile, for one chip of a described ``v5e:2x2`` topology,
   the creditcard and cardio architectures (paper Table 5), asserting that
   each compiled program holds a Mosaic kernel (``tpu_custom_call``);
 * the einsum fleet fit step (``fleet._fleet_fit``) at the same widths, with
-  its device memory within one v5e chip's 16 GB.
+  its device memory within one v5e chip's 16 GB, its encoder eigh on the
+  lane-batched Jacobi route (``core/eigh.py``: the ``jacobi_eigh`` Mosaic
+  kernel, its only one) and no XLA ``EighTpu``;
+* creditcard's one-model fit program, whose one eigh keeps ``EighTpu``.
 
 Nothing runs.  The topology is described inside a fixture: only the worker
 that is given this file loads the TPU compiler, and a host that cannot
@@ -24,7 +27,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import daef, fleet
+from repro.core import daef, eigh, fleet
 from repro.kernels.rolann_stats import ops
 
 # (layer sizes, training samples per model, models batched into one call):
@@ -114,7 +117,10 @@ def test_rolann_stats_compiles_for_v5e(one_chip, variant, arch):
 
 
 @pytest.mark.parametrize("arch", sorted(ARCHS))
-def test_einsum_fleet_fit_compiles_for_v5e(one_chip, arch):
+def test_einsum_fleet_fit_compiles_for_v5e(one_chip, arch, monkeypatch):
+    # The host is a CPU, where the Jacobi kernel would interpret: compile
+    # it with Mosaic, as on the chip.
+    monkeypatch.setattr(eigh, "_interpret", lambda: False)
     sizes, n, k = ARCHS[arch]
     if arch == "creditcard":
         n //= k            # the 8 federated partitions, fit in one dispatch
@@ -132,4 +138,17 @@ def test_einsum_fleet_fit_compiles_for_v5e(one_chip, arch):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < used < HBM_BYTES, used
-    assert "tpu_custom_call" not in compiled.as_text()
+    text = compiled.as_text()
+    jacobi = k >= eigh.B0 and sizes[0] <= eigh.N_MAX
+    assert jacobi or arch != "cardio"
+    assert ("jacobi_eigh" in text, "EighTpu" in text) == (jacobi, not jacobi)
+    assert text.count('custom_call_target="tpu_custom_call"') == int(jacobi)
+
+
+def test_one_model_fit_keeps_eigh_tpu_for_v5e(one_chip):
+    sizes, n, _ = ARCHS["creditcard"]
+    cfg = daef.DAEFConfig(layer_sizes=sizes, lam_hidden=0.8, lam_last=0.9,
+                          stats_backend="einsum")
+    x = jax.ShapeDtypeStruct((sizes[0], n), jnp.float32, sharding=one_chip)
+    text = daef.lower_fit(cfg, x).compile().as_text()
+    assert "EighTpu" in text and "jacobi_eigh" not in text
