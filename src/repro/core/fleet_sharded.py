@@ -35,7 +35,9 @@ from __future__ import annotations
 
 import functools
 import warnings
+from collections.abc import Callable
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -86,11 +88,12 @@ def shard_fleet(fl: fleet.DAEFFleet, mesh: Mesh) -> fleet.DAEFFleet:
     """Place every fleet leaf with NamedSharding(P("tenants")).
 
     The transfer is sharding-directed: each device receives only its K/D
-    tenant slice, there is no replicated staging copy.
+    tenant slice, there is no replicated staging copy.  A fleet of
+    ``jax.ShapeDtypeStruct``s comes back with the sharding on each leaf.
     """
     _check_divisible(fl.size, mesh, "shard_fleet")
     spec = tenant_sharding(mesh)
-    return jax.tree.map(lambda leaf: jax.device_put(leaf, spec), fl)
+    return jax.tree.map(lambda leaf: daef.put(leaf, spec), fl)
 
 
 def shard_batch(xs, mesh: Mesh) -> Array:
@@ -342,58 +345,73 @@ def _merge_pair_state(config: daef.DAEFConfig):
     return pair
 
 
-@functools.lru_cache(maxsize=None)
-def _merge_tree_fn(config: daef.DAEFConfig, mesh: Mesh, local_rounds: int,
-                   cross_rounds: int):
-    """Build (and cache) the jitted shard_map tree-reduction kernel."""
-    n_dev = mesh.shape[TENANT_AXIS]
-    pair = _merge_pair_state(config)
+def _butterfly(pair, state, n_dev: int, local_rounds: int, cross_rounds: int,
+               carry=()):
+    """The tree reduction both tree kernels run inside their shard_map:
+    ``pair`` (a vmapped pairwise merge) over ``state``, whose leaves lead
+    with this shard's slot axis; ``carry`` leaves are thinned alongside.
 
-    def body(model, seeds, lam_hidden, lam_last):
-        state = (model.encoder_factors, model.layer_knowledge,
-                 model.train_errors)
-
-        # Local phase: groups inside this shard reduce by strided slicing —
-        # on-device views of the local block, not host gathers of the global
-        # sharded array (what fleet_merge_pairwise would do per round).
+    Named scopes: ``merge_local`` (the rounds inside the shard),
+    ``merge_exchange`` (each round's ``ppermute`` and the select of the
+    lower-indexed block) and ``merge_cross`` (each round's merges after it).
+    """
+    # Local phase: groups inside this shard reduce by strided slicing —
+    # on-device views of the local block, not host gathers of the global
+    # sharded array (what fleet_merge_pairwise would do per round).
+    with jax.named_scope("merge_local"):
         for _ in range(local_rounds):
             even = jax.tree.map(lambda leaf: leaf[0::2], state)
             odd = jax.tree.map(lambda leaf: leaf[1::2], state)
-            state = jax.vmap(pair)(even, odd)
-            seeds = seeds[0::2]
-            lam_hidden, lam_last = lam_hidden[0::2], lam_last[0::2]
+            state = pair(even, odd)
+            carry = jax.tree.map(lambda leaf: leaf[0::2], carry)
 
-        # Cross-device phase: one model per device remains; butterfly-reduce
-        # groups of 2^cross_rounds adjacent devices.  d ^ shift never leaves
-        # an aligned power-of-two block, so the same permutation serves every
-        # group at once.
-        if cross_rounds:
-            me = lax.axis_index(TENANT_AXIS)
-            for r in range(cross_rounds):
-                shift = 1 << r
-                perm = [(d, d ^ shift) for d in range(n_dev)]
-                other = jax.tree.map(
-                    lambda leaf: lax.ppermute(leaf, TENANT_AXIS, perm), state
-                )
-                lower_first = (me & shift) == 0
-                a = jax.tree.map(
-                    lambda x, y: jnp.where(lower_first, x, y), state, other
-                )
-                b = jax.tree.map(
-                    lambda x, y: jnp.where(lower_first, y, x), state, other
-                )
-                state = jax.vmap(pair)(a, b)
+    # Cross-device phase: butterfly-reduce groups of 2^cross_rounds adjacent
+    # devices.  d ^ shift never leaves an aligned power-of-two block, so the
+    # same permutation serves every group at once.
+    for r in range(cross_rounds):
+        shift = 1 << r
+        with jax.named_scope("merge_exchange"):
+            perm = [(d, d ^ shift) for d in range(n_dev)]
+            other = jax.tree.map(
+                lambda leaf: lax.ppermute(leaf, TENANT_AXIS, perm), state
+            )
+            lower_first = (lax.axis_index(TENANT_AXIS) & shift) == 0
+            a = jax.tree.map(lambda x, y: jnp.where(lower_first, x, y), state, other)
+            b = jax.tree.map(lambda x, y: jnp.where(lower_first, y, x), state, other)
+        with jax.named_scope("merge_cross"):
+            state = pair(a, b)
+    return state, carry
 
-        def solve(enc, knw, errs, seed, lh, ll):
-            keys = daef.layer_keys_from_seed(seed, len(config.layer_sizes))
-            return daef._model_from_knowledge(config, enc, knw, keys, lh, ll, errs)
 
+def _tree_merge(config: daef.DAEFConfig, n_dev: int, local_rounds: int,
+                cross_rounds: int, model, seeds, lam_hidden, lam_last):
+    """One shard's part of `fleet_merge_tree`: the butterfly over
+    (enc factors, knowledge, errors), then the weights solved once from
+    each merged state (named scope ``merge_solve``)."""
+    state = (model.encoder_factors, model.layer_knowledge, model.train_errors)
+    state, (seeds, lam_hidden, lam_last) = _butterfly(
+        jax.vmap(_merge_pair_state(config)), state, n_dev, local_rounds,
+        cross_rounds, carry=(seeds, lam_hidden, lam_last),
+    )
+
+    def solve(enc, knw, errs, seed, lh, ll):
+        keys = daef.layer_keys_from_seed(seed, len(config.layer_sizes))
+        return daef._model_from_knowledge(config, enc, knw, keys, lh, ll, errs)
+
+    with jax.named_scope("merge_solve"):
         merged = jax.vmap(solve)(*state, seeds, lam_hidden, lam_last)
-        return merged, seeds, lam_hidden, lam_last
+    return merged, seeds, lam_hidden, lam_last
 
+
+@functools.lru_cache(maxsize=None)
+def _merge_tree_fn(config: daef.DAEFConfig, mesh: Mesh, local_rounds: int,
+                   cross_rounds: int):
+    """Build (and cache) the jitted shard_map tree-reduction program; its
+    module is named after `_tree_merge`."""
     spec = P(TENANT_AXIS)
     fn = jax.shard_map(
-        body,
+        partial(_tree_merge, config, mesh.shape[TENANT_AXIS], local_rounds,
+                cross_rounds),
         mesh=mesh,
         in_specs=(spec, spec, spec, spec),
         out_specs=spec,
@@ -410,11 +428,17 @@ def _every_nth(tree, stride: int):
 
 
 def _validate_groups(fl: fleet.DAEFFleet, group_size: int) -> None:
+    """Every group of adjacent tenants shares a seed and lambdas: a host
+    read of the fleet's seeds and lambdas (nothing to read for a fleet of
+    ``jax.ShapeDtypeStruct``s, which only lowers)."""
     fleet._require_concrete(
         (fl,), "fleet_merge_tree",
         remedy="and call it outside jit — it orchestrates device placement "
                "(its shard_map kernel is jitted internally)",
     )
+    if any(isinstance(leaf, jax.ShapeDtypeStruct)
+           for leaf in (fl.seeds, fl.lam_hidden, fl.lam_last)):
+        return
     seeds = np.asarray(fl.seeds).reshape(-1, group_size)
     if not np.array_equal(seeds, np.broadcast_to(seeds[:, :1], seeds.shape)):
         raise ValueError(
@@ -447,6 +471,108 @@ def _mesh_for_merge(fl: fleet.DAEFFleet, group_size: int) -> Mesh:
     return tenant_mesh(max(1, d))
 
 
+def _exchange_bytes(model: daef.DAEFModel, n_dev: int, local_rounds: int,
+                    cross_rounds: int) -> int:
+    """Bytes each device sends in the butterfly of one tree reduce: in cross
+    round r its whole state, (enc factors, knowledge, errors) per remaining
+    slot, the error pool of each slot 2^(local_rounds + r) sites long."""
+    def nbytes(leaf):
+        return int(np.prod(leaf.shape[1:])) * np.dtype(leaf.dtype).itemsize
+
+    fixed = sum(nbytes(leaf) for leaf in jax.tree.leaves(
+        (model.encoder_factors, model.layer_knowledge)))
+    errors = nbytes(model.train_errors)
+    slots = model.train_errors.shape[0] // n_dev >> local_rounds
+    return sum(slots * (fixed + (errors << (local_rounds + r)))
+               for r in range(cross_rounds))
+
+
+class MergeCall(NamedTuple):
+    """One tree reduce: the compiled tree program, the fleet it reads, its
+    mesh and butterfly depth — what `fleet_merge_tree` dispatches and what
+    ``DAEFEngine.lower_reduce`` lowers."""
+
+    fn: Callable
+    fleet: fleet.DAEFFleet
+    mesh: Mesh
+    local_rounds: int
+    cross_rounds: int
+
+    def _args(self) -> tuple:
+        fl = shard_fleet(self.fleet, self.mesh)
+        return fl.model, fl.seeds, fl.lam_hidden, fl.lam_last
+
+    def run(self) -> fleet.DAEFFleet:
+        """Place the fleet, dispatch the program, keep one model per group:
+        the host spans ``reduce.place``, ``reduce.dispatch`` (attribute
+        ``exchange_bytes``) and ``reduce.dedup``."""
+        with obs.span("reduce.place"):
+            args = self._args()
+        sent = _exchange_bytes(self.fleet.model, self.mesh.shape[TENANT_AXIS],
+                               self.local_rounds, self.cross_rounds)
+        with obs.span("reduce.dispatch", exchange_bytes=sent):
+            merged = fleet.DAEFFleet(*self.fn(*args))
+        if self.cross_rounds:
+            # Butterfly results are replicated inside each device group; keep
+            # one representative per group (a compiled strided slice, still
+            # on-mesh).
+            with obs.span("reduce.dedup"):
+                merged = _every_nth(merged, 1 << self.cross_rounds)
+        return merged
+
+    def lower(self):
+        """The lowered tree program ``run`` would dispatch."""
+        return self.fn.lower(*self._args())
+
+
+def _merge_tree_call(config: daef.DAEFConfig, fl: fleet.DAEFFleet,
+                     group_size: int, mesh: Mesh | None) -> MergeCall | None:
+    """Check a tree reduce of ``fl`` (see `fleet_merge_tree`) and build its
+    call; None for ``group_size`` 1, which has nothing to merge."""
+    if group_size < 1 or (group_size & (group_size - 1)):
+        raise ValueError(
+            f"fleet_merge_tree: group_size must be a positive power of two "
+            f"(the butterfly exchanges partner d ^ 2^r each round), got "
+            f"{group_size} — pad each group to the next power of two with "
+            "zero-masked slots and reduce via merge_state_tree, or use "
+            "DAEFEngine.reduce with merge='sequential' (any group size)"
+        )
+    k = fl.size
+    if k % group_size:
+        raise ValueError(
+            f"fleet_merge_tree: group_size {group_size} must divide the "
+            f"fleet size {k}"
+        )
+    _validate_groups(fl, group_size)
+    if group_size == 1:
+        return None
+
+    if mesh is None:
+        mesh = _mesh_for_merge(fl, group_size)
+    if TENANT_AXIS not in mesh.shape:
+        raise ValueError(f"mesh has no '{TENANT_AXIS}' axis: {mesh.axis_names}")
+    d = mesh.shape[TENANT_AXIS]
+    _check_divisible(k, mesh, "fleet_merge_tree")
+    local_k = k // d
+    if group_size <= local_k:
+        if local_k % group_size:
+            raise ValueError(
+                f"per-shard tenant count {local_k} not divisible by "
+                f"group_size {group_size}"
+            )
+        local_rounds, cross_rounds = group_size.bit_length() - 1, 0
+    else:
+        if group_size % local_k or local_k & (local_k - 1):
+            raise ValueError(
+                f"group_size {group_size} spans shards but per-shard tenant "
+                f"count {local_k} is not a power-of-two divisor of it"
+            )
+        local_rounds = local_k.bit_length() - 1
+        cross_rounds = (group_size // local_k).bit_length() - 1
+    return MergeCall(_merge_tree_fn(config, mesh, local_rounds, cross_rounds),
+                     fl, mesh, local_rounds, cross_rounds)
+
+
 def fleet_merge_tree(
     config: daef.DAEFConfig,
     fl: fleet.DAEFFleet,
@@ -474,60 +600,13 @@ def fleet_merge_tree(
     For other group sizes use ``DAEFEngine.reduce`` with
     ``merge='sequential'``; for a SUBSET of participants pad to a power of
     two and reduce the masked states with `merge_state_tree`.
+
+    Host spans (`repro.obs`): ``reduce.prepare``, then ``reduce.place``,
+    ``reduce.dispatch`` and ``reduce.dedup`` (`MergeCall.run`).
     """
-    if group_size < 1 or (group_size & (group_size - 1)):
-        raise ValueError(
-            f"fleet_merge_tree: group_size must be a positive power of two "
-            f"(the butterfly exchanges partner d ^ 2^r each round), got "
-            f"{group_size} — pad each group to the next power of two with "
-            "zero-masked slots and reduce via merge_state_tree, or use "
-            "DAEFEngine.reduce with merge='sequential' (any group size)"
-        )
-    k = fl.size
-    if k % group_size:
-        raise ValueError(
-            f"fleet_merge_tree: group_size {group_size} must divide the "
-            f"fleet size {k}"
-        )
-    _validate_groups(fl, group_size)
-    if group_size == 1:
-        return fl
-
-    if mesh is None:
-        mesh = _mesh_for_merge(fl, group_size)
-    if TENANT_AXIS not in mesh.shape:
-        raise ValueError(f"mesh has no '{TENANT_AXIS}' axis: {mesh.axis_names}")
-    d = mesh.shape[TENANT_AXIS]
-    _check_divisible(k, mesh, "fleet_merge_tree")
-    local_k = k // d
-    if group_size <= local_k:
-        if local_k % group_size:
-            raise ValueError(
-                f"per-shard tenant count {local_k} not divisible by "
-                f"group_size {group_size}"
-            )
-        local_rounds, cross_rounds = group_size.bit_length() - 1, 0
-    else:
-        if group_size % local_k or local_k & (local_k - 1):
-            raise ValueError(
-                f"group_size {group_size} spans shards but per-shard tenant "
-                f"count {local_k} is not a power-of-two divisor of it"
-            )
-        local_rounds = local_k.bit_length() - 1
-        cross_rounds = (group_size // local_k).bit_length() - 1
-
-    fl = shard_fleet(fl, mesh)
-    fn = _merge_tree_fn(config, mesh, local_rounds, cross_rounds)
-    model, seeds, lam_hidden, lam_last = fn(
-        fl.model, fl.seeds, fl.lam_hidden, fl.lam_last
-    )
-    merged = fleet.DAEFFleet(model=model, seeds=seeds, lam_hidden=lam_hidden,
-                             lam_last=lam_last)
-    if cross_rounds:
-        # Butterfly results are replicated inside each device group; keep one
-        # representative per group (a compiled strided slice, still on-mesh).
-        merged = _every_nth(merged, 1 << cross_rounds)
-    return merged
+    with obs.span("reduce.prepare"):
+        call = _merge_tree_call(config, fl, group_size, mesh)
+    return fl if call is None else call.run()
 
 
 # ---------------------------------------------------------------------------
@@ -541,31 +620,11 @@ def _state_tree_fn(config: daef.DAEFConfig, mesh: Mesh, local_rounds: int,
     `_merge_tree_fn` butterfly over (enc factors, knowledge) only — no
     per-slot weight solve, no error pool (both live with the caller)."""
     n_dev = mesh.shape[TENANT_AXIS]
-    pair = _merge_pair_knowledge(config)
+    pair = jax.vmap(_merge_pair_knowledge(config))
 
     def body(enc, knowledge):
-        state = (enc, knowledge)
-        for _ in range(local_rounds):
-            even = jax.tree.map(lambda leaf: leaf[0::2], state)
-            odd = jax.tree.map(lambda leaf: leaf[1::2], state)
-            state = jax.vmap(pair)(even, odd)
-        if cross_rounds:
-            me = lax.axis_index(TENANT_AXIS)
-            for r in range(cross_rounds):
-                shift = 1 << r
-                perm = [(d, d ^ shift) for d in range(n_dev)]
-                other = jax.tree.map(
-                    lambda leaf: lax.ppermute(leaf, TENANT_AXIS, perm), state
-                )
-                lower_first = (me & shift) == 0
-                a = jax.tree.map(
-                    lambda x, y: jnp.where(lower_first, x, y), state, other
-                )
-                b = jax.tree.map(
-                    lambda x, y: jnp.where(lower_first, y, x), state, other
-                )
-                state = jax.vmap(pair)(a, b)
-        return state
+        return _butterfly(pair, (enc, knowledge), n_dev, local_rounds,
+                          cross_rounds)[0]
 
     spec = P(TENANT_AXIS)
     fn = jax.shard_map(

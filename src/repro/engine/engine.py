@@ -743,7 +743,46 @@ class DAEFEngine:
             PlanError: a single model, a group size that does not divide the
                 fleet, a non-power-of-two group under "pairwise"/"tree", or
                 unequal seeds within a group.
+
+        Host spans (`repro.obs`): ``engine.reduce`` (attributes ``tenants``
+        and ``group_size``) over ``reduce.prepare`` (checks, the host read
+        of the seeds and lambdas); under "tree" then ``reduce.place``,
+        ``reduce.dispatch`` (attribute ``exchange_bytes``, the bytes each
+        device sends in the butterfly) and ``reduce.dedup``.
         """
+        with obs.span("engine.reduce", tenants=self.plan.tenants, group_size=group_size):
+            with obs.span("reduce.prepare"):
+                call = self._reduce_call(state, group_size)
+            if group_size == 1:
+                return state
+            if call is not None:
+                return call.run()
+            return self._reduce_on_host(state, group_size)
+
+    def lower_reduce(self, state: fleet.DAEFFleet, group_size: int):
+        """The lowered tree program ``reduce`` would dispatch for ``state``
+        under ``merge="tree"``: ``.compile().as_text()`` is what the devices
+        execute, its ops named by scope (``merge_local``, ``merge_exchange``,
+        ``merge_cross``, ``merge_solve``).
+
+        ``state``'s leaves may be ``jax.ShapeDtypeStruct``s: lowering needs
+        only their shapes and dtypes, and takes the placement from the plan.
+        Raises what ``reduce`` raises for the same arguments, and
+        ``PlanError`` for another merge strategy or a group of one.
+        """
+        if self.plan.merge != "tree":
+            raise PlanError(
+                f"lower_reduce: plan.merge={self.plan.merge!r} runs no tree "
+                "program; use merge='tree'"
+            )
+        call = self._reduce_call(state, group_size)
+        if call is None:
+            raise PlanError("lower_reduce: group_size 1 merges nothing")
+        return call.lower()
+
+    def _reduce_call(self, state, group_size: int):
+        """Check a reduce of ``state``; under "tree" the tree program's call
+        (`fleet_sharded.MergeCall`, None for a group of one), else None."""
         if not self._is_fleet(state, what="reduce"):
             raise PlanError("reduce: a single model has nothing to reduce")
         k, merge = state.size, self.plan.merge
@@ -758,15 +797,19 @@ class DAEFEngine:
                 f"(got {group_size}) — use merge='sequential' for arbitrary "
                 "group sizes"
             )
-        if group_size == 1:
-            return state
         if merge == "tree":
-            return fleet_sharded.fleet_merge_tree(
+            return fleet_sharded._merge_tree_call(
                 self.config, state, group_size,
-                mesh=self.mesh if self.plan.tenant_sharded else None,
+                self.mesh if self.plan.tenant_sharded else None,
             )
-        fleet_sharded._validate_groups(state, group_size)
-        if merge == "pairwise":
+        if group_size > 1:
+            fleet_sharded._validate_groups(state, group_size)
+        return None
+
+    def _reduce_on_host(self, state: fleet.DAEFFleet, group_size: int):
+        """The "pairwise" and "sequential" reduces."""
+        k = state.size
+        if self.plan.merge == "pairwise":
             while group_size > 1:
                 state = fleet.fleet_merge_pairwise(self.config, state)
                 group_size //= 2

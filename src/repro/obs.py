@@ -10,7 +10,9 @@
   thread, outermost first).  Per path the table holds a count and total
   seconds, and the same two numbers again for the spans during which JAX
   traced or compiled (``analysis.retrace``'s counters moved), with the
-  seconds JAX spent tracing, lowering and compiling inside them.
+  seconds JAX spent tracing, lowering and compiling inside them, and the
+  sums of the span's numeric attributes (a counter given as an attribute:
+  its mean per call is its sum over the count).
 
 ``snapshot()`` returns a copy of the table.  The store is always on: a
 span costs a few microseconds of host time.  Spans open only in host
@@ -24,6 +26,17 @@ Span names on the fit path (``docs/architecture.md``)::
                                 seeds and lambdas
       fit.place                 the input put on the program's device(s)
       fit.dispatch              the call of the compiled fit program
+
+and on the reduce path::
+
+    engine.reduce               DAEFEngine.reduce (attributes tenants,
+                                group_size)
+      reduce.prepare            checks, the host read of the seeds and
+                                lambdas, the tree program and its mesh
+      reduce.place              the fleet put on the tenant mesh
+      reduce.dispatch           the call of the tree program (attribute
+                                exchange_bytes: bytes each device sends)
+      reduce.dedup              one model kept per replicated group
 """
 from __future__ import annotations
 
@@ -37,7 +50,8 @@ from repro.analysis import retrace
 
 _LOCAL = threading.local()
 _LOCK = threading.Lock()
-# path -> [count, seconds, compiled_count, compiled_seconds, jax_seconds]
+# path -> [count, seconds, compiled_count, compiled_seconds, jax_seconds,
+#          {numeric attribute: sum}]
 _TABLE: dict[str, list] = {}
 
 
@@ -77,9 +91,12 @@ class _Span:
         with _LOCK:
             row = _TABLE.get(self._path)
             if row is None:
-                row = _TABLE[self._path] = [0, 0.0, 0, 0.0, 0.0]
+                row = _TABLE[self._path] = [0, 0.0, 0, 0.0, 0.0, {}]
             row[0] += 1
             row[1] += seconds
+            for key, value in self._attrs.items():
+                if isinstance(value, (int, float)) and not isinstance(value, bool):
+                    row[5][key] = row[5].get(key, 0) + value
             if compiled:
                 row[2] += 1
                 row[3] += seconds
@@ -102,8 +119,10 @@ def snapshot() -> dict[str, dict]:
     """A copy of the span table: per path ``count`` and ``seconds``, and of
     those the spans during which JAX traced or compiled: ``compiled_count``,
     ``compiled_seconds`` and ``jax_seconds`` (JAX's own trace, lowering and
-    compile seconds inside them)."""
+    compile seconds inside them); ``attrs``, the sums of the spans' numeric
+    attributes."""
     with _LOCK:
-        rows = {path: list(row) for path, row in _TABLE.items()}
-    keys = ("count", "seconds", "compiled_count", "compiled_seconds", "jax_seconds")
+        rows = {path: [*row[:5], dict(row[5])] for path, row in _TABLE.items()}
+    keys = ("count", "seconds", "compiled_count", "compiled_seconds", "jax_seconds",
+            "attrs")
     return {path: dict(zip(keys, row, strict=True)) for path, row in rows.items()}

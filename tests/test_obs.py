@@ -174,12 +174,23 @@ def test_no_span_is_recorded_inside_traced_code():
     assert not [p for p in snap if "t_traced.inside" in p]
 
 
+def test_numeric_attributes_are_summed_per_path():
+    for sent in (3, 4.5):
+        with obs.span("t_attrs.span", sent=sent, label="x", flag=True):
+            pass
+    row = obs.snapshot()["t_attrs.span"]
+    assert row["count"] == 2
+    assert row["attrs"] == {"sent": 7.5}
+
+
 def test_snapshot_is_a_copy():
     with obs.span("t_copy"):
         pass
     snap = obs.snapshot()
     snap["t_copy"]["count"] = 99
+    snap["t_copy"]["attrs"]["n"] = 1
     assert obs.snapshot()["t_copy"]["count"] == 1
+    assert obs.snapshot()["t_copy"]["attrs"] == {}
 
 
 @pytest.mark.parametrize("name", ["single", "vmap", "chunked"])
