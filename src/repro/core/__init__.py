@@ -3,6 +3,8 @@
 Public API:
   activations  — f / f' / f^-1 bundles used by ROLANN
   rolann       — closed-form one-layer solver + incremental merge
+  chol         — the decoder's Cholesky solves: a lane-batched kernel for
+                 stacks of small systems, XLA's cholesky otherwise
   dsvd         — distributed truncated SVD (encoder)
   eigh         — the encoder's eigh: lane-batched Jacobi for stacks of
                  small Grams, jnp.linalg.eigh otherwise
@@ -36,6 +38,7 @@ blocks, so training is also available as a bounded-memory fold —
 from repro.core import (  # noqa: F401
     activations,
     anomaly,
+    chol,
     daef,
     dsvd,
     eigh,

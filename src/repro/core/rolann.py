@@ -33,7 +33,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import activations, stats_backend
+from repro.core import activations, chol, stats_backend
 
 Array = jnp.ndarray
 
@@ -336,18 +336,13 @@ def _solve_stats_chol(stats: RolannStats, lam) -> Array:
     G = U S^2 U^T with a full orthonormal eigenbasis, so this is the same
     linear system the eigh route diagonalizes — one triangular factorization
     (O(m^3/3), small constant) instead of a batched symmetric eigendecomposition.
+    ``chol.chol_solve`` routes the stack of every output (and, under the
+    fleet's ``vmap``, of every tenant) by its shape.
     """
-    m_dim = stats.m.shape[-1]
-    eye = jnp.eye(m_dim, dtype=stats.g.dtype)
+    a = stats.g + lam * jnp.eye(stats.m.shape[-1], dtype=stats.g.dtype)
     if stats.shared_f:
-        chol = jnp.linalg.cholesky(stats.g + lam * eye)
-        return jax.scipy.linalg.cho_solve((chol, True), stats.m.T)  # [m, out]
-
-    def one(g, m_j):
-        chol = jnp.linalg.cholesky(g + lam * eye)
-        return jax.scipy.linalg.cho_solve((chol, True), m_j)
-
-    return jax.vmap(one)(stats.g, stats.m).T  # [m, out]
+        return chol.chol_solve(a, stats.m.T)  # [m, out]
+    return jax.vmap(chol.chol_solve)(a, stats.m).T  # [m, out]
 
 
 def solve(

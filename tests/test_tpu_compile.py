@@ -5,14 +5,19 @@ see what only the TPU compiler (Mosaic) refuses: block shapes off the
 (8, 128) tiling, in-kernel relayouts, VMEM overuse.  These tests lower and
 compile, for one chip of a described ``v5e:2x2`` topology,
 
+* the ``chol_solve`` kernel on the cardio fleet's decoder stacks;
 * all six ``rolann_stats`` kernel variants at every nonlinear layer width of
   the creditcard and cardio architectures (paper Table 5), asserting that
   each compiled program holds a Mosaic kernel (``tpu_custom_call``);
 * the einsum fleet fit step (``fleet._fleet_fit``) at the same widths, with
   its device memory within one v5e chip's 16 GB, its encoder eigh on the
   lane-batched Jacobi route (``core/eigh.py``: the ``jacobi_eigh`` Mosaic
-  kernel, its only one) and no XLA ``EighTpu``;
-* creditcard's one-model fit program, whose one eigh keeps ``EighTpu``.
+  kernel) and no XLA ``EighTpu``, and each decoder stack of at least
+  ``chol.B0`` systems on the lane-batched Cholesky route (``core/chol.py``:
+  one ``chol_solve`` Mosaic kernel a stack); at cardio's widths no XLA
+  ``Cholesky`` is left;
+* creditcard's one-model fit program, whose one eigh keeps ``EighTpu`` and
+  whose solves keep XLA's ``Cholesky``.
 
 Nothing runs.  The topology is described inside a fixture: only the worker
 that is given this file loads the TPU compiler, and a host that cannot
@@ -21,13 +26,14 @@ is off around these compiles (a compile for a described device is written
 to it but cannot be read back without the device).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import daef, eigh, fleet
+from repro.core import chol, daef, eigh, fleet
 from repro.kernels.rolann_stats import ops
 
 # (layer sizes, training samples per model, models batched into one call):
@@ -116,10 +122,40 @@ def test_rolann_stats_compiles_for_v5e(one_chip, variant, arch):
         assert "tpu_custom_call" in compiled.as_text(), (variant, m_l, ma)
 
 
+@pytest.mark.parametrize("batch, n, r", [
+    (1024 * 4, 9, 1), (1024 * 8, 13, 1), (1024 * 12, 17, 1), (1024, 17, 21),
+    (chol.B0, chol.N_MAX, 1),
+])
+def test_chol_solve_compiles_for_v5e(one_chip, batch, n, r):
+    """The Cholesky kernel on the cardio fleet's decoder stacks (1,024
+    tenants) and on the widest stack the route takes."""
+    from repro.kernels.chol_solve import chol_solve
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    compiled = jax.jit(lambda a, b: chol_solve(a, b, interpret=False)).lower(
+        spec(batch, n, n), spec(batch, n, r)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+def _solve_kernels(sizes, k: int) -> int:
+    """The decoder stacks of a K-tenant fleet fit that ``chol`` sends to its
+    kernel: K x o systems of n = the layer's width + 1 for each ELM-AE
+    layer (o = its input width), K shared systems for the last layer."""
+    stacks = [(k * sizes[li - 1], sizes[li] + 1) for li in range(2, len(sizes) - 1)]
+    stacks.append((k, sizes[-2] + 1))
+    return sum(b >= chol.B0 and n <= chol.N_MAX for b, n in stacks)
+
+
+def _has_chol_scope(text: str) -> bool:
+    return re.search(r'op_name="[^"]*\bchol_solve/', text) is not None
+
+
 @pytest.mark.parametrize("arch", sorted(ARCHS))
 def test_einsum_fleet_fit_compiles_for_v5e(one_chip, arch, monkeypatch):
-    # The host is a CPU, where the Jacobi kernel would interpret: compile
-    # it with Mosaic, as on the chip.
+    # The host is a CPU, where the Jacobi and Cholesky kernels would
+    # interpret: compile them with Mosaic, as on the chip.
     monkeypatch.setattr(eigh, "_interpret", lambda: False)
     sizes, n, k = ARCHS[arch]
     if arch == "creditcard":
@@ -142,7 +178,10 @@ def test_einsum_fleet_fit_compiles_for_v5e(one_chip, arch, monkeypatch):
     jacobi = k >= eigh.B0 and sizes[0] <= eigh.N_MAX
     assert jacobi or arch != "cardio"
     assert ("jacobi_eigh" in text, "EighTpu" in text) == (jacobi, not jacobi)
-    assert text.count('custom_call_target="tpu_custom_call"') == int(jacobi)
+    solves = _solve_kernels(sizes, k)
+    assert solves > 0 and _has_chol_scope(text)
+    assert text.count('custom_call_target="tpu_custom_call"') == int(jacobi) + solves
+    assert arch != "cardio" or 'custom_call_target="Cholesky"' not in text
 
 
 def test_one_model_fit_keeps_eigh_tpu_for_v5e(one_chip):
@@ -152,3 +191,4 @@ def test_one_model_fit_keeps_eigh_tpu_for_v5e(one_chip):
     x = jax.ShapeDtypeStruct((sizes[0], n), jnp.float32, sharding=one_chip)
     text = daef.lower_fit(cfg, x).compile().as_text()
     assert "EighTpu" in text and "jacobi_eigh" not in text
+    assert 'custom_call_target="Cholesky"' in text and not _has_chol_scope(text)
