@@ -4,7 +4,7 @@ For each kernel kind (one-shot stats, accumulating stats, fused-chunk fold)
 and each benchmark shape, every candidate sample-axis block in
 ``autotune.CANDIDATE_BLOCKS`` is timed and the winner recorded.  With
 ``--write-cache`` the winners — plus the measured einsum-vs-fused verdict
-that ``stats_backend.resolve("auto")`` consults — are merged into the
+that ``stats_backend.producer()`` consults — are merged into the
 committed per-platform cache (``src/repro/kernels/autotune_cache.json``).
 
 Each record carries attained GFLOP/s from the analytic contraction count
@@ -36,7 +36,7 @@ import numpy as np
 
 from repro.core import stats_backend
 from repro.kernels import autotune
-from repro.kernels.rolann_stats import ops
+from repro.kernels.rolann_stats import ops, rolann_stats
 from repro.launch import roofline
 
 REPO_ROOT = Path(__file__).resolve().parent.parent
@@ -156,13 +156,13 @@ def sweep(repeats: int) -> list[dict]:
 
 def backend_verdict(repeats: int) -> dict:
     """Measured einsum-vs-fused verdict on the largest sweep shape — what
-    ``"auto"`` resolves to on this platform."""
+    ``stats_backend.producer()`` returns on this platform once cached."""
     m, n, o = SHAPES[-1]
     p = _problem(m, n, o)
     times = {}
-    for backend in stats_backend.BACKENDS:
-        fn = jax.jit(lambda a, b, c, _bk=backend: stats_backend.gram_stats(
-            a, b, c, backend=_bk))
+    producers = {"einsum": stats_backend.einsum_stats, "fused": rolann_stats}
+    for backend, stats in producers.items():
+        fn = jax.jit(stats)
         jax.block_until_ready(fn(p["xa"], p["fsq"], p["fd"]))
         times[backend] = _timed(lambda: fn(p["xa"], p["fsq"], p["fd"]),
                                 repeats)

@@ -1,9 +1,11 @@
-"""Gram-stats backend benchmark: einsum vs the fused Pallas kernel.
+"""Gram-stats producer benchmark: einsum vs the fused Pallas kernel.
 
 Times the per-output sufficient statistics G[o] = Xa diag(f'^2) Xa^T,
-M[o] = Xa (f'^2 d̄) — DAEF's training hot-spot — through both stats
-backends (`repro.core.stats_backend`) over several shapes, plus one
-end-to-end `daef.fit` per backend, and writes the record to
+M[o] = Xa (f'^2 d̄) — DAEF's training hot-spot — through both producers
+(`stats_backend.einsum_stats` and the `rolann_stats` kernel, called
+directly) over several shapes, plus one end-to-end `daef.fit` per producer
+(selected through the autotune cache, `repro.testing.producer`), and
+writes the record to
 ``BENCH_stats.json`` (default: the repo root, so the perf trajectory
 accumulates in-tree per PR).
 
@@ -11,7 +13,7 @@ Interpretation note: on CPU the fused kernel runs in Pallas *interpret
 mode* — a correctness harness, not a fast path — so fused timings on this
 container measure interpreter overhead, not the TPU win.  The number that
 matters on CPU is parity (`max_abs_err`); the fused speedup is a TPU
-(Mosaic-compiled) claim.  See README "Stats backends".
+(Mosaic-compiled) claim.  See README "Gram-stats producers".
 
   PYTHONPATH=src python benchmarks/stats_backends.py [--repeats 3]
 """
@@ -27,11 +29,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import daef, stats_backend
+from repro.kernels.rolann_stats import rolann_stats
+from repro.testing.producer import stats_producer
 
 REPO_ROOT = Path(__file__).resolve().parent.parent
 
 # (m, n, o): feature rows of Xa, samples, output neurons.
 SHAPES = [(9, 2048, 8), (17, 8192, 16), (33, 4096, 33)]
+
+#: Each producer's one-model Gram stats, as ``stats_backend.gram_stats``
+#: runs them.
+PRODUCERS = {"einsum": stats_backend.einsum_stats, "fused": rolann_stats}
 
 
 def _timed(f, repeats: int) -> float:
@@ -53,9 +61,8 @@ def bench_shapes(repeats: int) -> list[dict]:
 
         runs = {}
         outs = {}
-        for backend in stats_backend.BACKENDS:
-            fn = jax.jit(lambda a, b, c, _bk=backend: stats_backend.gram_stats(
-                a, b, c, backend=_bk))
+        for backend, stats in PRODUCERS.items():
+            fn = jax.jit(stats)
             outs[backend] = jax.block_until_ready(fn(xa, fsq, fd))  # compile
             runs[backend] = _timed(lambda: fn(xa, fsq, fd), repeats)
         err = max(
@@ -80,17 +87,15 @@ def bench_shapes(repeats: int) -> list[dict]:
 
 
 def bench_fit(repeats: int) -> dict:
-    import dataclasses
-
     m0, n = 16, 4096
     cfg = daef.DAEFConfig(layer_sizes=(m0, 4, 8, m0), lam_hidden=0.5, lam_last=0.9)
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.normal(size=(m0, n)), jnp.float32)
     times = {}
-    for backend in stats_backend.BACKENDS:
-        cfg_b = dataclasses.replace(cfg, stats_backend=backend)
-        daef.fit(cfg_b, x)  # compile/trace warmup
-        times[backend] = _timed(lambda: daef.fit(cfg_b, x), repeats)
+    for backend in stats_backend.PRODUCERS:
+        with stats_producer(backend):
+            daef.fit(cfg, x)  # compile/trace warmup
+            times[backend] = _timed(lambda: daef.fit(cfg, x), repeats)
     rec = {
         "shape": {"m0": m0, "n": n, "layers": list(cfg.layer_sizes)},
         "einsum_ms": times["einsum"] * 1e3,

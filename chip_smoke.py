@@ -201,6 +201,7 @@ def fit_phase(clock: Clock, scale: float = 1.0):
     from repro.core import daef, stats_backend
     from repro.engine import DAEFEngine, ExecutionPlan
     from repro.kernels.rolann_stats import ops
+    from repro.testing.producer import stats_producer
 
     x_train, x_test = creditcard(scale)
     cfg = _config(CREDITCARD)
@@ -208,9 +209,9 @@ def fit_phase(clock: Clock, scale: float = 1.0):
           f"{x_train.shape[0]} features (fold 0), layers {cfg.layer_sizes}")
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
-        auto = stats_backend.resolve("auto")
+        auto = stats_backend.producer()
     why = "; ".join(str(w.message) for w in caught) or "measured entry"
-    print(f"  stats_backend 'auto' resolves to {auto!r} ({why})")
+    print(f"  Gram-stats producer {auto!r} ({why})")
     interpret = ops._resolve_interpret(None)
     check(interpret is False, "Pallas kernels resolve to Mosaic, not the "
                               "interpreter (_resolve_interpret(None) is False)")
@@ -221,24 +222,22 @@ def fit_phase(clock: Clock, scale: float = 1.0):
     fits = {}
     for chunk in (None, CHUNK):
         plan_name = "one-shot" if chunk is None else f"chunk {chunk}"
+        engine = DAEFEngine(cfg, ExecutionPlan(chunk_samples=chunk))
         with clock(f"fit {plan_name}, einsum, CPU float32 reference"), \
-                jax.default_device(cpu):
-            engine = DAEFEngine(cfg, ExecutionPlan(stats_backend="einsum",
-                                                   chunk_samples=chunk))
+                jax.default_device(cpu), stats_producer("einsum"):
             ref = engine.fit(x_cpu)
             ref_scores = engine.scores(ref, xt_cpu)
             jax.block_until_ready((ref, ref_scores))
         for backend in ("einsum", "fused"):
             tag = f"fit {plan_name}, {backend}"
-            engine = DAEFEngine(cfg, ExecutionPlan(stats_backend=backend,
-                                                   chunk_samples=chunk))
-            with clock(tag):
-                model = engine.fit(x_dev)
-                scores = engine.scores(model, xt_dev)
-                jax.block_until_ready((model, scores))
-            with clock(f"{tag}: read back the compiled fit"):
-                program = daef.lower_fit(engine.config, x_dev,
-                                         chunk_samples=chunk).compile()
+            with stats_producer(backend):
+                with clock(tag):
+                    model = engine.fit(x_dev)
+                    scores = engine.scores(model, xt_dev)
+                    jax.block_until_ready((model, scores))
+                with clock(f"{tag}: read back the compiled fit"):
+                    program = daef.lower_fit(engine.config, x_dev,
+                                             chunk_samples=chunk).compile()
             kernels = program.as_text().count("tpu_custom_call")
             print(f"  {tag}: {kernels} Mosaic kernel call(s) in the compiled fit")
             check((kernels > 0) == (backend == "fused"),
@@ -254,6 +253,7 @@ def serve_phase(clock: Clock, k: int = TENANTS, rounds: int = 4,
     FleetServer; served scores must equal engine.scores."""
     from repro.engine import DAEFEngine, ExecutionPlan
     from repro.serving import FleetServer
+    from repro.testing.producer import stats_producer
 
     xs, tests = cardio_fleet(k, scale)
     cfg = _config(CARDIO)
@@ -261,9 +261,9 @@ def serve_phase(clock: Clock, k: int = TENANTS, rounds: int = 4,
           f"layers {cfg.layer_sizes}")
     fleets = {}
     for backend in ("einsum", "fused"):
-        engine = DAEFEngine(cfg, ExecutionPlan(mode="vmap", tenants=k,
-                                               stats_backend=backend))
-        with clock(f"serve: fleet fit, {backend}, one dispatch"):
+        engine = DAEFEngine(cfg, ExecutionPlan(mode="vmap", tenants=k))
+        with clock(f"serve: fleet fit, {backend}, one dispatch"), \
+                stats_producer(backend):
             fl = engine.fit(xs, seeds=jnp.arange(k))
             jax.block_until_ready(fl.model)
         fleets[backend] = (engine, fl)
@@ -327,8 +327,7 @@ def federate_phase(clock: Clock, x_train, x_test, central):
     parts = np.array_split(x_train, PARTITIONS, axis=1)
     print(f"federate: {PARTITIONS} creditcard partitions of "
           f"{parts[0].shape[1]} samples")
-    engine = DAEFEngine(cfg, ExecutionPlan(merge="sequential",
-                                           stats_backend="einsum"))
+    engine = DAEFEngine(cfg, ExecutionPlan(merge="sequential"))
     x_dev = jax.device_put(x_test)
     with clock("federate: sequential round"):
         model = engine.session().round(parts)
@@ -338,7 +337,7 @@ def federate_phase(clock: Clock, x_train, x_test, central):
                    (scores, engine.scores(central, x_dev)))
 
     plan = ExecutionPlan(federation="async", merge="pairwise",
-                         max_staleness=1, stats_backend="einsum")
+                         max_staleness=1)
     session = DAEFEngine(cfg, plan).session()
     blocks = [np.array_split(p, 2, axis=1) for p in parts]
     straggler = PARTITIONS - 1
@@ -385,6 +384,7 @@ def mesh_fleet_phase(clock: Clock, devices: int, k: int = TENANTS,
     tree merge, against the same plan on a one-device mesh."""
     from repro.core import fleet
     from repro.engine import DAEFEngine, ExecutionPlan
+    from repro.testing.producer import stats_producer
 
     xs, tests = cardio_fleet(k, scale)
     cfg = _config(CARDIO)
@@ -396,10 +396,9 @@ def mesh_fleet_phase(clock: Clock, devices: int, k: int = TENANTS,
     results = {}
     for name, d in (("one chip", 1), ("mesh", devices)):
         engine = DAEFEngine(cfg, ExecutionPlan(mode="mesh", tenants=k,
-                                               mesh_devices=d,
-                                               stats_backend="fused",
-                                               merge="tree"))
-        with clock(f"mesh fleet: fit + scores + reduce, {name}"):
+                                               mesh_devices=d, merge="tree"))
+        with clock(f"mesh fleet: fit + scores + reduce, {name}"), \
+                stats_producer("fused"):
             fl = engine.fit(xs, seeds=seeds)
             scores = engine.scores(fl, x_test)
             merged = engine.reduce(fl, group_size=k)
@@ -435,14 +434,13 @@ def mesh_data_phase(clock: Clock, devices: int, scale: float = 1.0):
     cfg = _config(CREDITCARD)
     print(f"mesh data: creditcard, {x_train.shape[1]} samples sharded over a "
           f"{devices}-device 'data' mesh, against one chip")
-    one = DAEFEngine(cfg, ExecutionPlan(stats_backend="einsum"))
+    one = DAEFEngine(cfg)
     with clock("mesh data: one-chip fit"):
         ref = one.fit(jnp.asarray(x_train))
         ref_scores = one.scores(ref, x_test)
         jax.block_until_ready((ref, ref_scores))
     engine = DAEFEngine(cfg, ExecutionPlan(mode="mesh", mesh_axes=("data",),
-                                           mesh_devices=devices,
-                                           stats_backend="einsum"))
+                                           mesh_devices=devices))
     with clock("mesh data: sharded fit"):
         model = engine.fit(jnp.asarray(x_train))
         scores = one.scores(model, x_test)

@@ -9,8 +9,9 @@ Rules (each finding carries file:line:col, a rule id and a fix hint):
 * **RPR002** — ``os.environ`` / ``os.getenv`` read inside a jit-traced
   body (the value is baked into one trace and the jit cache goes stale
   when the env flips), or at import time of a library module (the
-  process can never flip it again).  Resolve at call time, pre-trace —
-  the ``DAEFConfig.stats_backend``/``resolved()`` idiom.
+  process can never flip it again).  Read it in a plain function called
+  before any trace and pass the value on — the ``compile_cache.enable()``
+  idiom.
 * **RPR003** — host ``np.*`` call applied to a value that flows from a
   jit-traced function's parameters: a tracer leak (``TracerArrayConversionError``
   at best, a silent device sync at worst).  Use ``jnp.*`` inside traced code.
@@ -377,8 +378,8 @@ class _Checker(ast.NodeVisitor):
                 node, "RPR002",
                 f"{what} inside a jit-traced body: the value is baked into "
                 "this trace and the cache goes stale when the env flips",
-                "resolve before trace time and pass the value in (the "
-                "stats_backend resolved() idiom)",
+                "read it before trace time and pass the value in (the "
+                "compile_cache.enable() idiom)",
             )
         elif self.library and self._at_module_level():
             self.add(

@@ -21,7 +21,7 @@ The unified engine (``repro.engine``): client code should not pick between
 these execution paths by importing different modules — construct a
 ``DAEFEngine`` from a ``DAEFConfig`` plus a declarative ``ExecutionPlan``
 (mode="loop"|"vmap"|"mesh", tenants=K, mesh_axes/mesh_devices,
-stats_backend, merge="sequential"|"pairwise"|"tree", chunk_samples for
+merge="sequential"|"pairwise"|"tree", chunk_samples for
 streamed training) and use one spelling of ``fit / fit_stream /
 partial_fit / predict / scores / merge / reduce / save / load`` plus the
 round-based ``FederationSession``.  The engine dispatches to the modules

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.core import activations, dsvd, elm_ae, rolann, stats_backend
+from repro.core import activations, dsvd, elm_ae, rolann
 
 Array = jnp.ndarray
 
@@ -51,10 +51,6 @@ class DAEFConfig:
     aux_bias: str = "zero"            # decoder bias scheme (see elm_ae)
     method: str = "gram"              # "gram" fast path | "svd" paper-faithful
     seed: int = 0                     # shared randomness across federated nodes
-    # Gram-stats producer: "einsum" | "fused" | "auto" (measured winner from
-    # the autotune cache); None defers to $REPRO_STATS_BACKEND then "auto".
-    stats_backend: str | None = None
-                                      # | None (resolve $REPRO_STATS_BACKEND)
     gram_solver: str = "chol"         # gram-knowledge weight solve: "chol"
                                       # (direct Cholesky, the fast default) |
                                       # "eigh" (factorization route) | "auto"
@@ -70,25 +66,11 @@ class DAEFConfig:
                 f"autoencoder must reconstruct its input: "
                 f"{self.layer_sizes[0]} != {self.layer_sizes[-1]}"
             )
-        if self.stats_backend is not None:
-            stats_backend.resolve(self.stats_backend)  # raises on unknown names
         if self.gram_solver not in rolann.GRAM_SOLVERS:
             raise ValueError(
                 f"unknown gram_solver {self.gram_solver!r}: choose from "
                 f"{rolann.GRAM_SOLVERS}"
             )
-
-    def resolved(self) -> "DAEFConfig":
-        """This config with ``stats_backend`` made concrete (env resolved).
-
-        Public entry points call this *before* handing the config to a jitted
-        kernel as a static argument, so the resolved backend — not the
-        mutable environment — keys the jit cache.
-        """
-        concrete = stats_backend.resolve(self.stats_backend)
-        if concrete == self.stats_backend:
-            return self
-        return dataclasses.replace(self, stats_backend=concrete)
 
     @property
     def latent_dim(self) -> int:
@@ -219,7 +201,6 @@ def _fit_call(config: DAEFConfig, x: Array, *, n_partitions: int = 1,
     m0 = x.shape[0]
     if m0 != config.layer_sizes[0]:
         raise ValueError(f"input dim {m0} != layer_sizes[0] {config.layer_sizes[0]}")
-    config = config.resolved()
     # The program reads the seed and the lambdas only through its arguments;
     # keying it on the rest lets per-tenant configs share one compile.
     shared = dataclasses.replace(config, seed=0, lam_hidden=0.0, lam_last=0.0)
@@ -274,7 +255,6 @@ def _fit_core(
                 init=config.init,
                 aux_bias=config.aux_bias,
                 method=config.method,
-                backend=config.stats_backend,
                 gram_solver=config.gram_solver,
             )
         weights.append(res.w)
@@ -286,7 +266,7 @@ def _fit_core(
     with jax.named_scope(f"layer{len(sizes) - 1}"):
         w_ll, b_ll, k_ll = rolann.fit(
             h, x, f_ll, lam_last, method=config.method,
-            backend=config.stats_backend, gram_solver=config.gram_solver,
+            gram_solver=config.gram_solver,
         )
     weights.append(w_ll)
     biases.append(b_ll)
@@ -398,8 +378,7 @@ def _fit_chunked_core(
                 xcg, mk = inp
                 h = _stream_forward(config, xcg, *_solved)
                 stats = elm_ae.accumulate_layer_stats(
-                    stats, _wc1, _bc1, h, f_hl, weights=mk,
-                    backend=config.stats_backend,
+                    stats, _wc1, _bc1, h, f_hl, weights=mk
                 )
                 return stats, None
 
@@ -420,9 +399,7 @@ def _fit_chunked_core(
     def last_step(stats, inp):
         xcg, mk = inp
         h = _stream_forward(config, xcg, *solved)
-        stats = rolann.accumulate_stats(
-            stats, h, xcg, f_ll, weights=mk, backend=config.stats_backend
-        )
+        stats = rolann.accumulate_stats(stats, h, xcg, f_ll, weights=mk)
         return stats, None
 
     with jax.named_scope(f"layer{len(sizes) - 1}"):
@@ -538,9 +515,7 @@ def _stream_layer_step(config, stats, params, x, mask):
     weights, biases, w_c1, b_c1 = params
     f_hl, _ = _acts(config)
     h = _stream_forward(config, x, weights, biases)
-    return elm_ae.accumulate_layer_stats(
-        stats, w_c1, b_c1, h, f_hl, weights=mask, backend=config.stats_backend
-    )
+    return elm_ae.accumulate_layer_stats(stats, w_c1, b_c1, h, f_hl, weights=mask)
 
 
 @partial(jax.jit, static_argnames=("config",), donate_argnums=(1,))
@@ -548,9 +523,7 @@ def _stream_last_step(config, stats, params, x, mask):
     weights, biases = params
     _, f_ll = _acts(config)
     h = _stream_forward(config, x, weights, biases)
-    return rolann.accumulate_stats(
-        stats, h, x, f_ll, weights=mask, backend=config.stats_backend
-    )
+    return rolann.accumulate_stats(stats, h, x, f_ll, weights=mask)
 
 
 def _errors_chunk(config, params, x):
@@ -580,7 +553,6 @@ def fit_stream(config: DAEFConfig, batches) -> DAEFModel:
     Numerically matches ``fit(config, concatenate(batches))`` (gram method)
     within accumulation-order float error.
     """
-    config = config.resolved()
     _require_gram(config, "fit_stream")
     factory = _stream_chunk_source(batches)
     keys = config.layer_keys()

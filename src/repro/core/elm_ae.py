@@ -57,7 +57,6 @@ def train_layer(
     init: str = "xavier",
     aux_bias: str = "zero",
     method: str = "gram",
-    backend: str | None = None,
     gram_solver: str = "chol",
 ) -> LayerResult:
     """Alg. 2: train the decoder layer mapping H_l [m_l, n] -> H_{l+1}."""
@@ -71,8 +70,7 @@ def train_layer(
     # W_{l+1} in R^{m_l x m_next} so that H_{l+1} = f(W_{l+1}^T H_l + b 1^T)
     # (Eq. 4); the ELM-AE transpose trick W_{l+1} = W_c2^T gives exactly that.
     w_c2, _b_c2, knowledge = rolann.fit(
-        h_c1, h_l, act, lam, method=method, backend=backend,
-        gram_solver=gram_solver,
+        h_c1, h_l, act, lam, method=method, gram_solver=gram_solver,
     )
     with jax.named_scope("solve"):
         w_next = w_c2.T  # [m_l, m_next]
@@ -97,7 +95,6 @@ def layer_knowledge_from_partition(
     init: str = "xavier",
     method: str = "gram",
     factorization: str = "direct_svd",
-    backend: str | None = None,
 ) -> rolann.RolannFactors | rolann.RolannStats:
     """Federated building block: compute ONLY the mergeable ROLANN statistics
     of this partition for the given decoder layer (stage-1 randomness is
@@ -107,9 +104,9 @@ def layer_knowledge_from_partition(
         w_c1, b_c1 = stage1(key, m_l, m_next, init, h_l.dtype)
         h_c1 = act.fn(w_c1.T @ h_l + b_c1[:, None])
     if method == "gram":
-        return rolann.compute_stats(h_c1, h_l, act, backend=backend)
+        return rolann.compute_stats(h_c1, h_l, act)
     if factorization == "gram_eigh":
-        return rolann.compute_factors_via_gram(h_c1, h_l, act, backend=backend)
+        return rolann.compute_factors_via_gram(h_c1, h_l, act)
     return rolann.compute_factors(h_c1, h_l, act)
 
 
@@ -121,7 +118,6 @@ def accumulate_layer_stats(
     act: activations.Activation,
     *,
     weights: Array | None = None,
-    backend: str | None = None,
 ) -> rolann.RolannStats:
     """Streaming building block: fold one sample chunk of layer inputs
     ``h_l`` [m_l, n_chunk] into the decoder layer's running ROLANN statistics.
@@ -132,26 +128,22 @@ def accumulate_layer_stats(
     equals `train_layer`'s one-shot statistics, so the solved weights match
     the non-streaming fit.  ``weights`` masks padded sample columns.
 
-    On the fused backend (non-linear activations) the whole fold is ONE
-    ``stats_backend.fused_chunk_acc`` dispatch — the stage-1 matmul,
-    activation, target transform and (G, M) accumulate run in a single
-    Pallas launch, so the chunk activation never materializes to HBM.  The
-    einsum backend (and the linear last layer, which has a cheaper shared-F
-    closed form) keeps the two-step path below.
+    Where ``stats_backend.producer()`` is the fused one (non-linear
+    activations) the whole fold is ONE ``stats_backend.fused_chunk_acc``
+    dispatch — the stage-1 matmul, activation, target transform and (G, M)
+    accumulate run in a single Pallas launch, so the chunk activation never
+    materializes to HBM.  The einsum producer (and the linear last layer,
+    which has a cheaper shared-F closed form) keeps the two-step path below.
     """
-    resolved = stats_backend.resolve(backend)
-    if resolved == "fused" and act.name != "linear":
+    if stats_backend.producer() == "fused" and act.name != "linear":
         with jax.named_scope("stats"):
             g, m = stats_backend.fused_chunk_acc(
-                stats.g, stats.m, h_l, w_c1, b_c1, weights,
-                act=act, backend=resolved,
+                stats.g, stats.m, h_l, w_c1, b_c1, weights, act=act
             )
         return rolann.RolannStats(g=g, m=m)
     with jax.named_scope("forward"):
         h_c1 = act.fn(w_c1.T @ h_l + b_c1[:, None])
-    return rolann.accumulate_stats(
-        stats, h_c1, h_l, act, weights=weights, backend=resolved
-    )
+    return rolann.accumulate_stats(stats, h_c1, h_l, act, weights=weights)
 
 
 def layer_from_knowledge(

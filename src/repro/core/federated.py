@@ -111,7 +111,6 @@ def _federated_fit(
     Communication per round: encoder factors (or Grams) once, then one
     ROLANN knowledge aggregate per decoder layer.
     """
-    config = config.resolved()
     f_hl, f_ll = daef._acts(config)
     keys = config.layer_keys()
     sizes = config.layer_sizes
@@ -132,7 +131,6 @@ def _federated_fit(
             elm_ae.layer_knowledge_from_partition(
                 keys[li], h, sizes[li], f_hl,
                 init=config.init, method=config.method,
-                backend=config.stats_backend,
             )
             for h in hs
         ]
@@ -149,7 +147,7 @@ def _federated_fit(
 
     # Final round: last layer against the original inputs.
     locals_ = [
-        rolann.compute_stats(h, p, f_ll, backend=config.stats_backend) if use_gram
+        rolann.compute_stats(h, p, f_ll) if use_gram
         else rolann.compute_factors(h, p, f_ll)
         for h, p in zip(hs, partitions, strict=True)
     ]

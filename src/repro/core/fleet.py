@@ -184,7 +184,6 @@ def _fit_fleet_call(config: daef.DAEFConfig, xs, seeds, lam_hidden, lam_last, *,
                     chunk_samples: int | None = None) -> daef.FitCall:
     """The program `_fit_fleet` / `_fit_fleet_chunked` run, and its
     arguments: the one-shot or the chunked core vmapped over tenants."""
-    config = config.resolved()  # env-resolved backend keys the jit cache
     if chunk_samples is not None:
         daef._require_gram(config, "chunked fleet fit")
     seeds, lam_hidden, lam_last = _prepare_fit(
@@ -227,8 +226,7 @@ def _fleet_stream_layer_step(config, stats, params, xs, mask):
     def one(stats_i, w_i, b_i, wc1_i, bc1_i, x_i):
         h = daef._stream_forward(config, x_i, w_i, b_i)
         return elm_ae.accumulate_layer_stats(
-            stats_i, wc1_i, bc1_i, h, f_hl, weights=mask,
-            backend=config.stats_backend,
+            stats_i, wc1_i, bc1_i, h, f_hl, weights=mask
         )
 
     return jax.vmap(one)(stats, weights, biases, w_c1, b_c1, xs)
@@ -241,9 +239,7 @@ def _fleet_stream_last_step(config, stats, params, xs, mask):
 
     def one(stats_i, w_i, b_i, x_i):
         h = daef._stream_forward(config, x_i, w_i, b_i)
-        return rolann.accumulate_stats(
-            stats_i, h, x_i, f_ll, weights=mask, backend=config.stats_backend
-        )
+        return rolann.accumulate_stats(stats_i, h, x_i, f_ll, weights=mask)
 
     return jax.vmap(one)(stats, weights, biases, xs)
 
@@ -274,7 +270,6 @@ def _fit_fleet_stream(
     sharding for mesh plans), so a mesh fleet streams without a replicated
     host staging copy.
     """
-    config = config.resolved()
     daef._require_gram(config, "streaming fleet fit")
     factory = daef._stream_chunk_source(batches)
     f_hl, f_ll = daef._acts(config)

@@ -146,7 +146,6 @@ def _fit_sharded_call(config: daef.DAEFConfig, xs, mesh: Mesh, seeds, lam_hidden
                       chunk_samples: int | None = None) -> daef.FitCall:
     """The per-shard program `_fit_sharded` runs, and its arguments; every
     argument is placed sharded over tenants."""
-    config = config.resolved()
     seeds, lam_hidden, lam_last = fleet._prepare_fit(
         config, xs, seeds, lam_hidden, lam_last
     )
@@ -297,7 +296,6 @@ def sharded_fleet_partial_fit(
     """
     if xs_new.shape[0] != fl.size:
         raise ValueError(f"update batch has {xs_new.shape[0]} tenants, fleet {fl.size}")
-    config = config.resolved()
     if chunk_samples is not None:
         daef._require_gram(config, "chunked sharded partial_fit")
     with warnings.catch_warnings():
@@ -668,7 +666,6 @@ def merge_state_tree(
     reduced away.  The caller re-solves weights once from the result
     (`daef._model_from_knowledge`).
     """
-    config = config.resolved()
     if config.method != "gram":
         raise ValueError(
             "merge_state_tree: masked tree reduction stacks site states into "

@@ -88,15 +88,9 @@ def _targets(d: Array, act: activations.Activation) -> tuple[Array, Array]:
 # Sufficient statistics
 # ---------------------------------------------------------------------------
 
-def compute_stats(
-    x: Array, d: Array, act: activations.Activation, *, backend: str | None = None
-) -> RolannStats:
-    """Gram-form statistics for inputs x [m, n] and targets d [out, n].
-
-    ``backend`` selects the Gram-stats producer (see `core.stats_backend`):
-    ``"einsum"`` (unfused XLA) or ``"fused"`` (the Pallas rolann_stats
-    kernel); None resolves from $REPRO_STATS_BACKEND.
-    """
+def compute_stats(x: Array, d: Array, act: activations.Activation) -> RolannStats:
+    """Gram-form statistics for inputs x [m, n] and targets d [out, n]; the
+    producer of the per-output Grams is `core.stats_backend`'s choice."""
     act = activations.get(act.name, invertible_required=True)
     with jax.named_scope("stats"):
         xa = _augment(x)  # [m+1, n]
@@ -113,7 +107,7 @@ def compute_stats(
             # one is active (the paper's pool.map over cores, TPU-native).
             from repro.models import hints
 
-            g, m_vec = stats_backend.gram_stats(xa, fsq, fsq * dbar, backend=backend)
+            g, m_vec = stats_backend.gram_stats(xa, fsq, fsq * dbar)
             g = hints.hint(g, {0: "model"})
     return RolannStats(g=g, m=m_vec)
 
@@ -140,14 +134,13 @@ def accumulate_stats(
     act: activations.Activation,
     *,
     weights: Array | None = None,
-    backend: str | None = None,
 ) -> RolannStats:
     """Fold one sample chunk into running Gram-form statistics.
 
     Mathematically ``merge_stats(stats, compute_stats(x, d, act))`` — the
     paper's Eq. 6-7 statistics are additive over sample blocks — but computed
     as a single accumulating fold (`stats_backend.gram_stats_acc`): the fused
-    backend aliases the running (G, M) onto the kernel outputs, so a chunked
+    producer aliases the running (G, M) onto the kernel outputs, so a chunked
     fit makes one HBM pass per chunk with no re-zeroing.
 
     ``weights`` ([n] in {0, 1}) masks padded sample columns: a zero weight
@@ -170,9 +163,7 @@ def accumulate_stats(
             g = stats.g + xw @ xa.T
             m_vec = stats.m + jnp.einsum("in,on->oi", xa, fd)
             return RolannStats(g=g, m=m_vec)
-        g, m_vec = stats_backend.gram_stats_acc(
-            stats.g, stats.m, xa, fsq, fd, backend=backend
-        )
+        g, m_vec = stats_backend.gram_stats_acc(stats.g, stats.m, xa, fsq, fd)
     return RolannStats(g=g, m=m_vec)
 
 
@@ -197,7 +188,7 @@ def compute_factors(x: Array, d: Array, act: activations.Activation) -> RolannFa
 
 
 def compute_factors_via_gram(
-    x: Array, d: Array, act: activations.Activation, *, backend: str | None = None
+    x: Array, d: Array, act: activations.Activation
 ) -> RolannFactors:
     """Paper-protocol factors (U, S, M) derived from the local Gram by eigh.
 
@@ -206,7 +197,7 @@ def compute_factors_via_gram(
     [m, n_local] matrix — at pod scale (n_local ~ 256k) the direct SVD's
     workspace is hundreds of GiB while this stays O(m^2) (EXPERIMENTS §Perf).
     """
-    stats = compute_stats(x, d, act, backend=backend)
+    stats = compute_stats(x, d, act)
     with jax.named_scope("stats"):
         return stats_to_factors(stats)
 
@@ -402,19 +393,15 @@ def fit(
     lam: float,
     *,
     method: str = "gram",
-    backend: str | None = None,
     gram_solver: str = "chol",
 ) -> tuple[Array, Array, RolannFactors | RolannStats]:
     """One-shot ROLANN fit. Returns (W, b, knowledge).
 
     method: "gram" (fast path, psum-mergeable) or "svd" (paper-faithful).
-    backend: Gram-stats producer for the "gram" method (stats_backend).
     gram_solver: weight-solve route for gram knowledge (see `solve`).
     """
     if method == "gram":
-        knowledge: RolannFactors | RolannStats = compute_stats(
-            x, d, act, backend=backend
-        )
+        knowledge: RolannFactors | RolannStats = compute_stats(x, d, act)
     elif method == "svd":
         knowledge = compute_factors(x, d, act)
     else:

@@ -127,7 +127,7 @@ def _fit_on_mesh_call(config: daef.DAEFConfig, x, mesh: Mesh, *,
     """The program `_fit_on_mesh` runs, and its input, placed with the
     sample axis sharded over ``data_axes``."""
     axes = tuple(data_axes)
-    fit = _mesh_fit_program(config.resolved(), mesh, axes, local_factorization)
+    fit = _mesh_fit_program(config, mesh, axes, local_factorization)
     spec = NamedSharding(mesh, P(None, axes))
     return daef.FitCall(fit, (x,), {}, lambda args: (daef.put(args[0], spec),),
                         _as_model)
@@ -186,7 +186,6 @@ def _mesh_fit_program(config: daef.DAEFConfig, mesh: Mesh, axes: tuple,
                     keys[li], h, sizes[li], f_hl,
                     init=config.init, method=config.method,
                     factorization=local_factorization,
-                    backend=config.stats_backend,
                 )
                 with jax.named_scope("stats"):
                     if use_gram:
@@ -210,11 +209,9 @@ def _mesh_fit_program(config: daef.DAEFConfig, mesh: Mesh, axes: tuple,
         # ---------------- last layer ----------------
         with jax.named_scope(f"layer{len(sizes) - 1}"):
             if use_gram:
-                local = rolann.compute_stats(h, xp, f_ll, backend=config.stats_backend)
+                local = rolann.compute_stats(h, xp, f_ll)
             elif local_factorization == "gram_eigh":
-                local = rolann.compute_factors_via_gram(
-                    h, xp, f_ll, backend=config.stats_backend
-                )
+                local = rolann.compute_factors_via_gram(h, xp, f_ll)
             else:
                 local = rolann.compute_factors(h, xp, f_ll)
             with jax.named_scope("stats"):

@@ -2,8 +2,8 @@
 
 The engine binds a ``DAEFConfig`` (the math: layer sizes, lambdas, knowledge
 representation) to an ``ExecutionPlan`` (the placement: loop / vmap / mesh,
-tenant count, merge strategy, stats backend, streaming chunk width) and
-exposes ONE spelling of
+tenant count, merge strategy, streaming chunk width) and exposes ONE
+spelling of
 
     fit / fit_stream / partial_fit / predict / scores / merge / reduce /
     thresholds / classify / save / load / session
@@ -123,13 +123,6 @@ class DAEFEngine:
             raise PlanError(
                 f"plan must be an ExecutionPlan, got {type(plan).__name__}"
             )
-        # stats-backend precedence, resolved ONCE: plan.stats_backend >
-        # config.stats_backend > $REPRO_STATS_BACKEND > default.  The
-        # resolved config keys every jit cache downstream.
-        if plan.stats_backend is not None:
-            config = dataclasses.replace(config, stats_backend=plan.stats_backend)
-        config = config.resolved()
-        plan = dataclasses.replace(plan, stats_backend=config.stats_backend)
         if plan.chunk_samples is not None and config.method != "gram":
             raise PlanError(
                 f"chunk_samples={plan.chunk_samples} streams the fit by "
@@ -940,6 +933,5 @@ class DAEFEngine:
     def __repr__(self) -> str:
         return (
             f"DAEFEngine(layers={self.config.layer_sizes}, "
-            f"method={self.config.method!r}, "
-            f"stats_backend={self.config.stats_backend!r}, plan={self.plan})"
+            f"method={self.config.method!r}, plan={self.plan})"
         )

@@ -6,8 +6,7 @@ fleet, tenant-mesh sharding, data-mesh federation, tree-reduce aggregation),
 but each used to carry its own call surface.  An ``ExecutionPlan`` collapses
 the choice into configuration:
 
-    plan = ExecutionPlan(mode="mesh", tenants=64, mesh_devices=8,
-                         stats_backend="fused", merge="tree")
+    plan = ExecutionPlan(mode="mesh", tenants=64, mesh_devices=8, merge="tree")
     engine = DAEFEngine(config, plan)
 
 * ``mode``      — "loop" (eager per-model calls, the debugging/parity
@@ -24,11 +23,6 @@ the choice into configuration:
                   data-parallel federation of `core.sharded.fit_on_mesh`.
 * ``mesh_devices`` — devices along the tenant axis (None = the largest
                   fleet-compatible mesh over all devices).
-* ``stats_backend`` — Gram-stats producer ("einsum" | "fused" | "auto", the
-                  measured winner from the committed autotune cache);
-                  overrides ``DAEFConfig.stats_backend``; None defers to the
-                  config / ``$REPRO_STATS_BACKEND`` precedence chain
-                  (default "auto").
 * ``merge``     — federation reduce strategy for ``DAEFEngine.reduce`` and
                   ``FederationSession.round``: "sequential" (left-to-right
                   host reduce / the exact layer-synchronized protocol),
@@ -77,7 +71,6 @@ from __future__ import annotations
 
 import dataclasses
 
-from repro.core import stats_backend as stats_backend_mod
 from repro.privacy.spec import PrivacySpec
 
 MODES = ("loop", "vmap", "mesh")
@@ -93,14 +86,13 @@ class PlanError(ValueError):
 @dataclasses.dataclass(frozen=True)
 class ExecutionPlan:
     """Declarative placement/dispatch choice for a DAEFEngine (see module
-    docstring for field semantics).  Frozen and hashable, so a resolved plan
-    can key caches the same way a resolved DAEFConfig keys jit caches."""
+    docstring for field semantics).  Frozen and hashable, so a plan can key
+    caches the way a DAEFConfig keys jit caches."""
 
     mode: str = "vmap"
     tenants: int = 1
     mesh_devices: int | None = None
     mesh_axes: tuple[str, ...] = TENANT_AXES
-    stats_backend: str | None = None
     merge: str = "sequential"
     local_factorization: str = "gram_eigh"
     chunk_samples: int | None = None
@@ -192,9 +184,6 @@ class ExecutionPlan:
                     "chunk_samples, or use mesh_axes=('tenants',) / "
                     "mode='vmap' for a streamed fit"
                 )
-        if self.stats_backend is not None:
-            # raises on unknown names (same contract as DAEFConfig)
-            stats_backend_mod.resolve(self.stats_backend)
         if self.privacy is not None:
             if not isinstance(self.privacy, PrivacySpec):
                 raise PlanError(

@@ -26,7 +26,7 @@ strictly validated — a corrupt file, a wrong version, or a stale entry
 (non-integer, non-power-of-two, out of range) falls back to the static
 heuristic with a one-time warning rather than poisoning kernel launches.
 
-``stats_backend.resolve("auto")`` consults :func:`preferred_backend` — the
+``stats_backend.producer()`` consults :func:`preferred_backend` — the
 measured einsum-vs-fused verdict recorded by ``benchmarks/kernel_autotune.py``
 — so the fused path flips on automatically exactly where it measured faster.
 
@@ -53,9 +53,9 @@ CACHE_VERSION = 1
 CANDIDATE_BLOCKS = (128, 256, 512, 1024)
 _MAX_BLOCK = 4096
 
-#: Concrete stats backends a cache may prefer.  Mirrors
-#: ``stats_backend.BACKENDS`` — spelled out here because ``stats_backend``
-#: imports this module to resolve ``"auto"`` (no import cycle).
+#: Gram-stats producers a cache may prefer.  Mirrors
+#: ``stats_backend.PRODUCERS`` — spelled out here because ``stats_backend``
+#: imports this module to choose its producer (no import cycle).
 _KNOWN_BACKENDS = ("einsum", "fused")
 
 # In-memory copy of the cache file, loaded once per (path, process) and
@@ -197,7 +197,8 @@ def best_block_n(
 
 
 def preferred_backend(platform: str | None = None) -> str:
-    """Measured stats-backend winner for this platform (``"auto"``'s answer).
+    """Measured Gram-stats producer for this platform
+    (``stats_backend.producer()``'s answer).
 
     Reads ``platforms.<platform>.preferred_backend`` from the cache;
     anything missing or unrecognized resolves to ``"einsum"`` — the safe
@@ -213,15 +214,15 @@ def preferred_backend(platform: str | None = None) -> str:
         _warn_once(
             f"pref:{plat}",
             f"autotune cache names unknown preferred_backend {pref!r} for "
-            f"platform {plat!r}; resolving 'auto' to 'einsum'",
+            f"platform {plat!r}; the Gram-stats producer is 'einsum'",
         )
     else:
         _warn_once(
             f"unmeasured:{plat}",
             f"autotune cache {cache_path()} has no preferred_backend for "
-            f"platform {plat!r}; stats_backend 'auto' resolves to 'einsum' "
+            f"platform {plat!r}; the Gram-stats producer is 'einsum', "
             "unmeasured — measure with benchmarks/kernel_autotune.py "
-            "--write-cache, or pass stats_backend explicitly",
+            "--write-cache",
         )
     return "einsum"
 

@@ -45,10 +45,10 @@ def run_fleet(args) -> None:
     """Train + serve a fleet of per-tenant anomaly detectors.
 
     Everything goes through the unified engine facade: placement
-    (``--mesh-tenants``) and the stats backend are ExecutionPlan fields, not
-    different call paths.
+    (``--mesh-tenants``) is an ExecutionPlan field, not a different call
+    path; the Gram-stats producer is ``stats_backend.producer()``'s choice.
     """
-    from repro.core import daef, fleet_sharded
+    from repro.core import daef, fleet_sharded, stats_backend
     from repro.engine import DAEFEngine, ExecutionPlan, PlanError
     from repro.serving import metrics as serving_metrics
 
@@ -67,13 +67,12 @@ def run_fleet(args) -> None:
             mode="mesh" if args.mesh_tenants else "vmap",
             tenants=k,
             mesh_devices=args.mesh_tenants or None,
-            stats_backend=args.stats_backend,
             chunk_samples=args.chunk_samples or None,
         )
         engine = DAEFEngine(cfg, plan)
     except PlanError as e:  # bad mesh sizes etc. -> clean CLI error
         raise SystemExit(f"error: {e}") from e
-    print(f"fleet: Gram-stats backend '{engine.config.stats_backend}'")
+    print(f"fleet: Gram-stats producer '{stats_backend.producer()}'")
     if engine.mesh is not None:
         d = engine.mesh.shape[fleet_sharded.TENANT_AXIS]
         print(f"fleet: sharding {k} tenants over a {d}-device '"
@@ -364,14 +363,6 @@ def main() -> None:
                     help="fleet mode: number of serving rounds")
     ap.add_argument("--scale", type=float, default=0.25,
                     help="fleet mode: synthetic dataset scale")
-    ap.add_argument("--stats-backend", default=None,
-                    choices=["einsum", "fused", "auto"],
-                    help="fleet mode: Gram-stats producer (default: "
-                         "$REPRO_STATS_BACKEND or 'auto', which picks the "
-                         "measured winner from the committed autotune cache "
-                         "for this platform; 'fused' forces training stats "
-                         "through the Pallas rolann_stats kernels — "
-                         "interpret mode on CPU)")
     ap.add_argument("--chunk-samples", type=int, default=0,
                     help="fleet mode: train with a streaming (chunked) "
                          "ExecutionPlan — per-layer Gram stats accumulate "
@@ -421,8 +412,6 @@ def main() -> None:
                  f"sharding; got {args.mesh_tenants}")
     if args.mesh_tenants and not args.fleet:
         ap.error("--mesh-tenants only applies to --fleet mode")
-    if args.stats_backend and not args.fleet:
-        ap.error("--stats-backend only applies to --fleet mode")
     if args.chunk_samples and not args.fleet:
         ap.error("--chunk-samples only applies to --fleet mode")
     if args.chunk_samples < 0:

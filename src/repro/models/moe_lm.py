@@ -25,8 +25,8 @@ def _seq_shard() -> bool:
     """§Perf experiment (env-gated; defaults unchanged): shard the residual
     stream's sequence dim over the model axis between blocks (Megatron-SP
     style) — norms/router/expert math are pointwise over S, attention
-    gathers.  Resolved at call time (the ``stats_backend.resolved()``
-    idiom), never at import, so tests/serving can flip it per-process;
+    gathers.  Read at call time (the ``compile_cache.enable()`` idiom),
+    never at import, so tests/serving can flip it per-process;
     callers that jit the forward pass bake the resolved value into that
     trace and pass ``seq_shard=`` explicitly to override per-call."""
     return os.environ.get("REPRO_SEQ_SHARD", "0") == "1"

@@ -292,7 +292,6 @@ def fit_dp(config, x: Array, key: jax.Array, spec: PrivacySpec,
     """
     from repro.core import daef  # deferred: daef is a heavy import chain
 
-    config = config.resolved()
     _validate(config, spec)
     x = jnp.asarray(x)
     m0, n = x.shape
@@ -343,9 +342,7 @@ def fit_dp(config, x: Array, key: jax.Array, spec: PrivacySpec,
         stats = rolann.init_stats(sizes[li], sizes[li - 1], f_hl, x.dtype)
         for a, b in _chunks(n, chunk_samples):
             h = _forward(config, x[:, a:b], weights, biases)
-            stats = elm_ae.accumulate_layer_stats(
-                stats, w_c1, b_c1, h, f_hl, backend=config.stats_backend
-            )
+            stats = elm_ae.accumulate_layer_stats(stats, w_c1, b_c1, h, f_hl)
         stats = noise_stats(block_keys[li - 1], stats, sigmas[f"layer{li}"])
         lam_hl = _dp_ridge(config.lam_hidden, sigmas[f"layer{li}"],
                            sizes[li] + 1)
@@ -362,9 +359,7 @@ def fit_dp(config, x: Array, key: jax.Array, spec: PrivacySpec,
     stats = rolann.init_stats(sizes[-2], m0, f_ll, x.dtype)
     for a, b in _chunks(n, chunk_samples):
         h = _forward(config, x[:, a:b], weights, biases)
-        stats = rolann.accumulate_stats(
-            stats, h, x[:, a:b], f_ll, backend=config.stats_backend
-        )
+        stats = rolann.accumulate_stats(stats, h, x[:, a:b], f_ll)
     stats = noise_stats(block_keys[-2], stats, sigmas["last"])
     lam_ll = _dp_ridge(config.lam_last, sigmas["last"], sizes[-2] + 1)
     w_ll, b_ll = rolann.solve(stats, lam_ll,

@@ -23,7 +23,7 @@ def bad_getenv_inside_jit(x, mode):
 
 
 def clean_call_time_resolution(backend=None):
-    """The stats_backend idiom: resolve at call time, pre-trace."""
+    """The compile_cache.enable() idiom: read at call time, pre-trace."""
     if backend is None:
         backend = os.environ.get("REPRO_FIXTURE_BACKEND") or "einsum"
     return backend

@@ -3,7 +3,7 @@
 The acceptance bar for ``ExecutionPlan(federation="async")``: with every
 site reporting every round and ``max_staleness=0`` the async session must
 reproduce the sequential broker merge of the same contributions (across
-loop/vmap/mesh modes and both stats backends); stragglers must be excluded
+loop/vmap/mesh modes and both Gram-stats producers); stragglers must be excluded
 exactly at the staleness bound and re-enter with their full accumulated
 contribution; the masked on-mesh tree must agree with the host reduction
 over the same subset.
@@ -17,6 +17,7 @@ import pytest
 
 from repro.core import daef, federated, fleet, fleet_sharded
 from repro.engine import DAEFEngine, ExecutionPlan, PlanError
+from repro.testing.producer import stats_producer
 
 M0, LATENT = 7, 3
 LAYERS = (M0, LATENT, 5, M0)
@@ -25,10 +26,9 @@ MODES = ("loop", "vmap", "mesh")
 PARITY = dict(atol=5e-4, rtol=1e-3)
 
 
-def _cfg(backend: str = "einsum", method: str = "gram") -> daef.DAEFConfig:
+def _cfg(method: str = "gram") -> daef.DAEFConfig:
     return daef.DAEFConfig(
         layer_sizes=LAYERS, lam_hidden=0.7, lam_last=0.9, method=method,
-        stats_backend=backend,
     )
 
 
@@ -78,13 +78,14 @@ def _assert_models_close(a, b, *, what: str):
 @pytest.mark.parametrize("backend", ["einsum", "fused"])
 @pytest.mark.parametrize("mode", MODES)
 def test_async_sync_parity(mode, backend):
-    cfg = _cfg(backend)
+    cfg = _cfg()
     site_blocks = _blocks(sites=3, rounds=2)
     plan = ExecutionPlan(mode=mode, federation="async", merge="sequential")
-    session = DAEFEngine(cfg, plan).session()
-    for r in range(2):
-        model = session.round([blocks[r] for blocks in site_blocks])
-    ref = _reference(cfg, site_blocks)
+    with stats_producer(backend):
+        session = DAEFEngine(cfg, plan).session()
+        for r in range(2):
+            model = session.round([blocks[r] for blocks in site_blocks])
+        ref = _reference(cfg, site_blocks)
     _assert_models_close(model, ref, what=f"async {mode}/{backend}")
     x = site_blocks[0][0]
     np.testing.assert_allclose(
@@ -218,7 +219,7 @@ def test_site_joins_mid_session():
 # ---------------------------------------------------------------------------
 
 def test_merge_state_tree_masked_subset_parity():
-    cfg = _cfg().resolved()
+    cfg = _cfg()
     parts = [b[0] for b in _blocks(sites=4, rounds=1, seed=5)]
     models = [daef.fit(cfg, p) for p in parts]
     states = [
@@ -242,7 +243,7 @@ def test_merge_state_tree_masked_subset_parity():
 
 
 def test_merge_state_tree_rejects_all_zero_mask():
-    cfg = _cfg().resolved()
+    cfg = _cfg()
     parts = [b[0] for b in _blocks(sites=2, rounds=1, seed=6)]
     models = [daef.fit(cfg, p) for p in parts]
     enc_b, knw_b = jax.tree.map(
@@ -262,7 +263,7 @@ def test_merge_state_tree_rejects_all_zero_mask():
 def test_fleet_merge_tree_pow2_error_names_the_alternatives():
     cfg = _cfg()
     xs = jnp.stack([b[0] for b in _blocks(sites=6, rounds=1, seed=7)])
-    fl = fleet._fit_fleet(cfg.resolved(), xs, seeds=None, lam_hidden=None,
+    fl = fleet._fit_fleet(cfg, xs, seeds=None, lam_hidden=None,
                           lam_last=None)
     with pytest.raises(ValueError, match="power of two") as e:
         fleet_sharded.fleet_merge_tree(cfg, fl, 3)

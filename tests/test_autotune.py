@@ -5,8 +5,8 @@ Covers the ISSUE-10 tentpole surface:
 * cache mechanics — roundtrip through ``update_cache``/``lookup_block``,
   hit/miss determinism, corrupt files and stale entries degrading to the
   static heuristic with a one-time warning;
-* backend auto-selection — ``stats_backend.resolve("auto")`` follows the
-  cache's measured ``preferred_backend`` verdict per platform;
+* producer selection — ``stats_backend.producer()`` follows the cache's
+  measured ``preferred_backend`` verdict per platform;
 * wrapper resolution — an explicitly requested ``block_n`` is never
   silently clipped (RPR-adjacent satellite), and interpret mode is chosen
   by an explicit argument or, failing one, by the CPU backend alone;
@@ -28,6 +28,7 @@ import pytest
 from repro.core import activations, elm_ae, rolann, stats_backend
 from repro.kernels import autotune
 from repro.kernels.rolann_stats import ops
+from repro.testing.producer import stats_producer
 
 # Parity bars match tests/test_parity.py; float64 still accumulates in f32
 # inside the kernel (the documented deviation), hence the relative bar.
@@ -124,28 +125,34 @@ def test_wrong_version_warns_and_falls_back():
 
 
 # ---------------------------------------------------------------------------
-# "auto" backend resolution
+# Producer selection from the cache
 # ---------------------------------------------------------------------------
 
 def test_resolve_auto_follows_cache_verdict():
     plat = jax.default_backend()
     with pytest.warns(RuntimeWarning, match="no preferred_backend"):
-        assert stats_backend.resolve("auto") == "einsum"  # unmeasured platform
+        assert stats_backend.producer() == "einsum"  # unmeasured platform
     autotune.update_cache(platform=plat, preferred="fused")
-    assert stats_backend.resolve("auto") == "fused"
+    assert stats_backend.producer() == "fused"
     autotune.update_cache(platform=plat, preferred="einsum")
-    assert stats_backend.resolve("auto") == "einsum"
+    assert stats_backend.producer() == "einsum"
 
 
-def test_resolve_default_is_auto(monkeypatch):
-    monkeypatch.delenv(stats_backend.ENV_VAR, raising=False)
+def test_resolve_default_is_auto():
+    """With no argument anywhere, ``gram_stats`` launches the kernel exactly
+    where the cache measured the fused producer faster."""
     plat = jax.default_backend()
+    xa, fsq, fd = jnp.ones((3, 40)), jnp.ones((2, 40)), jnp.ones((2, 40))
+
+    def launches():
+        jax.clear_caches()  # traces keep the producer they were made under
+        jaxpr = jax.make_jaxpr(stats_backend.gram_stats)(xa, fsq, fd).jaxpr
+        return list(_walk_eqns(jaxpr)).count("pallas_call")
+
     autotune.update_cache(platform=plat, preferred="fused")
-    assert stats_backend.DEFAULT == stats_backend.AUTO
-    assert stats_backend.resolve(None) == "fused"
-    # env still outranks the default chain
-    monkeypatch.setenv(stats_backend.ENV_VAR, "einsum")
-    assert stats_backend.resolve(None) == "einsum"
+    assert stats_backend.producer() == "fused" and launches() == 1
+    autotune.update_cache(platform=plat, preferred="einsum")
+    assert stats_backend.producer() == "einsum" and launches() == 0
 
 
 def test_unknown_preferred_backend_warns_to_einsum():
@@ -217,16 +224,20 @@ def _assert_stats_close(got, want, dtype):
                                atol=tol["atol"] * scale, rtol=tol["rtol"])
 
 
+def _fold(producer, stats, w, b, h, act, **kw):
+    """``accumulate_layer_stats`` under the named Gram-stats producer."""
+    with stats_producer(producer):
+        return elm_ae.accumulate_layer_stats(stats, w, b, h, act, **kw)
+
+
 @pytest.mark.parametrize("act_name", ["logsig", "tanh"])
 @pytest.mark.parametrize("n", [1, 130, 512, 700])
 def test_fused_chunk_matches_einsum_chunk(act_name, n):
     act = activations.get(act_name, invertible_required=True)
     h, w, b, mask = _chunk_problem(7, 5, n, seed=n, dtype=jnp.float32)
     s0 = rolann.init_stats(5, 7, act, dtype=jnp.float32)
-    want = elm_ae.accumulate_layer_stats(s0, w, b, h, act, weights=mask,
-                                         backend="einsum")
-    got = elm_ae.accumulate_layer_stats(s0, w, b, h, act, weights=mask,
-                                        backend="fused")
+    want = _fold("einsum", s0, w, b, h, act, weights=mask)
+    got = _fold("fused", s0, w, b, h, act, weights=mask)
     _assert_stats_close(got, want, jnp.float32)
 
 
@@ -236,10 +247,8 @@ def test_fused_chunk_parity_float64():
     act = activations.get("logsig", invertible_required=True)
     h, w, b, mask = _chunk_problem(7, 5, 300, seed=3, dtype=jnp.float64)
     s0 = rolann.init_stats(5, 7, act, dtype=jnp.float64)
-    want = elm_ae.accumulate_layer_stats(s0, w, b, h, act, weights=mask,
-                                         backend="einsum")
-    got = elm_ae.accumulate_layer_stats(s0, w, b, h, act, weights=mask,
-                                        backend="fused")
+    want = _fold("einsum", s0, w, b, h, act, weights=mask)
+    got = _fold("fused", s0, w, b, h, act, weights=mask)
     _assert_stats_close(got, want, jnp.float64)
 
 
@@ -249,13 +258,12 @@ def test_fused_chunk_accumulates_over_ragged_chunks():
     act = activations.get("logsig", invertible_required=True)
     h, w, b, _ = _chunk_problem(7, 5, 700, seed=11, dtype=jnp.float32)
     s_ref = rolann.init_stats(5, 7, act, dtype=jnp.float32)
-    want = elm_ae.accumulate_layer_stats(s_ref, w, b, h, act,
-                                         backend="einsum")
+    want = _fold("einsum", s_ref, w, b, h, act)
     stats = rolann.init_stats(5, 7, act, dtype=jnp.float32)
-    for start in range(0, 700, 256):   # chunks of 256, 256, 188 (ragged)
-        chunk = h[:, start:start + 256]
-        stats = elm_ae.accumulate_layer_stats(stats, w, b, chunk, act,
-                                              backend="fused")
+    with stats_producer("fused"):
+        for start in range(0, 700, 256):   # chunks of 256, 256, 188 (ragged)
+            chunk = h[:, start:start + 256]
+            stats = elm_ae.accumulate_layer_stats(stats, w, b, chunk, act)
     _assert_stats_close(stats, want, jnp.float32)
 
 
@@ -266,12 +274,11 @@ def test_fused_chunk_vmap_collapses_to_batched(backend, monkeypatch):
     calls = []
     orig = stats_backend.fused_chunk_acc_batched
 
-    def spy(g, m, h, w, b, mask=None, *, act, backend=None):
-        calls.append((h.shape, backend))
-        return orig(g, m, h, w, b, mask, act=act, backend=backend)
+    def spy(g, m, h, w, b, mask=None, *, act):
+        calls.append((h.shape, stats_backend.producer()))
+        return orig(g, m, h, w, b, mask, act=act)
 
     monkeypatch.setattr(stats_backend, "fused_chunk_acc_batched", spy)
-    stats_backend._fused_chunk_fn.cache_clear()
 
     act = activations.get("logsig", invertible_required=True)
     k = 3
@@ -279,20 +286,19 @@ def test_fused_chunk_vmap_collapses_to_batched(backend, monkeypatch):
     for t in range(k):
         h, w, b, mask = _chunk_problem(7, 5, 200, seed=t, dtype=jnp.float32)
         s0 = rolann.init_stats(5, 7, act, dtype=jnp.float32)
-        singles.append(elm_ae.accumulate_layer_stats(
-            s0, w, b, h, act, weights=mask, backend="einsum"))
+        singles.append(_fold("einsum", s0, w, b, h, act, weights=mask))
         hs.append(h); ws.append(w); bs.append(b); masks.append(mask)
     g0 = jnp.stack([rolann.init_stats(5, 7, act).g] * k)
     m0 = jnp.stack([rolann.init_stats(5, 7, act).m] * k)
 
     def per_tenant(g, m, h, w, b, mask):
-        return stats_backend.fused_chunk_acc(g, m, h, w, b, mask,
-                                             act="logsig", backend=backend)
+        return stats_backend.fused_chunk_acc(g, m, h, w, b, mask, act="logsig")
 
-    gk, mk = jax.vmap(per_tenant)(
-        g0, m0, jnp.stack(hs), jnp.stack(ws), jnp.stack(bs), jnp.stack(masks)
-    )
-    stats_backend._fused_chunk_fn.cache_clear()
+    with stats_producer(backend):
+        gk, mk = jax.vmap(per_tenant)(
+            g0, m0, jnp.stack(hs), jnp.stack(ws), jnp.stack(bs),
+            jnp.stack(masks),
+        )
     assert calls and calls[0][0] == (k, 7, 200)
     assert all(b == backend for _, b in calls)
     for t in range(k):
@@ -305,7 +311,7 @@ def test_fused_chunk_rejects_linear():
     with pytest.raises(ValueError, match="linear"):
         stats_backend.fused_chunk_acc(
             jnp.zeros((2, 3, 3)), jnp.zeros((2, 3)), jnp.zeros((2, 4)),
-            jnp.zeros((2, 2)), jnp.zeros((2,)), act=act, backend="fused",
+            jnp.zeros((2, 2)), jnp.zeros((2,)), act=act,
         )
 
 
@@ -342,22 +348,17 @@ def test_fused_layer_fold_is_one_launch_no_hbm_roundtrip():
 
     def fold(g, m, h, w, b, mask):
         out = elm_ae.accumulate_layer_stats(
-            rolann.RolannStats(g=g, m=m), w, b, h, act, weights=mask,
-            backend="fused")
+            rolann.RolannStats(g=g, m=m), w, b, h, act, weights=mask)
         return out.g, out.m
 
-    prims = list(_walk_eqns(
-        jax.make_jaxpr(fold)(s0.g, s0.m, h, w, b, mask).jaxpr))
+    with stats_producer("fused"):
+        prims = list(_walk_eqns(
+            jax.make_jaxpr(fold)(s0.g, s0.m, h, w, b, mask).jaxpr))
     assert prims.count("pallas_call") == 1, prims
     assert "dot_general" not in prims, prims
     # the einsum path, by contrast, has the matmul + contractions in XLA
-    def fold_einsum(g, m, h, w, b, mask):
-        out = elm_ae.accumulate_layer_stats(
-            rolann.RolannStats(g=g, m=m), w, b, h, act, weights=mask,
-            backend="einsum")
-        return out.g, out.m
-
-    prims_e = list(_walk_eqns(
-        jax.make_jaxpr(fold_einsum)(s0.g, s0.m, h, w, b, mask).jaxpr))
+    with stats_producer("einsum"):
+        prims_e = list(_walk_eqns(
+            jax.make_jaxpr(fold)(s0.g, s0.m, h, w, b, mask).jaxpr))
     assert prims_e.count("pallas_call") == 0
     assert "dot_general" in prims_e

@@ -180,8 +180,7 @@ def test_fleet_fit_on_kernel_route_matches_per_model_fits():
 
 
 def test_one_model_fit_program_keeps_xla_cholesky():
-    cfg = daef.DAEFConfig(layer_sizes=CARDIO, lam_hidden=0.9, lam_last=0.9,
-                          stats_backend="einsum")
+    cfg = daef.DAEFConfig(layer_sizes=CARDIO, lam_hidden=0.9, lam_last=0.9)
     text = daef.lower_fit(cfg, jax.ShapeDtypeStruct(
         (CARDIO[0], 64), jnp.float32)).compile().as_text()
     assert _compiled_route(text) == (False, True)

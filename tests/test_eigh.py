@@ -159,8 +159,7 @@ def test_gram_to_factors_stack_matches_per_matrix():
 
 
 def _cardio_cfg() -> daef.DAEFConfig:
-    return daef.DAEFConfig(layer_sizes=CARDIO, lam_hidden=0.9, lam_last=0.9,
-                           stats_backend="einsum")
+    return daef.DAEFConfig(layer_sizes=CARDIO, lam_hidden=0.9, lam_last=0.9)
 
 
 def test_fleet_fit_program_takes_jacobi_under_encoder():

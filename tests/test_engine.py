@@ -2,23 +2,20 @@
 
 The engine is pure dispatch — every ``ExecutionPlan`` mode must reproduce
 the direct-call results bit-for-bit (same kernels) or within the
-tests/test_parity.py tolerances (different execution order), for both stats
-backends; precedence resolution and plan validation must be loud and
-actionable.  These tests are the acceptance bar for the API redesign: if
-they pass, rewriting a caller from the old entry points onto the facade is
-a no-op.
+tests/test_parity.py tolerances (different execution order), under both
+Gram-stats producers; plan validation must be loud and actionable.  These
+tests are the acceptance bar for the API redesign: if they pass, rewriting a
+caller from the old entry points onto the facade is a no-op.
 """
 import dataclasses
 import functools
-import os
-from unittest import mock
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import daef, federated, fleet, fleet_sharded, sharded, stats_backend
+from repro.core import daef, federated, fleet, fleet_sharded, sharded
 from repro.engine import (
     DAEFEngine,
     ExecutionPlan,
@@ -26,6 +23,7 @@ from repro.engine import (
     PlanError,
     deprecation,
 )
+from repro.testing.producer import stats_producer
 
 # Same bar as tests/test_parity.py's execution-path parity.
 TOLS = {
@@ -38,10 +36,9 @@ LAYERS = (M0, LATENT, 5, M0)
 MODES = ("loop", "vmap", "mesh")
 
 
-def _cfg(method: str = "gram", backend: str | None = None) -> daef.DAEFConfig:
+def _cfg(method: str = "gram") -> daef.DAEFConfig:
     return daef.DAEFConfig(
         layer_sizes=LAYERS, lam_hidden=0.7, lam_last=0.9, method=method,
-        stats_backend=backend,
     )
 
 
@@ -68,26 +65,29 @@ def _assert_trees_close(a, b, *, what: str, atol=None, rtol=None):
 
 
 # ---------------------------------------------------------------------------
-# fit / predict / scores parity, all modes x both backends
+# fit / predict / scores parity, all modes x both producers
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("backend", ["einsum", "fused"])
 @pytest.mark.parametrize("mode", MODES)
 def test_fit_predict_scores_parity(mode, backend):
     k, n = 4, 64
-    cfg = _cfg("gram", backend)
+    cfg = _cfg("gram")
     xs = _data(k, n, seed=0)
     seeds = jnp.arange(k)
     engine = DAEFEngine(cfg, ExecutionPlan(mode=mode, tenants=k))
 
-    fl = engine.fit(xs, seeds=seeds)
+    with stats_producer(backend):
+        fl = engine.fit(xs, seeds=seeds)
+        refs = [daef.fit(dataclasses.replace(cfg, seed=i), xs[i])
+                for i in range(k)]
     assert isinstance(fl, fleet.DAEFFleet) and fl.size == k
     recon = engine.predict(fl, xs)
     scores = engine.scores(fl, xs)
 
     for i in range(k):
         cfg_i = dataclasses.replace(cfg, seed=i)
-        ref = daef.fit(cfg_i, xs[i])
+        ref = refs[i]
         _assert_trees_close(
             engine.get_model(fl, i), ref, what=f"{mode} fit, tenant {i}"
         )
@@ -307,42 +307,18 @@ def test_session_accumulates_across_rounds():
 
 
 # ---------------------------------------------------------------------------
-# stats-backend precedence (plan > config > env > default)
+# Gram-stats producers through the engine
 # ---------------------------------------------------------------------------
-
-def test_stats_backend_precedence():
-    cfg = _cfg()
-    with mock.patch.dict(os.environ, {stats_backend.ENV_VAR: "fused"}):
-        # env var applies when neither plan nor config pin a backend
-        assert DAEFEngine(cfg).config.stats_backend == "fused"
-        # explicit config beats env
-        assert (DAEFEngine(_cfg(backend="einsum")).config.stats_backend
-                == "einsum")
-        # explicit plan beats both
-        eng = DAEFEngine(
-            _cfg(backend="fused"), ExecutionPlan(stats_backend="einsum")
-        )
-        assert eng.config.stats_backend == "einsum"
-        assert eng.plan.stats_backend == "einsum"
-    # resolution happened at construction: mutating the env later is inert
-    with mock.patch.dict(os.environ, {stats_backend.ENV_VAR: "einsum"}):
-        eng = DAEFEngine(cfg)
-    assert eng.config.stats_backend == "einsum"
-    with mock.patch.dict(os.environ, {stats_backend.ENV_VAR: "nonsense"}):
-        with pytest.raises(ValueError, match="unknown stats backend"):
-            DAEFEngine(cfg)
-
 
 def test_backend_parity_through_engine():
     """fused == einsum through the facade (vmap plan), test_parity bar."""
     k = 4
     xs = _data(k, 56, seed=19)
+    engine = DAEFEngine(_cfg(), ExecutionPlan(mode="vmap", tenants=k))
     fls = {}
     for backend in ("einsum", "fused"):
-        engine = DAEFEngine(
-            _cfg(), ExecutionPlan(mode="vmap", tenants=k, stats_backend=backend)
-        )
-        fls[backend] = engine.fit(xs, seeds=jnp.arange(k))
+        with stats_producer(backend):
+            fls[backend] = engine.fit(xs, seeds=jnp.arange(k))
     _assert_trees_close(fls["einsum"].model, fls["fused"].model,
                         what="backend parity via engine")
 
@@ -364,8 +340,6 @@ def test_plan_validation_errors():
         ExecutionPlan(mode="vmap", mesh_devices=2)
     with pytest.raises(PlanError, match="SINGLE model"):
         ExecutionPlan(mode="mesh", tenants=4, mesh_axes=("data",))
-    with pytest.raises(ValueError, match="unknown stats backend"):
-        ExecutionPlan(stats_backend="nonsense")
 
 
 def test_engine_input_errors():

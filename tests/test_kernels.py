@@ -176,19 +176,19 @@ def test_rolann_stats_acc_batched_vs_oracle():
 def test_gram_stats_acc_vmap_dispatches_batched(monkeypatch):
     """vmapping the accumulating fold (the fleet's tenant axis) must lower
     to ONE tenant-batched dispatch via the custom_vmap rule — for the fused
-    backend a single `rolann_stats_acc_batched` launch — and agree with the
-    per-tenant loop for both backends."""
+    producer a single `rolann_stats_acc_batched` launch — and agree with the
+    per-tenant loop under both producers."""
     from repro.core import stats_backend
+    from repro.testing.producer import stats_producer
 
     calls = []
     orig = stats_backend.gram_stats_acc_batched
 
-    def spy(g, m, xa, fsq, fd, *, backend=None):
-        calls.append((tuple(xa.shape), backend))
-        return orig(g, m, xa, fsq, fd, backend=backend)
+    def spy(g, m, xa, fsq, fd):
+        calls.append((tuple(xa.shape), stats_backend.producer()))
+        return orig(g, m, xa, fsq, fd)
 
     monkeypatch.setattr(stats_backend, "gram_stats_acc_batched", spy)
-    stats_backend._gram_stats_acc_fn.cache_clear()
     rng = np.random.default_rng(6)
     k, m, o, n = 4, 5, 3, 64
     g0 = jnp.asarray(rng.normal(size=(k, o, m, m)), jnp.float32)
@@ -196,26 +196,20 @@ def test_gram_stats_acc_vmap_dispatches_batched(monkeypatch):
     xa = jnp.asarray(rng.normal(size=(k, m, n)), jnp.float32)
     fsq = jnp.asarray(rng.uniform(0.1, 1, (k, o, n)), jnp.float32)
     fd = jnp.asarray(rng.normal(size=(k, o, n)), jnp.float32)
-    try:
-        for backend in stats_backend.BACKENDS:
+    for backend in stats_backend.PRODUCERS:
+        with stats_producer(backend):
             calls.clear()
-            g, mv = jax.vmap(
-                lambda a, b, c, d, e: stats_backend.gram_stats_acc(
-                    a, b, c, d, e, backend=backend
-                )
-            )(g0, m0, xa, fsq, fd)
+            g, mv = jax.vmap(stats_backend.gram_stats_acc)(g0, m0, xa, fsq, fd)
             assert calls, f"{backend}: batched accumulator was not dispatched"
             assert calls[0] == ((k, m, n), backend)
             for i in range(k):
                 gi, mi = stats_backend.gram_stats_acc(
-                    g0[i], m0[i], xa[i], fsq[i], fd[i], backend=backend
+                    g0[i], m0[i], xa[i], fsq[i], fd[i]
                 )
                 np.testing.assert_allclose(np.asarray(g[i]), np.asarray(gi),
                                            atol=1e-5, rtol=1e-5)
                 np.testing.assert_allclose(np.asarray(mv[i]), np.asarray(mi),
                                            atol=1e-5, rtol=1e-5)
-    finally:
-        stats_backend._gram_stats_acc_fn.cache_clear()
 
 
 def test_rolann_stats_acc_scan_carry_and_dtype():

@@ -3,7 +3,7 @@
 The chunked scan core (`daef.fit_chunked`, `ExecutionPlan(chunk_samples=...)`)
 and the host-iterator driver (`daef.fit_stream` / `DAEFEngine.fit_stream`)
 must reproduce the one-shot gram-method fit for every execution mode
-(loop / vmap / mesh) and both stats backends (einsum / fused), within the
+(loop / vmap / mesh) and both Gram-stats producers (einsum / fused), within the
 same per-dtype tolerances as tests/test_parity.py — plus chunk-size
 invariance (ragged tails, chunk == n, chunk == 1) and the iterator
 semantics of ``fit_stream`` (lists, one-shot generators, per-pass callable
@@ -21,6 +21,7 @@ import pytest
 
 from repro.core import daef, fleet, stats_backend
 from repro.engine import DAEFEngine, ExecutionPlan, PlanError
+from repro.testing.producer import stats_producer
 from repro.testing.proptest import given, settings, st
 
 TOLS = {
@@ -90,15 +91,15 @@ def _plan(mode: str, k: int, **kw) -> ExecutionPlan:
 
 
 # ---------------------------------------------------------------------------
-# chunked == one-shot: every mode x both backends
+# chunked == one-shot: every mode x both producers
 # ---------------------------------------------------------------------------
 
-# The fused backend runs the Pallas kernels in interpret mode on CPU — full
+# The fused producer runs the Pallas kernels in interpret mode on CPU — full
 # coverage, but slow; those combos ride the slow tier (still executed by
 # CI's multi-device job, which selects "slow or not slow").
 BACKEND_PARAMS = [
     pytest.param(b, marks=[pytest.mark.slow] if b == "fused" else [])
-    for b in stats_backend.BACKENDS
+    for b in stats_backend.PRODUCERS
 ]
 
 
@@ -113,13 +114,14 @@ LOOP_SLOW_MODES = [
 @pytest.mark.parametrize("mode", LOOP_SLOW_MODES)
 def test_chunked_fit_matches_oneshot(mode, backend):
     k, n = 2, 48
-    cfg = _cfg(stats_backend=backend)
+    cfg = _cfg()
     xs = _data(k, n, seed=0)
     seeds = jnp.arange(k)
 
-    ref = DAEFEngine(cfg, _plan(mode, k)).fit(xs, seeds=seeds)
     eng = DAEFEngine(cfg, _plan(mode, k, chunk_samples=20))  # ragged tail
-    got = eng.fit(xs, seeds=seeds)
+    with stats_producer(backend):
+        ref = DAEFEngine(cfg, _plan(mode, k)).fit(xs, seeds=seeds)
+        got = eng.fit(xs, seeds=seeds)
     _assert_close(got.model, ref.model, what=f"{mode}/{backend} chunked fit")
 
     scores_ref = DAEFEngine(cfg, _plan(mode, k)).scores(ref, xs)
@@ -133,14 +135,15 @@ def test_chunked_fit_matches_oneshot(mode, backend):
 @pytest.mark.parametrize("mode", ["loop", "vmap", "mesh"])
 def test_fit_stream_matches_oneshot(mode, backend):
     k, n = 2, 48
-    cfg = _cfg(stats_backend=backend)
+    cfg = _cfg()
     xs = _data(k, n, seed=1)
     seeds = jnp.arange(k)
 
-    ref = DAEFEngine(cfg, _plan(mode, k)).fit(xs, seeds=seeds)
     eng = DAEFEngine(cfg, _plan(mode, k, chunk_samples=20))
     chunks = [np.asarray(xs[:, :, i:i + 20]) for i in range(0, n, 20)]
-    got = eng.fit_stream(chunks, seeds=seeds)
+    with stats_producer(backend):
+        ref = DAEFEngine(cfg, _plan(mode, k)).fit(xs, seeds=seeds)
+        got = eng.fit_stream(chunks, seeds=seeds)
     _assert_close(got.model, ref.model, what=f"{mode}/{backend} fit_stream")
 
 
@@ -344,18 +347,17 @@ def test_fleet_chunked_routes_through_batched_acc(monkeypatch):
     calls = []
     orig = stats_backend.gram_stats_acc_batched
 
-    def spy(g, m, xa, fsq, fd, *, backend=None):
-        calls.append((tuple(xa.shape), backend))
-        return orig(g, m, xa, fsq, fd, backend=backend)
+    def spy(g, m, xa, fsq, fd):
+        calls.append((tuple(xa.shape), stats_backend.producer()))
+        return orig(g, m, xa, fsq, fd)
 
     monkeypatch.setattr(stats_backend, "gram_stats_acc_batched", spy)
-    stats_backend._gram_stats_acc_fn.cache_clear()
     k, n, chunk = 3, 36, 12
     xs = _data(k, n, seed=10)
-    try:
-        for backend in stats_backend.BACKENDS:
+    cfg = _cfg()
+    for backend in stats_backend.PRODUCERS:
+        with stats_producer(backend):
             calls.clear()
-            cfg = _cfg(stats_backend=backend)
             fl = fleet._fit_fleet_chunked(
                 cfg, xs, chunk_samples=chunk, seeds=jnp.arange(k)
             )
@@ -366,8 +368,6 @@ def test_fleet_chunked_routes_through_batched_acc(monkeypatch):
             ref = fleet._fit_fleet(cfg, xs, seeds=jnp.arange(k))
             _assert_close(fl.model, ref.model,
                           what=f"{backend} batched-acc chunked fleet")
-    finally:
-        stats_backend._gram_stats_acc_fn.cache_clear()
 
 
 # ---------------------------------------------------------------------------

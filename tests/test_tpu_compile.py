@@ -160,8 +160,7 @@ def test_einsum_fleet_fit_compiles_for_v5e(one_chip, arch, monkeypatch):
     sizes, n, k = ARCHS[arch]
     if arch == "creditcard":
         n //= k            # the 8 federated partitions, fit in one dispatch
-    cfg = daef.DAEFConfig(layer_sizes=sizes, lam_hidden=0.8, lam_last=0.9,
-                          stats_backend="einsum")
+    cfg = daef.DAEFConfig(layer_sizes=sizes, lam_hidden=0.8, lam_last=0.9)
 
     def spec(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -186,8 +185,7 @@ def test_einsum_fleet_fit_compiles_for_v5e(one_chip, arch, monkeypatch):
 
 def test_one_model_fit_keeps_eigh_tpu_for_v5e(one_chip):
     sizes, n, _ = ARCHS["creditcard"]
-    cfg = daef.DAEFConfig(layer_sizes=sizes, lam_hidden=0.8, lam_last=0.9,
-                          stats_backend="einsum")
+    cfg = daef.DAEFConfig(layer_sizes=sizes, lam_hidden=0.8, lam_last=0.9)
     x = jax.ShapeDtypeStruct((sizes[0], n), jnp.float32, sharding=one_chip)
     text = daef.lower_fit(cfg, x).compile().as_text()
     assert "EighTpu" in text and "jacobi_eigh" not in text
