@@ -11,7 +11,9 @@ where ``U^p, S^p`` come from the local SVD of ``X^p``.  ``V`` is never formed
 
 As with ROLANN, ``U S^2 U^T = X X^T``: the Gram-sum path (``psum`` of local
 ``X^p X^p^T`` followed by one ``eigh``) is mathematically identical and is our
-beyond-paper fast path.
+beyond-paper fast path.  ``factors_to_gram`` takes factors back to their
+Gram, so factors merged by a tree under ``method="gram"`` are summed as
+Grams and factored once, at the root (``fleet_sharded._tree_merge``).
 """
 from __future__ import annotations
 
@@ -100,6 +102,17 @@ def gram_to_factors(g: Array) -> SvdFactors:
     evals, evecs = eigh.eigh(g)
     evals = jnp.maximum(evals, 0.0)
     return SvdFactors(u=canonicalize_signs(evecs[:, ::-1]), s=jnp.sqrt(evals[::-1]))
+
+
+def factors_to_gram(f: SvdFactors) -> Array:
+    """``U diag(s^2) U^T`` [..., m, m], the Gram the factors came from
+    (``X X^T``): the inverse of `gram_to_factors`, and additive like it.
+
+    Element-wise float32 products summed over the rank axis, so no
+    reduced-precision matrix-unit pass enters and the result is exactly
+    symmetric (the Jacobi route of `gram_to_factors` reads it whole)."""
+    us = jnp.swapaxes(f.u * f.s[..., None, :], -1, -2)     # [..., r, m]
+    return jnp.sum(us[..., :, :, None] * us[..., :, None, :], axis=-3)
 
 
 def truncate(f: SvdFactors, rank: int) -> SvdFactors:

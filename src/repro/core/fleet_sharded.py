@@ -25,8 +25,10 @@ sizes, run entirely on the mesh as a ``shard_map`` tree reduction:
 * weights are re-solved from the merged knowledge once, at the root —
   not once per merge round as a naive loop over `fleet_merge` would.
 
-Works for both knowledge representations: ``method="gram"`` merges are
-sums (the butterfly is a segmented all-reduce) and ``method="svd"``
+Works for both knowledge representations.  Under ``method="gram"`` every
+merge is a sum (the butterfly is a segmented all-reduce): the encoder
+travels as its Gram ``U S^2 U^T`` (`dsvd.factors_to_gram`) and is factored
+once per merged state, by one eigh at the root.  Under ``method="svd"``
 merges are the paper's concat-SVD (Eq. 2/8), whose U-sign ambiguity is
 harmless here: encoder factors are sign-canonicalized and the ROLANN
 solve is U-sign-invariant.
@@ -318,16 +320,47 @@ def sharded_fleet_partial_fit(
 # ---------------------------------------------------------------------------
 
 def _merge_pair_knowledge(config: daef.DAEFConfig):
-    """Pairwise merge on (enc factors, knowledge) — the fixed-shape part of
-    the exchanged state, shared by both tree kernels."""
-    merge = rolann.merge_stats if config.method == "gram" else rolann.merge_factors
+    """Pairwise merge on (encoder, knowledge) — the fixed-shape part of the
+    exchanged state, shared by both tree kernels.  The encoder is as
+    `_encoder_state` gives it: a Gram summed under ``method="gram"``,
+    factors merged by the concat-SVD (`dsvd.merge_pair`) under "svd"."""
+    if config.method == "gram":
+        merge_enc, merge = jnp.add, rolann.merge_stats
+    else:
+        merge_enc, merge = dsvd.merge_pair, rolann.merge_factors
 
     def pair(a, b):
-        enc = dsvd.merge_pair(a[0], b[0])
+        enc = merge_enc(a[0], b[0])
         knw = tuple(merge(ka, kb) for ka, kb in zip(a[1], b[1], strict=True))
         return enc, knw
 
     return pair
+
+
+def _encoder_state(config: daef.DAEFConfig, enc: dsvd.SvdFactors):
+    """The encoder as the butterfly carries it: under ``method="gram"`` its
+    Gram ``U S^2 U^T`` [slots, m0, m0], which merges by sums; under "svd"
+    its factors.  Named scope ``merge_local``."""
+    if config.method != "gram":
+        return enc
+    with jax.named_scope("merge_local"):
+        return dsvd.factors_to_gram(enc)
+
+
+def _encoder_factors(config: daef.DAEFConfig, enc, rank: int) -> dsvd.SvdFactors:
+    """`_encoder_state` undone at the root: under ``method="gram"`` one
+    eigh per merged Gram (`dsvd.gram_to_factors`), kept to ``rank``, the
+    rank the concat-SVD route would reach (`_merged_rank`)."""
+    if config.method != "gram":
+        return enc
+    return dsvd.truncate(dsvd.gram_to_factors(enc), rank)
+
+
+def _merged_rank(enc: dsvd.SvdFactors, levels: int) -> int:
+    """Columns of the factors ``levels`` concat-SVD merges give: each merge
+    of two rank-r factors keeps min(m0, 2 r)."""
+    m0, r = enc.u.shape[-2:]
+    return min(m0, r << levels)
 
 
 def _merge_pair_state(config: daef.DAEFConfig):
@@ -384,10 +417,14 @@ def _butterfly(pair, state, n_dev: int, local_rounds: int, cross_rounds: int,
 def _tree_merge(config: daef.DAEFConfig, n_dev: int, local_rounds: int,
                 cross_rounds: int, model, seeds, lam_hidden, lam_last):
     """One shard's part of `fleet_merge_tree`: the butterfly over
-    (enc factors, knowledge, errors), then the weights solved once from
-    each merged state (named scope ``merge_solve``)."""
-    state = (model.encoder_factors, model.layer_knowledge, model.train_errors)
-    state, (seeds, lam_hidden, lam_last) = _butterfly(
+    (encoder, knowledge, errors), then the encoder factored (under
+    ``method="gram"``) and the weights solved once from each merged state
+    (named scope ``merge_solve``)."""
+    enc = model.encoder_factors
+    rank = _merged_rank(enc, local_rounds + cross_rounds)
+    state = (_encoder_state(config, enc), model.layer_knowledge,
+             model.train_errors)
+    (enc, knw, errs), (seeds, lam_hidden, lam_last) = _butterfly(
         jax.vmap(_merge_pair_state(config)), state, n_dev, local_rounds,
         cross_rounds, carry=(seeds, lam_hidden, lam_last),
     )
@@ -397,7 +434,8 @@ def _tree_merge(config: daef.DAEFConfig, n_dev: int, local_rounds: int,
         return daef._model_from_knowledge(config, enc, knw, keys, lh, ll, errs)
 
     with jax.named_scope("merge_solve"):
-        merged = jax.vmap(solve)(*state, seeds, lam_hidden, lam_last)
+        enc = _encoder_factors(config, enc, rank)
+        merged = jax.vmap(solve)(enc, knw, errs, seeds, lam_hidden, lam_last)
     return merged, seeds, lam_hidden, lam_last
 
 
@@ -469,20 +507,35 @@ def _mesh_for_merge(fl: fleet.DAEFFleet, group_size: int) -> Mesh:
     return tenant_mesh(max(1, d))
 
 
-def _exchange_bytes(model: daef.DAEFModel, n_dev: int, local_rounds: int,
-                    cross_rounds: int) -> int:
+def _exchange_bytes(config: daef.DAEFConfig, model: daef.DAEFModel, n_dev: int,
+                    local_rounds: int, cross_rounds: int) -> int:
     """Bytes each device sends in the butterfly of one tree reduce: in cross
-    round r its whole state, (enc factors, knowledge, errors) per remaining
-    slot, the error pool of each slot 2^(local_rounds + r) sites long."""
+    round r its whole state, (encoder, knowledge, errors) per remaining
+    slot, the encoder as `_encoder_state` carries it (an m0 x m0 Gram under
+    ``method="gram"``) and the error pool of each slot 2^(local_rounds + r)
+    sites long."""
     def nbytes(leaf):
         return int(np.prod(leaf.shape[1:])) * np.dtype(leaf.dtype).itemsize
 
+    enc = jax.eval_shape(partial(_encoder_state, config), model.encoder_factors)
     fixed = sum(nbytes(leaf) for leaf in jax.tree.leaves(
-        (model.encoder_factors, model.layer_knowledge)))
+        (enc, model.layer_knowledge)))
     errors = nbytes(model.train_errors)
     slots = model.train_errors.shape[0] // n_dev >> local_rounds
     return sum(slots * (fixed + (errors << (local_rounds + r)))
                for r in range(cross_rounds))
+
+
+def _encoder_factorizations(config: daef.DAEFConfig, tenants: int, n_dev: int,
+                            local_rounds: int, cross_rounds: int) -> int:
+    """Encoder factorizations one device's tree program runs per call: under
+    ``method="gram"`` one eigh per slot left at the root; under "svd" one
+    concat-SVD per pairwise merge, local levels and cross levels."""
+    local = tenants // n_dev
+    roots = local >> local_rounds
+    if config.method == "gram":
+        return roots
+    return local - roots + cross_rounds * roots
 
 
 class MergeCall(NamedTuple):
@@ -491,6 +544,7 @@ class MergeCall(NamedTuple):
     ``DAEFEngine.lower_reduce`` lowers."""
 
     fn: Callable
+    config: daef.DAEFConfig
     fleet: fleet.DAEFFleet
     mesh: Mesh
     local_rounds: int
@@ -502,13 +556,17 @@ class MergeCall(NamedTuple):
 
     def run(self) -> fleet.DAEFFleet:
         """Place the fleet, dispatch the program, keep one model per group:
-        the host spans ``reduce.place``, ``reduce.dispatch`` (attribute
-        ``exchange_bytes``) and ``reduce.dedup``."""
+        the host spans ``reduce.place``, ``reduce.dispatch`` (attributes
+        ``exchange_bytes`` and ``encoder_factorizations``) and
+        ``reduce.dedup``."""
         with obs.span("reduce.place"):
             args = self._args()
-        sent = _exchange_bytes(self.fleet.model, self.mesh.shape[TENANT_AXIS],
-                               self.local_rounds, self.cross_rounds)
-        with obs.span("reduce.dispatch", exchange_bytes=sent):
+        shape = (self.mesh.shape[TENANT_AXIS], self.local_rounds,
+                 self.cross_rounds)
+        sent = _exchange_bytes(self.config, self.fleet.model, *shape)
+        factored = _encoder_factorizations(self.config, self.fleet.size, *shape)
+        with obs.span("reduce.dispatch", exchange_bytes=sent,
+                      encoder_factorizations=factored):
             merged = fleet.DAEFFleet(*self.fn(*args))
         if self.cross_rounds:
             # Butterfly results are replicated inside each device group; keep
@@ -568,7 +626,7 @@ def _merge_tree_call(config: daef.DAEFConfig, fl: fleet.DAEFFleet,
         local_rounds = local_k.bit_length() - 1
         cross_rounds = (group_size // local_k).bit_length() - 1
     return MergeCall(_merge_tree_fn(config, mesh, local_rounds, cross_rounds),
-                     fl, mesh, local_rounds, cross_rounds)
+                     config, fl, mesh, local_rounds, cross_rounds)
 
 
 def fleet_merge_tree(
@@ -615,14 +673,18 @@ def fleet_merge_tree(
 def _state_tree_fn(config: daef.DAEFConfig, mesh: Mesh, local_rounds: int,
                    cross_rounds: int):
     """Build (and cache) the jitted shard_map state-reduction kernel: the
-    `_merge_tree_fn` butterfly over (enc factors, knowledge) only — no
-    per-slot weight solve, no error pool (both live with the caller)."""
+    `_merge_tree_fn` butterfly over (encoder, knowledge) only, the encoder
+    factored once per remaining slot — no per-slot weight solve, no error
+    pool (both live with the caller)."""
     n_dev = mesh.shape[TENANT_AXIS]
     pair = jax.vmap(_merge_pair_knowledge(config))
 
     def body(enc, knowledge):
-        return _butterfly(pair, (enc, knowledge), n_dev, local_rounds,
-                          cross_rounds)[0]
+        rank = _merged_rank(enc, local_rounds + cross_rounds)
+        state = (_encoder_state(config, enc), knowledge)
+        enc, knowledge = _butterfly(pair, state, n_dev, local_rounds,
+                                    cross_rounds)[0]
+        return _encoder_factors(config, enc, rank), knowledge
 
     spec = P(TENANT_AXIS)
     fn = jax.shard_map(
@@ -651,11 +713,11 @@ def merge_state_tree(
     stacked site states (S a power of two — pad with arbitrary slots and
     zero their mask entries), and ``mask`` ([S] in {0, 1}) selects who
     participates.  Masked slots are scaled to the merge identity
-    (`rolann.mask_knowledge` / zeroed encoder singular values) BEFORE the
-    reduction, so the fixed-shape butterfly needs no data-dependent control
-    flow: excluded sites ride along as no-ops.  This is how the async
-    `FederationSession` folds whichever sites are fresh on a mesh without a
-    participation barrier.
+    (`rolann.mask_knowledge` / zeroed encoder singular values, so a zero
+    encoder Gram) BEFORE the reduction, so the fixed-shape butterfly needs
+    no data-dependent control flow: excluded sites ride along as no-ops.
+    This is how the async `FederationSession` folds whichever sites are
+    fresh on a mesh without a participation barrier.
 
     Requires ``method="gram"`` — factor-form knowledge is rank-ragged across
     sites (r depends on the local sample count) and cannot stack; the host
@@ -663,7 +725,8 @@ def merge_state_tree(
     ``ValueError`` on a non-power-of-two S or an all-zero mask.
 
     Returns the merged ``(enc_factors, knowledge)`` with the slot axis
-    reduced away.  The caller re-solves weights once from the result
+    reduced away, the encoder factored by one eigh of the summed Gram.  The
+    caller re-solves weights once from the result
     (`daef._model_from_knowledge`).
     """
     if config.method != "gram":

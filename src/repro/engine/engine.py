@@ -740,8 +740,12 @@ class DAEFEngine:
         Host spans (`repro.obs`): ``engine.reduce`` (attributes ``tenants``
         and ``group_size``) over ``reduce.prepare`` (checks, the host read
         of the seeds and lambdas); under "tree" then ``reduce.place``,
-        ``reduce.dispatch`` (attribute ``exchange_bytes``, the bytes each
-        device sends in the butterfly) and ``reduce.dedup``.
+        ``reduce.dispatch`` (attributes ``exchange_bytes``, the bytes each
+        device sends in the butterfly, and ``encoder_factorizations``, the
+        encoder factorizations each device's tree program runs: one eigh
+        per merged state under ``method="gram"``, whose butterfly sums
+        encoder Grams, one concat-SVD per pairwise merge under "svd") and
+        ``reduce.dedup``.
         """
         with obs.span("engine.reduce", tenants=self.plan.tenants, group_size=group_size):
             with obs.span("reduce.prepare"):

@@ -34,8 +34,10 @@ and on the reduce path::
       reduce.prepare            checks, the host read of the seeds and
                                 lambdas, the tree program and its mesh
       reduce.place              the fleet put on the tenant mesh
-      reduce.dispatch           the call of the tree program (attribute
-                                exchange_bytes: bytes each device sends)
+      reduce.dispatch           the call of the tree program (attributes
+                                exchange_bytes: bytes each device sends;
+                                encoder_factorizations: encoder SVDs or
+                                eighs each device's program runs)
       reduce.dedup              one model kept per replicated group
 """
 from __future__ import annotations

@@ -17,7 +17,10 @@ compile, for one chip of a described ``v5e:2x2`` topology,
   one ``chol_solve`` Mosaic kernel a stack); at cardio's widths no XLA
   ``Cholesky`` is left;
 * creditcard's one-model fit program, whose one eigh keeps ``EighTpu`` and
-  whose solves keep XLA's ``Cholesky``.
+  whose solves keep XLA's ``Cholesky``;
+* the federated tree program of 1,024 cardio sites over the four chips of
+  the 2x2 mesh, whose encoder merges by Gram sums: one ``EighTpu`` a chip
+  at the root and none of the concat-SVD's QDWH loops.
 
 Nothing runs.  The topology is described inside a fixture: only the worker
 that is given this file loads the TPU compiler, and a host that cannot
@@ -25,13 +28,16 @@ describe one skips here, never at import.  The persistent compilation cache
 is off around these compiles (a compile for a described device is written
 to it but cannot be read back without the device).
 """
+import functools
 import os
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core import chol, daef, eigh, fleet
 from repro.kernels.rolann_stats import ops
@@ -190,3 +196,31 @@ def test_one_model_fit_keeps_eigh_tpu_for_v5e(one_chip):
     text = daef.lower_fit(cfg, x).compile().as_text()
     assert "EighTpu" in text and "jacobi_eigh" not in text
     assert 'custom_call_target="Cholesky"' in text and not _has_chol_scope(text)
+
+
+def test_gram_tree_merge_factors_once_a_chip_for_v5e(topo, one_chip):  # noqa: ARG001
+    """cardio-fed-1024's reduce (``method="gram"``, 256 sites a chip: 8
+    local and 2 ``ppermute`` levels): its encoders are summed as Grams, so
+    the program holds one eigh, of the root's Gram, and no SVD."""
+    from repro.engine import DAEFEngine, ExecutionPlan
+
+    sizes, n, k = ARCHS["cardio"][0], 1_489, 1_024
+    cfg = daef.DAEFConfig(layer_sizes=sizes, lam_hidden=0.9, lam_last=0.9)
+    mesh = Mesh(np.array(topo.devices), ("tenants",))
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P("tenants")))
+
+    args = (spec((k,), jnp.int32), spec((k,)), spec((k,)))
+    model = jax.eval_shape(functools.partial(fleet._fleet_fit, cfg),
+                           spec((k, sizes[0], n)), *args)
+    fl = fleet.DAEFFleet(jax.tree.map(lambda a: spec(a.shape, a.dtype), model),
+                         *args)
+    engine = DAEFEngine(cfg, ExecutionPlan(mode="mesh", tenants=k, mesh_devices=4,
+                                           merge="tree"), mesh=mesh)
+    text = engine.lower_reduce(fl, k).compile().as_text()
+    assert "collective-permute" in text
+    assert text.count('custom_call_target="EighTpu"') == 1
+    assert not re.search(r"\swhile\(", text)
+    assert "CompactWyHelper" not in text and "QrDecompositionBlock" not in text
